@@ -1,9 +1,9 @@
-"""Encode-path A/B harness: gating × phase-1 depth × chase formulation.
+"""Encode-path A/B harness: gating × phase-1 depth.
 
 The 48-plane encode is the self-play ceiling and the two ladder planes
-are ~93% of it (BENCH_RESULTS.md "Bottleneck analysis") — yet until
+are ~93% of it in CPU traces (on the chip: not measured) — yet until
 this harness every encode knob was a platform heuristic. This measures
-each configuration of the three axes that matter and records one
+each configuration of the two axes that matter and records one
 results.jsonl row per config, so the defaults in
 ``features/ladders.py`` are set from numbers (the
 ``jaxgo._dense_engine`` discipline):
@@ -14,10 +14,7 @@ results.jsonl row per config, so the defaults in
 * **phase1** — the two-phase chase schedule's lockstep depth
   (``$ROCALPHAGO_LADDER_PHASE1``; a value ≥ ladder depth recovers the
   old single-phase FIXED-RUNG read — the baseline the gated/early-exit
-  path is judged against);
-* **impl** — ``xla`` (batch-lockstep while_loop) vs ``pallas`` (the
-  per-lane TPU kernel ``ops/chase.py``; ``interpret`` runs it in the
-  Pallas interpreter — correctness-only, not perf-comparable).
+  path is judged against).
 
 Every row carries ``us_per_pos`` (per-position microseconds — the
 unit ``scripts/bench_report.py``'s encode column renders) plus the
@@ -25,9 +22,7 @@ axis fields, and one ``encode_noladder`` row measures the same batch
 without the ladder planes so the ladder share of encode is a recorded
 number, not folklore. The env knobs are read at TRACE time, so each
 config traces a fresh program — the A/B never reuses a stale cached
-trace. TPU rows: the ``encode_*`` steps in
-``scripts/tpu_window_hunter2.sh`` run this harness per config in the
-next healthy window.
+trace. On the chip: not measured (ROADMAP S1).
 
 TRAJECTORY rows (``--trajectory``, PR 6): self-play and MCTS visit
 SUCCESSIVE positions, so the batched mid-game measurement above is
@@ -39,8 +34,7 @@ carried ply to ply) against the from-scratch encode (
 records the speedup as ``vs_baseline`` (incr rate ÷ scratch rate).
 ``--traj-batch`` adds the batched-lockstep pair
 (``encode_incr_batched`` / ``encode_scratch_batched``) — the numbers
-behind ``selfplay.incremental_default``'s measured default. TPU rows:
-``encode_incr*`` hunter steps.
+behind ``selfplay.incremental_default``'s CPU-measured default.
 """
 
 from __future__ import annotations
@@ -48,7 +42,6 @@ from __future__ import annotations
 import functools
 import os
 import sys
-import time
 
 sys.path.insert(0, ".")
 from benchmarks._harness import (  # noqa: E402
@@ -249,8 +242,6 @@ def main() -> None:
     ap.add_argument("--phase1", default="4",
                     help="comma list of phase-1 depths (>= --depth "
                          "recovers the single-phase fixed-rung read)")
-    ap.add_argument("--impl", default="xla",
-                    help="comma list: xla,pallas,interpret")
     ap.add_argument("--depth", type=int, default=40)
     ap.add_argument("--slots", type=int, default=None,
                     help="ladder_chase_slots override (default: the "
@@ -325,32 +316,17 @@ def main() -> None:
             else:
                 os.environ["ROCALPHAGO_LADDER_PLANES"] = prev
 
-    impl_env = {"xla": "", "pallas": "1", "interpret": "interpret"}
-    for impl in args.impl.split(","):
-        if impl not in impl_env:
-            print(f"bench_encode: unknown impl {impl!r}",
-                  file=sys.stderr)
-            continue
-        for gating in args.gating.split(","):
-            for phase1 in (int(p) for p in args.phase1.split(",")):
-                os.environ["ROCALPHAGO_PALLAS_CHASE"] = impl_env[impl]
-                os.environ["ROCALPHAGO_LADDER_GATE"] = gating
-                os.environ["ROCALPHAGO_LADDER_PHASE1"] = str(phase1)
-                t0 = time.time()
-                try:
-                    dt = measure(DEFAULT_FEATURES)
-                except Exception as e:  # noqa: BLE001 — keep the sweep
-                    print(f"bench_encode: {impl}/{gating}/p{phase1} "
-                          f"failed after {time.time() - t0:.0f}s: "
-                          f"{type(e).__name__}: {e}", file=sys.stderr)
-                    continue
-                report("encode_ab", batch / dt, "positions/s",
-                       batch=batch, board=args.board,
-                       gating=gating, phase1=phase1, chase_impl=impl,
-                       us_per_pos=round(1e6 * dt / batch, 1),
-                       **({"slots": args.slots}
-                          if args.slots is not None else {}))
-
+    for gating in args.gating.split(","):
+        for phase1 in (int(p) for p in args.phase1.split(",")):
+            os.environ["ROCALPHAGO_LADDER_GATE"] = gating
+            os.environ["ROCALPHAGO_LADDER_PHASE1"] = str(phase1)
+            dt = measure(DEFAULT_FEATURES)
+            report("encode_ab", batch / dt, "positions/s",
+                   batch=batch, board=args.board,
+                   gating=gating, phase1=phase1,
+                   us_per_pos=round(1e6 * dt / batch, 1),
+                   **({"slots": args.slots}
+                      if args.slots is not None else {}))
 
 if __name__ == "__main__":
     main()

@@ -25,8 +25,7 @@ batched curve rises with session count while unbatched stays flat at
 its single-session rate — the cross-game economics the serving
 subsystem exists for — and saturates once the core runs out of
 FLOPs (~64 sessions here; 256 measured flat within noise, which is
-why the default sweep stops at 64 — the accelerator continuation is
-the ``serve_small``/``serve_fleet`` hunter steps).
+why the default sweep stops at 64; on the chip: not measured).
 
 ``--cache-ab`` replaces the batched/unbatched sweep with the
 transposition-cache A/B (docs/SERVING.md "Evaluation cache"): the
@@ -100,9 +99,7 @@ def main():
                     help="comma list of concurrent-session counts. "
                          "The CPU default stops at 64: on one host "
                          "core the batched path saturates there "
-                         "(measured flat ±2%% to 256 — the 256-row "
-                         "record and the TPU continuation live in "
-                         "the serve_fleet hunter step)")
+                         "(measured flat ±2%% to 256 on CPU)")
     ap.add_argument("--layers", type=int, default=6)
     ap.add_argument("--filters", type=int, default=96)
     ap.add_argument("--sims", type=int, default=8,

@@ -102,6 +102,16 @@ def main() -> None:
                     "sweep, whose actors start compile-hot)")
     ap.set_defaults(board=5, batch=8)
     args = ap.parse_args()
+    if args.wire and jax.default_backend() != "cpu":
+        # one process per chip: this process holds the accelerator
+        # and every replaynet.actor child would ask for it too — on
+        # one chip that hangs or fails. The wire rig is a CPU rig
+        # until there is a launcher that hands each actor a device.
+        raise SystemExit(
+            f"bench_zero_scale --wire spawns actor PROCESSES that "
+            f"each claim the default backend; on "
+            f"{jax.default_backend()!r} they would fight this "
+            f"process for the chip. Run it with JAX_PLATFORMS=cpu.")
     econ = {}
     if args.cap_p:
         econ = {"cap_p": args.cap_p,

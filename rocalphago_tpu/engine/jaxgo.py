@@ -171,12 +171,13 @@ def _dense_engine() -> bool:
 
     On TPU, scatter-adds with colliding indices and `[N,4]` index
     gathers serialize, while broadcast compares, 2-D grid shifts and
-    small matmuls run at full vector/MXU width — measured round 5's
-    on-chip A/B (batch 1024, 19x19, `benchmarks/tpu_hunt2_r5`): dense
-    17,762 steps/s vs scatter 10,558 — dense wins 1.68x, so it is the
-    TPU default by measurement. On CPU the scatter path wins (1444
-    cheap serial updates beat 131k-cell dense compares), so the
-    default follows the backend platform.
+    small matmuls run at full vector/MXU width — the one on-chip A/B
+    on record (`benchmarks/results.jsonl`, 2026-08-01, batch 1024,
+    19x19): dense 17,762 steps/s vs scatter 10,558, so dense is the
+    TPU default. On CPU the scatter path wins (1444 cheap serial
+    updates beat 131k-cell dense compares), so the default follows
+    the backend platform; entry points report which one ran
+    (:func:`engine_formulation`, the ``device`` event).
 
     Read once per process (trace-time; cached): override with
     ``ROCALPHAGO_ENGINE_DENSE=0/1`` **before the first engine trace**
@@ -189,6 +190,12 @@ def _dense_engine() -> bool:
     if v in ("0", "1"):
         return v == "1"
     return jax.default_backend() == "tpu"
+
+
+def engine_formulation() -> str:
+    """``"dense"`` or ``"scatter"`` — which group-analysis formulation
+    this process traces (see :func:`_dense_engine`)."""
+    return "dense" if _dense_engine() else "scatter"
 
 
 def _shift2d(x: jax.Array, dx: int, dy: int, fill) -> jax.Array:

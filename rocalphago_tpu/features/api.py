@@ -122,7 +122,7 @@ class Preprocess:
       6, ~69 at 8 — 6 trades ~13% against the fastest setting to keep
       the POOLED capacity near the pre-overhaul per-plane total
       (4 + 4) and dense-board truncation well inside the 1% oracle
-      bound (BENCH_RESULTS.md "Encode A/B").
+      bound (CHANGES.md PR 5).
     """
 
     def __init__(self, feature_list=DEFAULT_FEATURES,

@@ -4,7 +4,7 @@ Self-play and MCTS visit SUCCESSIVE positions, so almost all of each
 48-plane tensor's expensive analysis is unchanged ply-to-ply — yet the
 from-scratch encoder re-reads every ladder every time, and the ladder
 work (candidate openings + chases) dominates sequential encode cost
-(BENCH_RESULTS.md "Encode A/B" / "Incremental encode"). This module is
+(CPU profiles, CHANGES.md PR 5/6). This module is
 the delta path: an :class:`EncodeCache` carried through the sequential
 hot loops (a jit-compatible pytree) and an :func:`encode_step` that
 recomputes only what a move can change:
@@ -66,9 +66,9 @@ ko, edge/corner ladders, passes) asserting bit-identity against the
 from-scratch ``Preprocess`` at every ply with the ``pyfeatures``
 oracle as the independent check.
 
-The cached read always traces the default SHARED/XLA chase
-formulation; the ``ROCALPHAGO_LADDER_GATE=split`` and pallas-kernel
-A/B knobs apply to the from-scratch path only.
+The cached read always traces the default SHARED chase formulation;
+the ``ROCALPHAGO_LADDER_GATE=split`` A/B knob applies to the
+from-scratch path only.
 
 COLD / INVALIDATED caches are not an error path: a cold cache simply
 has no valid entries, so every lane refreshes and every live chase

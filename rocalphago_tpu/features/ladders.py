@@ -65,12 +65,10 @@ def _phase1_depth() -> int:
     at 4 slots; 73.9 vs 73.3 / 71.3 at the default 6 — within the
     run-to-run ~10% noise there), 8 pays extra lockstep rungs
     whenever any lane runs deep (62.2), and 40 recovers the old
-    single-phase fixed-rung read (21.8 — the baseline; see
-    BENCH_RESULTS.md "Encode A/B"). TPU rows are queued in
-    ``scripts/tpu_window_hunter2.sh`` (``encode_*`` steps); revisit
-    when they land. Read from ``$ROCALPHAGO_LADDER_PHASE1`` at TRACE
-    time (same policy as ``_chase_impl``) so A/B sweeps can flip it
-    per run. Floor 1: a while_loop body always runs once for live
+    single-phase fixed-rung read (21.8 — the baseline; CHANGES.md
+    PR 5). On the chip: not measured (ROADMAP S1 decides). Read from
+    ``$ROCALPHAGO_LADDER_PHASE1`` at TRACE time (same policy as
+    ``_ladder_gating``) so A/B sweeps can flip it per run. Floor 1: a while_loop body always runs once for live
     lanes, so a "depth-0" phase 1 would still play a rung and
     over-read by one."""
     return max(1, int(os.environ.get("ROCALPHAGO_LADDER_PHASE1", "2")))
@@ -85,7 +83,7 @@ def _ladder_gating() -> str:
     A/B baseline). MEASURED DEFAULT: shared wins the CPU A/B
     (``benchmarks/bench_encode.py``; the two planes' rung loops merge,
     so a deep chase pays its trips once instead of once per plane —
-    see BENCH_RESULTS.md "Encode A/B"). Read from
+    CHANGES.md PR 5). Read from
     ``$ROCALPHAGO_LADDER_GATE`` at trace time."""
     v = os.environ.get("ROCALPHAGO_LADDER_GATE", "shared")
     return "split" if v in ("split", "0", "off") else "shared"
@@ -323,8 +321,8 @@ def _foot_mode() -> str:
     only costs reuse. Read from ``$ROCALPHAGO_LADDER_FOOT`` at trace
     time (same policy as the other ladder knobs). MEASURED: tight cuts
     the footprint-churn re-chase cascade that capped incremental
-    encode at ~2.1–2.3× — see BENCH_RESULTS.md "Incremental encode"
-    and the ``encode_cascade`` row of ``bench_encode.py``."""
+    encode at ~2.1–2.3× on CPU — CHANGES.md PR 19 and the
+    ``encode_cascade`` row of ``bench_encode.py``."""
     v = os.environ.get("ROCALPHAGO_LADDER_FOOT", "tight")
     return "wide" if v in ("wide", "0", "off") else "tight"
 
@@ -579,18 +577,6 @@ def _chase(cfg: GoConfig, board0, labels0, prey_pt, depth: int,
     return captured, unresolved, final.board, final.labels
 
 
-def _chase_impl() -> str:
-    """Which chase implementation to trace: ``"xla"`` (default — the
-    batch-lockstep while_loop), ``"pallas"`` (the per-lane TPU kernel
-    ``ops.chase``), or ``"interpret"`` (the kernel in the Pallas
-    interpreter — CPU CI). Read from ``$ROCALPHAGO_PALLAS_CHASE`` at
-    trace time; the kernel is opt-in until real-chip measurements
-    favor it (same policy as ``ops.labels``)."""
-    v = os.environ.get("ROCALPHAGO_PALLAS_CHASE", "")
-    return {"1": "pallas", "pallas": "pallas",
-            "interpret": "interpret"}.get(v, "xla")
-
-
 def _compacted_chase(cfg: GoConfig, boards, labels, prey_pts,
                      need_chase, depth: int, slots: int):
     """Run the chase for the lanes flagged ``need_chase``, first
@@ -627,47 +613,34 @@ def _compacted_chase(cfg: GoConfig, boards, labels, prey_pts,
                       "ladder_chase_slots)")
 
         jax.debug.callback(_warn, need_chase.sum())
-    impl = _chase_impl()
-    if impl == "xla":
-        # TWO-PHASE schedule (VERDICT r3 #5). The vmapped while_loop
-        # locksteps every lane of every board in the batch: ONE deep
-        # chase anywhere makes all B×slots lanes pay its full trip
-        # count through the expensive two-ply body. Measured on
-        # random 19×19 mid-games, typical lanes settle in ≤9 rungs
-        # while a stray lane runs to the 40 cap — so phase 1 reads
-        # everyone to a short cap lockstep, then the still-live
-        # lanes finish ONE AT A TIME as scalar chases (resume is
-        # exact: the chase state is (board, labels, prey_pt)). Each
-        # scalar loop runs at 1/slots the width, and a loop whose
-        # lane doesn't exist exits in zero trips — so typical boards
-        # pay nothing for the tail, EVERY slotted lane is still read
-        # to full depth (the slots-restore-exactness contract), and
-        # the worst case (all slots deep) costs what the single
-        # lockstep loop did.
-        d1 = min(_phase1_depth(), depth)
-        prey = prey_pts[safe]
-        captured, unres, b_end, lab_end = jax.vmap(
-            lambda b, l, p, v: _chase(cfg, b, l, p, d1, enabled=v,
-                                      return_state=True))(
-                boards[safe], labels[safe], prey, valid)
-        if depth > d1:
-            deep_idx = _compact_indices(unres, slots, slots)
-            for s in range(slots):
-                idx = deep_idx[s]
-                live = idx < slots
-                at = jnp.where(live, idx, 0)
-                cap_s = _chase(cfg, b_end[at], lab_end[at], prey[at],
-                               depth - d1, enabled=live)
-                captured = captured.at[idx].set(cap_s, mode="drop")
-    else:
-        from rocalphago_tpu.ops.chase import pallas_chase
-
-        n = cfg.num_points
-        prey_oh = ((jnp.arange(n)[None, :] == prey_pts[safe][:, None])
-                   & valid[:, None])
-        captured = pallas_chase(boards[safe], labels[safe], prey_oh,
-                                cfg.size, depth,
-                                interpret=impl == "interpret")
+    # TWO-PHASE schedule. The vmapped while_loop locksteps every lane
+    # of every board in the batch: ONE deep chase anywhere makes all
+    # B×slots lanes pay its full trip count through the expensive
+    # two-ply body. Measured on random 19×19 mid-games, typical lanes
+    # settle in ≤9 rungs while a stray lane runs to the 40 cap — so
+    # phase 1 reads everyone to a short cap lockstep, then the
+    # still-live lanes finish ONE AT A TIME as scalar chases (resume
+    # is exact: the chase state is (board, labels, prey_pt)). Each
+    # scalar loop runs at 1/slots the width, and a loop whose lane
+    # doesn't exist exits in zero trips — so typical boards pay
+    # nothing for the tail, EVERY slotted lane is still read to full
+    # depth (the slots-restore-exactness contract), and the worst case
+    # (all slots deep) costs what the single lockstep loop did.
+    d1 = min(_phase1_depth(), depth)
+    prey = prey_pts[safe]
+    captured, unres, b_end, lab_end = jax.vmap(
+        lambda b, l, p, v: _chase(cfg, b, l, p, d1, enabled=v,
+                                  return_state=True))(
+            boards[safe], labels[safe], prey, valid)
+    if depth > d1:
+        deep_idx = _compact_indices(unres, slots, slots)
+        for s in range(slots):
+            idx = deep_idx[s]
+            live = idx < slots
+            at = jnp.where(live, idx, 0)
+            cap_s = _chase(cfg, b_end[at], lab_end[at], prey[at],
+                           depth - d1, enabled=live)
+            captured = captured.at[idx].set(cap_s, mode="drop")
     scatter = jnp.zeros((k,), jnp.bool_)
     return (scatter.at[slot_idx].set(captured & valid, mode="drop"),
             scatter.at[slot_idx].set(valid, mode="drop"))

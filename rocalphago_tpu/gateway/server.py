@@ -57,6 +57,7 @@ from rocalphago_tpu.interface.gtp import (
 )
 from rocalphago_tpu.interface.resilient import percentile
 from rocalphago_tpu.net.server import LineServerCore
+from rocalphago_tpu.obs import jaxobs
 from rocalphago_tpu.obs import registry as obs_registry
 from rocalphago_tpu.runtime import faults
 from rocalphago_tpu.runtime.deadline import Deadline
@@ -584,6 +585,7 @@ def main(argv=None) -> int:
         from rocalphago_tpu.io.metrics import MetricsLogger
 
         metrics = MetricsLogger(a.metrics, echo=False)
+        metrics.log("device", **jaxobs.device_record())
     policy = NeuralNetBase.load_model(a.policy)
     value = NeuralNetBase.load_model(a.value)
     if a.sizes:

@@ -37,6 +37,7 @@ import json
 import sys
 
 from rocalphago_tpu.engine import pygo
+from rocalphago_tpu.obs import jaxobs
 from rocalphago_tpu.obs import registry as obs_registry
 from rocalphago_tpu.obs import trace
 from rocalphago_tpu.runtime import faults
@@ -957,6 +958,7 @@ def main(argv=None):
         metrics = MetricsLogger(a.metrics, echo=False)
         # genmove spans + compile events join the serving metrics
         trace.configure(metrics)
+        metrics.log("device", **jaxobs.device_record())
     pool = None
     session = None
     if a.serve or a.serve_sizes:

@@ -32,7 +32,7 @@ def default_transient(exc: BaseException) -> bool:
     True for socket-level failures (``OSError`` and friends), for
     any exception carrying a non-None ``retry_after_s`` (a
     structured refusal), and for the wire clients' ``*Closed`` /
-    ``*Refused`` taxonomy by name — so the helper needs no import
+    ``*Refused`` family by name — so the helper needs no import
     of every protocol's exception classes.
     """
     if isinstance(exc, (OSError, TimeoutError, ConnectionError)):

@@ -22,6 +22,14 @@ delegates unknown attributes to the wrapped function, so
 ``.lower()``/``.clear_cache()`` and the chunk-program attribute
 conventions (``search.run_sims``) keep working.
 
+DEVICE RECORD — :func:`device_record` names the device a process is
+running on (platform, ``device_kind``, count), the installed
+jax/jaxlib/libtpu and the engine formulation the platform selected.
+``run_training`` and the gateway / GTP ``main``s log it once at start
+as a ``device`` event, so every run's artifacts say where they were
+made — a CPU run can never pass for a chip run (``chip_smoke.py``
+asserts on this record).
+
 PROFILER CAPTURE — ``maybe_start_profiler()`` starts a
 ``jax.profiler`` trace into a directory given explicitly (trainer
 ``--profile-dir`` flags) or via :data:`PROFILE_ENV`; no-op otherwise,
@@ -117,6 +125,30 @@ def track(entry: str, fn=None, registry=None):
     if fn is None:
         return lambda f: TrackedFunction(entry, f, registry)
     return TrackedFunction(entry, fn, registry)
+
+
+# --------------------------------------------------- device record
+
+def device_record() -> dict:
+    """Where this process runs, as JAX reports it: the fields of the
+    one ``device`` event each entry point logs at start."""
+    import importlib.metadata
+
+    import jax
+    import jaxlib
+
+    from rocalphago_tpu.engine.jaxgo import engine_formulation
+
+    try:
+        libtpu = importlib.metadata.version("libtpu")
+    except importlib.metadata.PackageNotFoundError:
+        libtpu = None
+    devices = jax.devices()
+    return {"platform": devices[0].platform,
+            "device_kind": devices[0].device_kind,
+            "count": len(devices),
+            "jax": jax.__version__, "jaxlib": jaxlib.__version__,
+            "libtpu": libtpu, "engine": engine_formulation()}
 
 
 # ------------------------------------------------ profiler capture

@@ -1,7 +1,6 @@
 """Pallas TPU kernels for the framework's hot primitives (opt-in;
 the XLA formulations remain the defaults — see ops.labels)."""
 
-from rocalphago_tpu.ops.chase import pallas_chase
 from rocalphago_tpu.ops.labels import pallas_labels
 
-__all__ = ["pallas_chase", "pallas_labels"]
+__all__ = ["pallas_labels"]

@@ -29,10 +29,12 @@ Design notes:
   its own 8-board block, unlike the XLA path's batch-global fixpoint.
 
 The kernel is exact but OPT-IN: the default engine path stays on the
-XLA ``while_loop`` (early exit usually wins on sparse boards, and the
-attached TPU backend is experimental). ``benchmarks/bench_labels.py``
-compares both; flipping the engine over is a one-line change in
-``jaxgo.compute_labels`` if measurements favor the kernel.
+XLA ``while_loop`` (early exit usually wins on sparse boards).
+``benchmarks/bench_labels.py`` compares both and
+``scripts/chip_kernels.py`` checks it on the chip (compiled by Mosaic
+on the v5e and bit-equal to XLA at 19×19, PR 21); flipping the engine
+over is a one-line change in ``jaxgo.compute_labels`` if measurements
+favor the kernel.
 """
 
 from __future__ import annotations

@@ -30,20 +30,6 @@ DATA_AXIS = "data"
 MODEL_AXIS = "model"
 
 
-def cpu_collectives_available() -> bool:
-    """Whether this jaxlib ships gloo TCP collectives for the CPU
-    backend. Without them a multi-process CPU bring-up constructs a
-    client whose collectives raise ``Multiprocess computations aren't
-    implemented on the CPU backend`` at the first cross-process op —
-    the capability the CPU DCN test keys its skip on."""
-    try:
-        import jaxlib.xla_extension as _xe
-
-        return hasattr(_xe, "make_gloo_tcp_collectives")
-    except Exception:  # noqa: BLE001 — capability probe must not raise
-        return False
-
-
 def distributed_init(coordinator: str | None = None,
                      num_processes: int | None = None,
                      process_id: int | None = None) -> None:
@@ -51,22 +37,14 @@ def distributed_init(coordinator: str | None = None,
 
     On Cloud TPU pods the arguments are auto-detected from the
     environment; pass them explicitly elsewhere. Multi-process CPU
-    runs (the localhost DCN test, CPU-only actor fleets) need a real
-    collectives transport — the default CPU client has none and fails
-    at the first cross-process op — so gloo is selected here whenever
-    the installed jaxlib ships it.
+    runs (the localhost DCN test, CPU-only actor fleets) ride jax's
+    default CPU collectives, gloo over TCP.
     """
     multiproc = (num_processes is not None and num_processes > 1
                  or coordinator is not None
                  or int(os.environ.get("JAX_NUM_PROCESSES", "1")) > 1)
     if not multiproc:
         return
-    if cpu_collectives_available():
-        try:
-            jax.config.update(
-                "jax_cpu_collectives_implementation", "gloo")
-        except Exception:  # noqa: BLE001 — older jax without the knob
-            pass
     if num_processes is not None and num_processes > 1 or (
             coordinator is not None):
         jax.distributed.initialize(

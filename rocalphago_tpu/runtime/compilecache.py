@@ -1,61 +1,51 @@
-"""Shared persistent-XLA-compile-cache setup for every entry point.
+"""Where JAX's persistent compilation cache lives — one rule, one place.
 
-The round-5 headline regression was partly ``includes_compile: true``:
-the driver's bench capture paid a cold 20–40s compile because only
-``bench.py`` and the test suite configured JAX's persistent
-compilation cache — the trainers, the self-play CLI and the GTP
-server each recompiled their programs from scratch on every launch.
-This helper is the one place that knob lives now; every CLI calls
-:func:`enable_compile_cache` at startup, so repeat runs of the SAME
-program (the common operational case: resumed trainers, re-launched
-benches, restarted GTP engines) skip compile entirely.
+Every entry point (trainers, self-play CLI, GTP engine, gateway,
+``bench.py``, the ``benchmarks/`` harness, ``chip_smoke.py``'s legs and
+the test suite's conftest) calls :func:`enable_compile_cache` before its
+first compile, so a re-launch of the SAME program loads its executables
+instead of compiling them again.
 
-Env knob ``ROCALPHAGO_COMPILE_CACHE``:
+The rule:
 
-* unset (default) → ``~/.cache/jax_comp_cache``;
-* a path → that directory;
-* ``0`` / ``off`` / ``none`` → disabled (no config touched).
+* ``JAX_COMPILATION_CACHE_DIR`` set in the environment → the cache is
+  placed from outside. JAX reads that variable itself; this code sets
+  no directory and touches no cache option.
+* unset → ``<checkout>/.jax_cache``: a FIXED, git-ignored path next to
+  the package. The directory is part of what a run on a fresh machine
+  can find again, so it never comes from ``tempfile``, a pid or the
+  clock.
 
-First configuration wins: if the process has already pinned a cache
-directory (the test suite's conftest, an operator's explicit
-``jax.config`` call), the helper leaves it alone — re-pointing the
-cache mid-process would split one run's compiles across two caches.
-
-Note the JAX CPU backend does not serialize executables to this cache
-(measured no-op — scripts/test.sh); the payoff is on TPU, where the
-big self-play/search programs cost 20–40s each to compile.
+There is no knob of this repo's own: JAX's switches
+(``JAX_ENABLE_COMPILATION_CACHE=false``,
+``JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS``, …) cover "off" and the
+thresholds.
 """
 
 from __future__ import annotations
 
 import os
 
-ENV = "ROCALPHAGO_COMPILE_CACHE"
-DEFAULT_DIR = "~/.cache/jax_comp_cache"
-_OFF = ("0", "off", "none", "disable", "disabled")
+ENV = "JAX_COMPILATION_CACHE_DIR"
+#: the fixed in-checkout default (``rocalphago_tpu/runtime/`` is two
+#: levels below the checkout root)
+CHECKOUT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
 
-def enable_compile_cache(min_compile_secs: int = 5) -> str | None:
-    """Point JAX's persistent compilation cache at the configured
-    directory; returns the active cache dir (existing or newly set),
-    or None when disabled/unavailable. Safe to call from any entry
-    point, any number of times."""
-    raw = os.environ.get(ENV)
-    if raw is not None and raw.strip().lower() in _OFF:
-        return None
-    import jax
+def cache_dir() -> str:
+    """The directory the rule above puts the cache in (jax-free: the
+    smoke's parent process reads it to count entries)."""
+    return os.environ.get(ENV) or CHECKOUT_CACHE_DIR
 
-    try:
-        current = jax.config.jax_compilation_cache_dir
-    except AttributeError:      # very old jax: no such config at all
-        return None
-    if current:
-        return current          # first configuration wins
-    path = os.path.expanduser(raw or DEFAULT_DIR)
-    try:
-        jax.config.update("jax_compilation_cache_dir", path)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          min_compile_secs)
-    except Exception:  # noqa: BLE001 — older jax without the knobs
-        return None
-    return path
+
+def enable_compile_cache() -> str:
+    """Apply the rule above; returns the directory in effect. Safe to
+    call from any entry point, any number of times."""
+    if not os.environ.get(ENV):
+        import jax
+
+        jax.config.update("jax_compilation_cache_dir",
+                          CHECKOUT_CACHE_DIR)
+    return cache_dir()

@@ -38,8 +38,8 @@ Depth: ``depth`` = how many dispatched-but-unretired chunks the host
 may run ahead. ``depth=0`` reproduces today's fully synchronous
 behavior (every ``push`` blocks on the chunk just pushed);
 ``depth=1`` (the default) keeps one chunk in flight. The default is
-env-overridable via ``ROCALPHAGO_PIPELINE_DEPTH`` so the TPU window
-hunter can A/B without code changes.
+env-overridable via ``ROCALPHAGO_PIPELINE_DEPTH`` so a sweep can A/B
+without code changes.
 
 Donation: pipelining must not double slab memory — the chunk loops
 donate their big device-resident carries (DeviceTree slabs, self-play
@@ -74,7 +74,7 @@ DEFAULT_DEPTH = 1
 def default_depth() -> int:
     """The process-default pipeline depth: ``$ROCALPHAGO_PIPELINE_
     DEPTH`` if set (0 = sync), else :data:`DEFAULT_DEPTH`. Read at
-    call time so tests and the TPU hunter can flip it per run."""
+    call time so tests and sweeps can flip it per run."""
     raw = os.environ.get(DEPTH_ENV, "").strip()
     if not raw:
         return DEFAULT_DEPTH

@@ -1,27 +1,65 @@
 """The headline benchmark's adaptive TPU sizing path, exercised on CPU.
 
-Round-1 postmortem: bench.py failures are invisible until the driver's
-round-end run on real hardware, so the risky code path — the mid-game
-probe that picks batch/chunk — must be covered off-chip. The
-``_GRAFT_BENCH_FORCE_ADAPTIVE`` hook runs it on the CPU backend with
-shrunken workloads.
+bench.py failures are otherwise invisible until a run on real
+hardware, so the risky code path — the mid-game probe that picks
+batch/chunk — is covered off-chip: the ``_GRAFT_BENCH_FORCE_ADAPTIVE``
+hook runs ``_measure`` in-process on the CPU backend with shrunken
+workloads. The CLI itself (``python bench.py``) refuses a non-TPU
+backend outright.
 """
 
 import io
 import json
 import os
+import subprocess
 import sys
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def test_bench_cli_refuses_without_a_chip():
+    """``python bench.py`` off-TPU: nonzero exit, nothing on stdout —
+    no fallback may print a CPU number under the device metric's
+    name (how the driver's old captures came to be CPU figures)."""
+    proc = subprocess.run(
+        [sys.executable, os.path.join(REPO, "bench.py")],
+        env=dict(os.environ, JAX_PLATFORMS="cpu"), cwd=REPO,
+        capture_output=True, text=True, timeout=120)
+    assert proc.returncode != 0
+    assert proc.stdout.strip() == ""
+    assert "platform 'cpu'" in proc.stderr
 
 import pytest
 
 
+def test_harness_peak_table_refuses_unknown_device(monkeypatch):
+    """benchmarks/_harness.py: the bf16 peak is keyed by device_kind;
+    a TPU that is not in the table raises instead of borrowing the
+    v5e's peak (an MFU against a guessed peak is worse than none),
+    and off-TPU there is no peak at all."""
+    monkeypatch.syspath_prepend(REPO)
+    from benchmarks import _harness
+
+    class Dev:
+        platform = "tpu"
+        device_kind = "TPU v5 lite"
+
+    monkeypatch.setattr(_harness.jax, "devices", lambda: [Dev])
+    assert _harness.bf16_peak_flops() == 197e12
+    assert _harness.mfu(19.7e12) == pytest.approx(0.1)
+    Dev.device_kind = "TPU v9 mystery"
+    with pytest.raises(KeyError, match="TPU v9 mystery"):
+        _harness.bf16_peak_flops()
+    Dev.platform, Dev.device_kind = "cpu", "cpu"
+    assert _harness.bf16_peak_flops() is None
+    assert _harness.mfu(1e12) is None
+
+
 def test_honest_metric_suffixes(monkeypatch):
-    """The headline honesty rules (VERDICT r5 #2) in one table: a
-    truncated or contended run reports under a suffixed metric name,
-    and NO compromised measurement (truncated, compile-included,
-    contended) emits a vs_baseline ratio — the exact hole that let
-    round 5 publish 1.81 games/min at vs_baseline 0.145 with
-    includes_compile true."""
+    """The headline honesty rules in one table: a truncated or
+    contended run reports under a suffixed metric name, and NO
+    compromised measurement (truncated, compile-included, contended)
+    emits a vs_baseline ratio."""
     monkeypatch.syspath_prepend(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
     import bench
@@ -41,8 +79,7 @@ def test_honest_metric_suffixes(monkeypatch):
     name, vs = bench._honest_metric(m, 10.0, 12.5, truncated=False,
                                     includes_compile=True,
                                     contended=False)
-    # compile-polluted runs suffix too (the r5 leak published the
-    # headline name with includes_compile true)
+    # compile-polluted runs suffix too
     assert name == m + "_compiled" and vs is None
     name, vs = bench._honest_metric(m, 10.0, 12.5, truncated=True,
                                     includes_compile=True,
@@ -68,8 +105,7 @@ def test_host_contention_reading(monkeypatch):
 def test_warmup_compiles_exactly_the_timed_programs():
     """run.warmup must leave a subsequent full rep with ZERO segment
     compiles — the exact-program warmup discipline that keeps the
-    headline row at includes_compile: false (the r5 leak was a
-    full-rep warmup starving the timed reps instead)."""
+    headline row at includes_compile: false."""
     import jax
 
     from rocalphago_tpu.engine.jaxgo import GoConfig
@@ -115,7 +151,7 @@ def test_adaptive_bench_measure_runs_and_reports(monkeypatch):
     rec = json.loads(lines[-1])
     # 12-ply games are truncated: the record must carry its own
     # metric name — never the full-game headline's — and no ratio
-    # against the full-game north star (VERDICT r2/r3)
+    # against the full-game north star
     assert rec["metric"] == bench.METRIC + "_truncated"
     assert rec["load_1m"] == 0.1 and "contended" not in rec
     assert rec["unit"] == "games/min"
@@ -129,8 +165,8 @@ def test_adaptive_bench_measure_runs_and_reports(monkeypatch):
 
 @pytest.mark.slow
 def test_fixed_override_ignored_off_tpu(monkeypatch):
-    """_GRAFT_BENCH_FIXED must not leak into a CPU child: a TPU-sized
-    batch on host would blow the liveness fallback's budget."""
+    """_GRAFT_BENCH_FIXED must not leak into a CPU run: a TPU-sized
+    batch on host would take hours."""
     monkeypatch.setenv("_GRAFT_BENCH_FIXED", "1024,10")
     monkeypatch.setenv("_GRAFT_BENCH_MAX_MOVES", "4")
     monkeypatch.syspath_prepend(os.path.dirname(os.path.dirname(
@@ -230,9 +266,9 @@ def test_self_size_from_results(tmp_path, monkeypatch):
     assert bench._self_size_from_results() is None
 
 
-def test_bench_report_tables_and_probe_stats(tmp_path, monkeypatch):
+def test_bench_report_tables(tmp_path, monkeypatch):
     """scripts/bench_report.py: latest-record-per-config selection,
-    date/platform filters, probe-window extraction."""
+    date/platform filters."""
     monkeypatch.syspath_prepend(os.path.join(os.path.dirname(
         os.path.dirname(os.path.abspath(__file__))), "scripts"))
     import bench_report
@@ -402,34 +438,6 @@ def test_bench_report_tables_and_probe_stats(tmp_path, monkeypatch):
     assert ("| selfplay_cap_games_per_min | 582.5 | games/min | 9 | — "
             "| — | — | — | — | — | — | 0.25 | 16.7% | — | — | batch=8 |"
             in table)
-
-    probe = tmp_path / "probe.log"
-    probe.write_text(
-        "probe rc=124 [01:00:00]\n"
-        "probe rc=0 [01:02:00]\nprobe rc=3 [01:04:00]\n"
-        "probe rc=124 [01:06:00]\n"
-        "probe rc=0 [01:10:00]\n")
-    s = bench_report.probe_stats([str(probe)])
-    assert s["probes"] == 5 and s["up"] == 3
-    assert s["windows"] == 2
-    assert s["window_spans_s"] == [120, 0]
-
-
-def test_probe_stats_midnight_and_file_boundaries(tmp_path, monkeypatch):
-    monkeypatch.syspath_prepend(os.path.join(os.path.dirname(
-        os.path.dirname(os.path.abspath(__file__))), "scripts"))
-    import bench_report
-
-    a = tmp_path / "a.probe.log"
-    a.write_text("probe rc=0 [23:50:00]\nprobe rc=0 [00:20:00]\n")
-    b = tmp_path / "b.probe.log"
-    b.write_text("probe rc=0 [00:21:00]\n")
-    s = bench_report.probe_stats([str(a), str(b)])
-    # midnight wrap inside one file: one 30-min window, not clamped 0;
-    # file boundary: b's window is separate, never stitched onto a's
-    assert s["windows"] == 2
-    assert s["window_spans_s"] == [1800, 0]
-    assert s["probes"] == 3 and s["up"] == 3
 
 
 def test_zero_curve_summary(tmp_path, monkeypatch):

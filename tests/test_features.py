@@ -261,6 +261,9 @@ class TestLadderDifferential:
             f"device ladder reader disagrees with the full-branching "
             f"oracle on {rate:.2%} of cells (bound 1%)")
 
+    @pytest.mark.slow   # compiles a 19×19 encode (~20 s of a tier-1
+    # with no spare time); chip_smoke.py's verify phase holds the
+    # same 1% bound at 19×19 on the chip on every PR
     def test_dense_19x19_disagreement_rate_bounded(self):
         """Crowded 19×19 boards are where the bounded chase-slot
         capacity could bite (uniform-random 200-ply boards carry 2–11

@@ -88,7 +88,7 @@ class _Refused(Exception):
         self.retry_after_s = retry_after_s
 
 
-def test_default_transient_taxonomy():
+def test_default_transient_classes():
     class SomethingClosed(Exception):
         pass
 
@@ -99,7 +99,7 @@ def test_default_transient_taxonomy():
     assert default_transient(ConnectionResetError())
     assert default_transient(_Refused(retry_after_s=2.0))
     assert default_transient(SomethingClosed())
-    # the *Refused/*Closed taxonomy is transient BY NAME, hint or not
+    # the *Refused/*Closed family is transient BY NAME, hint or not
     assert default_transient(_Refused(retry_after_s=None))
     assert not default_transient(ValueError("typo"))
     assert not default_transient(Shed())

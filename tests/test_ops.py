@@ -1,21 +1,20 @@
-"""Pallas kernel differential tests (interpret mode on CPU CI).
+"""Pallas kernel differential tests (interpret mode on CPU CI; the
+same check at 19×19 with ``interpret=False`` is
+``scripts/chip_kernels.py``, run on the chip).
 
-The kernels must be drop-in exact against their XLA twins; adversarial
+The kernel must be drop-in exact against its XLA twin; adversarial
 shapes (the serpentine worst case that maximizes label-propagation
 distance) are included so the static sweep bound is exercised, not
 just typical sparse boards.
 """
 
-import functools
-
 import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from rocalphago_tpu.engine import pygo
 from rocalphago_tpu.engine.jaxgo import GoConfig, compute_labels
-from rocalphago_tpu.ops import pallas_chase, pallas_labels
+from rocalphago_tpu.ops import pallas_labels
 
 SIZE = 9
 N = SIZE * SIZE
@@ -60,147 +59,6 @@ def test_pallas_labels_match_xla_on_random_boards(moves):
     got = np.asarray(pallas_labels(boards, SIZE, interpret=True))
     want = np.asarray(xla_labels(boards))
     np.testing.assert_array_equal(got, want)
-
-
-def chase_lanes(seed, positions=24, moves_lo=8, moves_hi=40):
-    """Chase entries via the SAME harvest the chase benchmark uses
-    (``benchmarks/_harness.py``) so test and bench always exercise the
-    exact entry contract the ladder planes hand to the chase."""
-    import os
-    import sys
-
-    # repo root derived from this file, not cwd, so the import works
-    # from any pytest invocation directory
-    sys.path.insert(0, os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    from benchmarks._harness import harvest_chase_lanes
-
-    return harvest_chase_lanes(SIZE, lanes=None, seed=seed,
-                               moves_lo=moves_lo, moves_hi=moves_hi,
-                               positions=positions)
-
-
-@pytest.mark.slow
-def test_pallas_chase_matches_xla_on_random_entries():
-    from rocalphago_tpu.features.ladders import _chase
-
-    cfg = GoConfig(size=SIZE)
-    boards, labels, preys = chase_lanes(seed=3)
-    assert len(preys) >= 20
-    xla = jax.jit(jax.vmap(functools.partial(
-        _chase, cfg, depth=40, enabled=True)))
-    want = np.asarray(xla(jnp.asarray(boards), jnp.asarray(labels),
-                          jnp.asarray(preys)))
-    prey_oh = (np.arange(N)[None, :] == preys[:, None])
-    got = np.asarray(pallas_chase(
-        jnp.asarray(boards), jnp.asarray(labels),
-        jnp.asarray(prey_oh), SIZE, depth=40, interpret=True))
-    np.testing.assert_array_equal(got, want)
-    # the harvest must include both outcomes or the test proves little
-    assert want.any() and not want.all()
-
-
-@pytest.mark.slow
-def test_pallas_chase_under_vmap_matches_unbatched():
-    """Every production call site reaches the kernel through the
-    encoder's jax.vmap over games (the pallas_call batching rule
-    prepends a grid dim) — pin that path, not just the flat one."""
-    from rocalphago_tpu.features.ladders import _chase
-
-    cfg = GoConfig(size=SIZE)
-    boards, labels, preys = chase_lanes(seed=9, positions=30)
-    g = 3                                 # games × lanes
-    lanes = (len(preys) // g) * g
-    assert lanes >= 2 * g
-    shape_b = (g, lanes // g, N)
-    vb = jnp.asarray(boards[:lanes]).reshape(shape_b)
-    vl = jnp.asarray(labels[:lanes]).reshape(shape_b)
-    oh = (np.arange(N)[None, :] == preys[:lanes, None]).reshape(shape_b)
-
-    batched = jax.vmap(lambda b, l, p: pallas_chase(
-        b, l, p, SIZE, depth=40, interpret=True))(vb, vl,
-                                                  jnp.asarray(oh))
-    xla = jax.jit(jax.vmap(functools.partial(
-        _chase, cfg, depth=40, enabled=True)))
-    want = np.asarray(xla(jnp.asarray(boards[:lanes]),
-                          jnp.asarray(labels[:lanes]),
-                          jnp.asarray(preys[:lanes])))
-    np.testing.assert_array_equal(
-        np.asarray(batched).reshape(-1), want)
-
-
-@pytest.mark.slow
-def test_pallas_chase_collect_core_matches_xla():
-    """The kernel's read-core accumulation (the incremental encoder's
-    footprint seed) must match the XLA chase's ``collect_core`` cell
-    for cell — captured verdicts too, since the tuple return shares
-    one while loop."""
-    from rocalphago_tpu.features.ladders import _chase
-
-    cfg = GoConfig(size=SIZE)
-    boards, labels, preys = chase_lanes(seed=7, positions=30)
-    xla = jax.jit(jax.vmap(functools.partial(
-        _chase, cfg, depth=40, enabled=True, collect_core=True)))
-    want_cap, want_core = xla(jnp.asarray(boards),
-                              jnp.asarray(labels),
-                              jnp.asarray(preys))
-    prey_oh = (np.arange(N)[None, :] == preys[:, None])
-    got_cap, got_core = pallas_chase(
-        jnp.asarray(boards), jnp.asarray(labels), jnp.asarray(prey_oh),
-        SIZE, depth=40, interpret=True, collect_core=True)
-    np.testing.assert_array_equal(np.asarray(got_cap),
-                                  np.asarray(want_cap))
-    np.testing.assert_array_equal(np.asarray(got_core),
-                                  np.asarray(want_core))
-    assert np.asarray(want_core).any()
-
-
-@pytest.mark.slow
-def test_pallas_chase_disabled_lane_is_false():
-    boards, labels, preys = chase_lanes(seed=5, positions=4)
-    zeros = np.zeros((len(preys), N), bool)
-    got = np.asarray(pallas_chase(
-        jnp.asarray(boards), jnp.asarray(labels), jnp.asarray(zeros),
-        SIZE, interpret=True))
-    assert not got.any()
-
-
-@pytest.mark.slow
-def test_chase_impl_flag_produces_identical_planes(monkeypatch):
-    """The ROCALPHAGO_PALLAS_CHASE=interpret path must yield the exact
-    same ladder planes as the default XLA chase (plane-level wiring of
-    the kernel, not just the raw chase)."""
-    from rocalphago_tpu.engine.jaxgo import (
-        from_pygo,
-        group_data,
-        legal_mask,
-    )
-    from rocalphago_tpu.features import ladders
-
-    cfg = GoConfig(size=SIZE)
-    rng = np.random.default_rng(11)
-    st = pygo.GameState(size=SIZE, komi=5.5)
-    for _ in range(30):
-        legal = st.get_legal_moves(include_eyes=False)
-        if not legal or st.is_end_of_game:
-            break
-        st.do_move(legal[rng.integers(len(legal))])
-    jst = from_pygo(cfg, st)
-    gd = group_data(cfg, jst.board, with_zxor=False)
-    legal = legal_mask(cfg, jst, gd)[:-1]
-
-    def planes():
-        return (np.asarray(ladders.ladder_capture_plane(
-                    cfg, jst, gd, legal)),
-                np.asarray(ladders.ladder_escape_plane(
-                    cfg, jst, gd, legal)))
-
-    monkeypatch.delenv("ROCALPHAGO_PALLAS_CHASE", raising=False)
-    cap_xla, esc_xla = planes()
-    monkeypatch.setenv("ROCALPHAGO_PALLAS_CHASE", "interpret")
-    cap_pal, esc_pal = planes()
-    np.testing.assert_array_equal(cap_xla, cap_pal)
-    np.testing.assert_array_equal(esc_xla, esc_pal)
 
 
 @pytest.mark.parametrize("size", [SIZE, 19])

@@ -330,26 +330,74 @@ def test_ladder_write_spec_never_clobbers_pool(tmp_path):
 
 # -------------------------------------------------- compile cache
 
-def test_compile_cache_env_off_and_first_config_wins(monkeypatch):
-    """runtime/compilecache.py: the shared persistent-cache helper
-    every CLI entry point calls is env-disableable
-    (``ROCALPHAGO_COMPILE_CACHE=off``) and NEVER re-points an
-    already-configured cache — the suite's conftest pins one, which
-    is exactly the first-config-wins case the helper must respect
-    (re-pointing mid-process would split one run's compiles across
-    two caches)."""
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _recorded_config_updates(monkeypatch):
     import jax
 
+    calls = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda name, value: calls.append((name, value)))
+    return calls
+
+
+def test_compile_cache_placed_from_outside_sets_nothing(monkeypatch):
+    """``JAX_COMPILATION_CACHE_DIR`` set → JAX's own handling of it is
+    the only configuration: the helper reports the directory and
+    makes NO ``jax.config.update`` call."""
     from rocalphago_tpu.runtime.compilecache import enable_compile_cache
 
-    for off in ("0", "off", "NONE", "disabled", " Off "):
-        monkeypatch.setenv("ROCALPHAGO_COMPILE_CACHE", off)
-        assert enable_compile_cache() is None
-    pinned = jax.config.jax_compilation_cache_dir
-    assert pinned                   # conftest configured the suite's
-    monkeypatch.setenv("ROCALPHAGO_COMPILE_CACHE", "/tmp/elsewhere")
-    assert enable_compile_cache() == pinned
-    assert jax.config.jax_compilation_cache_dir == pinned
+    calls = _recorded_config_updates(monkeypatch)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/placed/outside")
+    assert enable_compile_cache() == "/placed/outside"
+    assert calls == []
+
+
+def test_compile_cache_defaults_to_the_checkout(monkeypatch):
+    """Unset → ``<checkout>/.jax_cache``: one fixed, git-ignored path
+    (never from tempfile, a pid or the clock), and nothing but the
+    directory is configured."""
+    from rocalphago_tpu.runtime.compilecache import enable_compile_cache
+
+    calls = _recorded_config_updates(monkeypatch)
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    want = os.path.join(REPO, ".jax_cache")
+    assert enable_compile_cache() == want
+    assert enable_compile_cache() == want
+    assert calls == [("jax_compilation_cache_dir", want)] * 2
+    with open(os.path.join(REPO, ".gitignore")) as f:
+        assert ".jax_cache/" in f.read().split()
+
+
+def test_compile_cache_rule_has_one_home():
+    """The suite itself runs under the rule (conftest), no other file
+    configures a cache directory, and the repo's old knob is gone
+    from code and docs/KNOBS.md."""
+    import jax
+
+    from rocalphago_tpu.runtime.compilecache import cache_dir
+
+    assert jax.config.jax_compilation_cache_dir == cache_dir()
+    home = os.path.join("rocalphago_tpu", "runtime", "compilecache.py")
+    setters, old_knob = [], []
+    for top in ("rocalphago_tpu", "benchmarks", "scripts", "tests",
+                "docs", "bench.py", "chip_smoke.py",
+                "__graft_entry__.py"):
+        path = os.path.join(REPO, top)
+        files = [path] if os.path.isfile(path) else [
+            os.path.join(d, f) for d, _, fs in os.walk(path)
+            for f in fs if f.endswith((".py", ".md", ".sh"))]
+        for fp in files:
+            rel = os.path.relpath(fp, REPO)
+            with open(fp, errors="replace") as f:
+                text = f.read()
+            if '"jax_compilation_cache_dir"' in text and rel not in (
+                    home, os.path.join("tests", "test_runtime.py")):
+                setters.append(rel)
+            if "ROCALPHAGO_" + "COMPILE_CACHE" in text:
+                old_knob.append(rel)
+    assert setters == [] and old_knob == []
 
 
 # -------------------------------------------------------- deadline

@@ -306,7 +306,13 @@ def test_zero_actor_learner_cli_bit_exact(tmp_path, nets):
 def test_zero_iteration_sharded_matches_unsharded(nets):
     """Mesh wiring is placement + constraints only: one iteration on
     the virtual 8-device mesh must match the unsharded run
-    bit-for-bit (same rng, same math; XLA inserts the collectives)."""
+    bit-for-bit (same rng, same math; XLA inserts the collectives).
+    And the replay is traced once per distinct segment LENGTH, not
+    once per segment: every carry leaf enters the first segment
+    already committed to the mesh, so the second segment's inputs
+    (the first one's outputs) have the same types (on the v5e a
+    re-trace of the 19x19 replay program cost ~37 s per run, PR 21)."""
+    from rocalphago_tpu.io.checkpoint import unpack_rng
     from rocalphago_tpu.parallel import mesh as meshlib
 
     pol, val = nets
@@ -318,14 +324,25 @@ def test_zero_iteration_sharded_matches_unsharded(nets):
         cfg, FEATS, VFEATS, pol.module.apply, val.module.apply,
         tx_p, tx_v, **kw)
     mesh = meshlib.make_mesh(4)
+    traces = []                 # the Python body runs once per trace
+
+    def counted_value_apply(*a, **k):
+        traces.append(1)
+        return val.module.apply(*a, **k)
+
     sharded = make_zero_iteration(
-        cfg, FEATS, VFEATS, pol.module.apply, val.module.apply,
+        cfg, FEATS, VFEATS, pol.module.apply, counted_value_apply,
         tx_p, tx_v, mesh=mesh, **kw)
     s0 = init_zero_state(pol.params, val.params, tx_p, tx_v, seed=7)
     s0m = meshlib.replicate(mesh, init_zero_state(
         pol.params, val.params, tx_p, tx_v, seed=7))
     _, m1 = base(s0)
-    _, m2 = sharded(s0m)
+    # sharded(s0m), taken apart at its own play/learn seam
+    _, game_key = jax.random.split(unpack_rng(s0m.rng))
+    games = sharded.play(s0m.policy_params, s0m.value_params, game_key)
+    del traces[:]
+    _, m2 = sharded.learn(s0m, games)
+    assert len(traces) == 2     # segments of 8, 8 and 4 plies
     for k in m1:
         np.testing.assert_allclose(
             float(jax.device_get(m1[k])), float(jax.device_get(m2[k])),
