@@ -21,6 +21,12 @@ fresh weights from seed 0). Counts are cut; widths are not.
   device encode agrees with the host oracle (``features.pyfeatures``:
   exact off the ladder planes, under the tests' 1% bound on them) and
   both forwards give finite, normalized outputs.
+* **replay** — the device rules engine against the host's: uniform
+  random legal play through the program's ``vgroup_data``,
+  ``legal_mask`` and ``step`` under one ``lax.scan`` at 1,024 games
+  (the batch at which the vmapped step lost stones on the TPU,
+  PERF.md §7 row 2), then 16 sampled games replayed move by move on
+  ``pygo``: every dealt move legal there, every board equal.
 * **leg B, a server that answers a few requests** —
   ``python -m rocalphago_tpu.gateway.server --policy <out>/train/
   policy.json --value …`` serving the pair leg A exported; the jax-free
@@ -68,13 +74,15 @@ FLAGSHIP = dict(
     board=19, spec_args=[],
     train_args=["--game-batch", "8", "--sims", "8", "--move-limit", "16",
                 "--replay-chunk", "8", "--gate-games", "8"],
-    playouts=8, genmoves=6, verify_plies=(0, 12, 40, 90))
+    playouts=8, genmoves=6, verify_plies=(0, 12, 40, 90),
+    replay=dict(games=1024, plies=48, sampled=16))
 REHEARSAL = dict(
     board=9, spec_args=["--board", "9", "--layers", "2",
                         "--filters", "16"],
     train_args=["--game-batch", "4", "--sims", "4", "--move-limit", "8",
                 "--gate-games", "4"],
-    playouts=4, genmoves=4, verify_plies=(0, 6, 20))
+    playouts=4, genmoves=4, verify_plies=(0, 6, 20),
+    replay=dict(games=32, plies=24, sampled=4))
 
 
 class SmokeFailure(Exception):
@@ -192,6 +200,72 @@ def _child_verify(out: str, board: int, plies: list) -> int:
            and bool((abs(values) <= 1).all()),
            f"value outputs {values}")
     print(json.dumps({"verified_positions": len(states)}), flush=True)
+    return 0
+
+
+def _child_replay(board: int, games: int, plies: int,
+                  sampled: int) -> int:
+    """The device engine's own stepping against the host's rules, at
+    a batch the self-play programs really run."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from rocalphago_tpu.engine import jaxgo, pygo
+    from rocalphago_tpu.obs import jaxobs
+    from rocalphago_tpu.runtime.compilecache import enable_compile_cache
+    from rocalphago_tpu.utils.coords import unflatten_idx
+
+    enable_compile_cache()
+    print(json.dumps({"device": jaxobs.device_record()}), flush=True)
+    cfg = jaxgo.GoConfig(size=board, komi=7.5)
+    n = cfg.num_points
+    vgd = jaxgo.vgroup_data(cfg, with_zxor=cfg.enforce_superko)
+    vlegal = jax.vmap(functools.partial(jaxgo.legal_mask, cfg))
+    vstep = jax.vmap(functools.partial(jaxgo.step, cfg))
+
+    @jax.jit
+    def deal(key):
+        def ply(carry, _):
+            states, key = carry
+            key, sub = jax.random.split(key)
+            gd = vgd(states)
+            legal = vlegal(states, gd)[:, :-1]
+            action = jnp.where(
+                legal.any(-1),
+                jax.random.categorical(
+                    sub, jnp.where(legal, 0.0, -1e30), axis=-1),
+                n).astype(jnp.int32)
+            states = vstep(states, action, gd)
+            return (states, key), (action, states.board)
+
+        _, (actions, boards) = jax.lax.scan(
+            ply, (jaxgo.new_states(cfg, games), key), None,
+            length=plies)
+        return actions, boards
+
+    actions, boards = jax.device_get(deal(jax.random.key(0)))
+    picks = np.random.default_rng(0).choice(games, sampled,
+                                            replace=False)
+    for g in sorted(int(x) for x in picks):
+        st = pygo.GameState(size=board, komi=7.5)
+        for t in range(plies):
+            a = int(actions[t, g])
+            move = None if a >= n else unflatten_idx(a, board)
+            _check(move is None or st.is_legal(move),
+                   f"replay: game {g} ply {t}: the device dealt "
+                   f"{move}, which the host engine calls illegal")
+            st.do_move(move)
+            host = np.asarray(st.board, np.int8).reshape(-1)
+            bad = np.flatnonzero(host != boards[t, g])
+            _check(not len(bad),
+                   f"replay: game {g} ply {t} (action {a}): device "
+                   f"board differs from the host's at cells "
+                   f"{bad[:8].tolist()}")
+    print(json.dumps({"replayed_games": int(sampled), "plies": plies,
+                      "batch": games}), flush=True)
     return 0
 
 
@@ -386,6 +460,13 @@ class Smoke:
             "verify", self.out, str(self.size["board"]),
             json.dumps(list(self.size["verify_plies"]))])
 
+    def replay(self) -> None:
+        r = self.size["replay"]
+        self._run("replay", [
+            sys.executable, os.path.abspath(__file__), "--child",
+            "replay", str(self.size["board"]), str(r["games"]),
+            str(r["plies"]), str(r["sampled"])])
+
     def leg_b(self) -> None:
         from rocalphago_tpu.gateway.client import (
             GatewayClient,
@@ -474,7 +555,8 @@ class Smoke:
 
         t0 = time.monotonic()
         self.summary["cache"] = {"dir": cache, "before": entries()}
-        for phase in (self.specs, self.leg_a, self.verify, self.leg_b):
+        for phase in (self.specs, self.leg_a, self.verify, self.replay,
+                      self.leg_b):
             print(f"chip_smoke: {phase.__name__} ...", flush=True)
             phase()
             print(f"chip_smoke: {phase.__name__} ok", flush=True)
@@ -496,6 +578,8 @@ def main(argv=None) -> int:
         if a.child[0] == "specs":
             _, platform, out, spec_args = a.child
             return _child_specs(platform, out, json.loads(spec_args))
+        if a.child[0] == "replay":
+            return _child_replay(*(int(x) for x in a.child[1:]))
         _, out, board, plies = a.child
         return _child_verify(out, int(board), json.loads(plies))
 

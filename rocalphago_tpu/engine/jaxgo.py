@@ -725,7 +725,12 @@ def _step_place(cfg: GoConfig, state: GoState, action,
     captured = (gd.labels[:, None] == cap_roots[None, :]).any(axis=1)
     num_captured = captured.sum(dtype=jnp.int32)
 
-    board2 = jnp.where(captured, 0, board).at[action].set(me)
+    # a one-hot select, not ``.at[action].set(me)``: under vmap at
+    # 1,024 games the TPU's int8 scatter dropped every stone whose
+    # action index lay beyond the first one to three 91-cell windows
+    # (PERF.md §7 row 2; chip_smoke.py replays the engine on pygo)
+    board2 = jnp.where(jnp.arange(n) == action, me,
+                       jnp.where(captured, 0, board)).astype(board.dtype)
 
     # simple ko: lone new stone, exactly one capture, one liberty left
     placed_alone = ~(nbr_color == me).any()

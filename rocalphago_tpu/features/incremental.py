@@ -107,6 +107,7 @@ from rocalphago_tpu.features.planes import (
     assemble_planes,
     encode_analysis,
 )
+from rocalphago_tpu.obs import scopes
 
 #: default outcome-ring capacity. Ring retention must comfortably
 #: exceed the reuse distance or the cache sits in an eviction-forced
@@ -650,9 +651,10 @@ def encode_step(cfg: GoConfig, state: GoState, cache: EncodeCache,
                   chase_slots=ladder_chase_slots)
     lad_cap = lad_esc = None
     if "ladder_capture" in features and "ladder_escape" in features:
-        lad_cap, lad_esc, cache = ladder_planes_cached(
-            cfg, state, gd, legal, cache,
-            refresh_slots=refresh_slots, **lad_kw)
+        with jax.named_scope(scopes.ENCODE_LADDER):
+            lad_cap, lad_esc, cache = ladder_planes_cached(
+                cfg, state, gd, legal, cache,
+                refresh_slots=refresh_slots, **lad_kw)
     else:
         cache = cache._replace(board=state.board)
     cache = cache._replace(

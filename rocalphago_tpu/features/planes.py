@@ -36,6 +36,7 @@ from rocalphago_tpu.engine.jaxgo import (
     legal_mask,
     neighbors_for,
 )
+from rocalphago_tpu.obs import scopes
 
 
 class CandidateInfo(NamedTuple):
@@ -209,11 +210,12 @@ def encode_analysis(cfg: GoConfig, state: GoState, features: tuple,
                         with_zxor=cfg.enforce_superko,
                         labels=state.labels)
     ci = None
-    if needs_candidates(features):
-        ci = candidate_info(cfg, state, gd)
-        legal = ci.legal
-    else:
-        legal = legal_mask(cfg, state, gd)[:n]
+    with jax.named_scope(scopes.ENCODE_CANDIDATES):
+        if needs_candidates(features):
+            ci = candidate_info(cfg, state, gd)
+            legal = ci.legal
+        else:
+            legal = legal_mask(cfg, state, gd)[:n]
     return gd, ci, legal
 
 
@@ -228,6 +230,21 @@ def assemble_planes(cfg: GoConfig, state: GoState, features: tuple,
     so the two paths cannot drift plane-by-plane."""
     from rocalphago_tpu.features import ladders as _ladders
 
+    # a single-plane ladder request chases here, under its own scope
+    with jax.named_scope(scopes.ENCODE_LADDER):
+        if "ladder_capture" in features and lad_cap is None:
+            lad_cap = _ladders.ladder_capture_plane(
+                cfg, state, gd, legal, **lad_kw)
+        if "ladder_escape" in features and lad_esc is None:
+            lad_esc = _ladders.ladder_escape_plane(
+                cfg, state, gd, legal, **lad_kw)
+    with jax.named_scope(scopes.ENCODE_PLANES):
+        return _stack_planes(cfg, state, features, gd, ci, legal,
+                             lad_cap, lad_esc)
+
+
+def _stack_planes(cfg, state, features, gd, ci, legal, lad_cap,
+                  lad_esc) -> jax.Array:
     n = cfg.num_points
     board, me = state.board, state.turn
     empty = board == 0
@@ -253,15 +270,9 @@ def assemble_planes(cfg: GoConfig, state: GoState, features: tuple,
         elif name == "liberties_after":
             f = _one_hot8(ci.libs_after, 1, legal)
         elif name == "ladder_capture":
-            cap = (lad_cap if lad_cap is not None
-                   else _ladders.ladder_capture_plane(
-                       cfg, state, gd, legal, **lad_kw))
-            f = cap.astype(jnp.float32)[:, None]
+            f = lad_cap.astype(jnp.float32)[:, None]
         elif name == "ladder_escape":
-            esc = (lad_esc if lad_esc is not None
-                   else _ladders.ladder_escape_plane(
-                       cfg, state, gd, legal, **lad_kw))
-            f = esc.astype(jnp.float32)[:, None]
+            f = lad_esc.astype(jnp.float32)[:, None]
         elif name == "sensibleness":
             f = (legal & ~true_eyes(cfg, state, me)).astype(
                 jnp.float32)[:, None]
@@ -314,8 +325,9 @@ def encode(cfg: GoConfig, state: GoState,
     lad_kw = dict(depth=ladder_depth, lanes=ladder_lanes,
                   chase_slots=ladder_chase_slots)
     if "ladder_capture" in features and "ladder_escape" in features:
-        lad_cap, lad_esc = _ladders.ladder_planes(
-            cfg, state, gd, legal, **lad_kw)
+        with jax.named_scope(scopes.ENCODE_LADDER):
+            lad_cap, lad_esc = _ladders.ladder_planes(
+                cfg, state, gd, legal, **lad_kw)
     return assemble_planes(cfg, state, features, gd, ci, legal,
                            lad_cap, lad_esc, lad_kw)
 

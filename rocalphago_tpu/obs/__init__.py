@@ -36,6 +36,7 @@ from rocalphago_tpu.obs.registry import (  # noqa: F401
     timed,
 )
 from rocalphago_tpu.obs.trace import (  # noqa: F401
+    annotation,
     configure,
     current_path,
     emit,

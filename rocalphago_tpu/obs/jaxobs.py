@@ -42,7 +42,6 @@ module stays stdlib-cheap.
 from __future__ import annotations
 
 import atexit
-import contextlib
 import os
 import sys
 import time
@@ -183,14 +182,3 @@ def stop_profiler() -> None:
     out, _profiling["dir"] = _profiling["dir"], None
     jax.profiler.stop_trace()
     _trace.emit("profiler", action="stop", out_dir=out)
-
-
-@contextlib.contextmanager
-def profiler_session(out_dir: str | None = None):
-    """Context-manager form of the start/stop pair."""
-    started = maybe_start_profiler(out_dir)
-    try:
-        yield started
-    finally:
-        if started:
-            stop_profiler()

@@ -283,16 +283,23 @@ log_to = REGISTRY.log_to
 reset = REGISTRY.reset
 
 
-def timed(iterable, hist: Histogram):
+def timed(iterable, hist: Histogram, annotate: str | None = None):
     """Yield from ``iterable`` recording each ``next()`` wait into
     ``hist`` — the data-starvation probe the trainers wrap their
     prefetch iterators with (host wait per batch; near-zero when the
-    pipeline keeps up)."""
+    pipeline keeps up). ``annotate`` names the same wait on the
+    profiler's clock (``obs.trace.annotation``)."""
+    from rocalphago_tpu.obs import trace
+
     it = iter(iterable)
     while True:
         t0 = time.monotonic()
         try:
-            x = next(it)
+            if annotate is None:
+                x = next(it)
+            else:
+                with trace.annotation(annotate):
+                    x = next(it)
         except StopIteration:
             return
         hist.observe(time.monotonic() - t0)

@@ -1,0 +1,71 @@
+"""The names of the ``jax.named_scope``s inside the device programs.
+
+A scope is HLO metadata: it changes no computation and costs nothing
+at run time, and it is what lets a profiler trace put device time
+down to a part of the program (``chipbench/scopes.py`` reduces a
+trace by these names; docs/OBSERVABILITY.md lists who reads which).
+Every call site imports its constant from here — a scope that is a
+string literal elsewhere is a scope no reader can rely on
+(``tests/test_scopes.py`` lowers the owning program for each name).
+
+The networks need no entry: Flax scopes every module by its name
+(``PolicyNet/trunk/conv3``), and JAX wraps those in ``jvp(…)`` on the
+forward and ``transpose(jvp(…))`` on the backward pass of a gradient.
+"""
+
+from __future__ import annotations
+
+# ---- the train step (training/sl.py, value.py, rl.py)
+#: the input cast and the on-device dihedral augmentation
+TRAIN_AUGMENT = "train.augment"
+#: the loss after the network's ``apply_fn`` (cross-entropy / MSE /
+#: REINFORCE weighting, accuracy)
+TRAIN_LOSS = "train.loss"
+#: the optimizer: ``tx.update`` + ``apply_updates``
+TRAIN_UPDATE = "train.update"
+
+# ---- one self-play ply (search/selfplay.py::_make_ply)
+#: the shared group analysis (``vgroup_data``)
+PLY_GROUPS = "ply.groups"
+#: feature planes from states (``features/``; holds the ``encode.*``)
+PLY_ENCODE = "ply.encode"
+#: both half-batch network forwards and the half swap
+PLY_FORWARD = "ply.forward"
+#: sensibleness mask, temperature, categorical draw
+PLY_SAMPLE = "ply.sample"
+#: the rules step (``engine/jaxgo.py::step`` under vmap)
+PLY_STEP = "ply.step"
+
+# ---- the stages of any encode (features/planes.py, incremental.py)
+#: the per-candidate-move analysis (``encode_analysis``): captures,
+#: merged groups and liberties after each move, and legality — what
+#: the capture-size, self-atari and liberties-after planes read
+ENCODE_CANDIDATES = "encode.candidates"
+#: the ladder capture/escape planes: the bounded chase
+ENCODE_LADDER = "encode.ladder"
+#: stacking the plane groups (``assemble_planes``): stones, ages,
+#: liberties, the one-hots of the analysis, sensibleness (true eyes)
+ENCODE_PLANES = "encode.planes"
+
+# ---- a leaf evaluation (search/device_mcts.py::eval_batch*)
+#: the group analysis of the leaves
+EVAL_GROUPS = "eval.groups"
+#: feature planes for both nets
+EVAL_ENCODE = "eval.encode"
+#: the policy forward, masking and softmax to priors
+EVAL_POLICY = "eval.policy"
+#: the value forward (and terminal overrides)
+EVAL_VALUE = "eval.value"
+
+# ---- one tree simulation (search/device_mcts.py)
+#: the PUCT descent to a leaf (``_descend_one`` via ``prepare_sim``)
+MCTS_SELECT = "mcts.select"
+#: stepping the leaf's state and writing the new node (``apply_sim``
+#: up to the backup)
+MCTS_EXPAND = "mcts.expand"
+#: the value's walk back to the root (``_backup_one``)
+MCTS_BACKUP = "mcts.backup"
+
+#: every name above, for tests and readers
+ALL = tuple(v for k, v in sorted(globals().items())
+            if k.isupper() and isinstance(v, str))

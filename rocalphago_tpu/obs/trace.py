@@ -28,10 +28,23 @@ One process = one sink: trainers and the GTP CLI call
 :func:`configure` right after building their ``MetricsLogger``.
 Library code just opens spans — unconfigured processes pay ~1µs per
 span and write nothing.
+
+The profiler's clock: a span also opens a
+``jax.profiler.TraceAnnotation("rocalphago.<name>")``, so whatever
+profiler capture is running (``--profile-dir``,
+``ROCALPHAGO_JAX_PROFILE``, the chip benchmark's traced window) shows
+every span on the host plane beside the device's operations, and an
+idle gap on the chip can be laid beside what the host was doing
+(``chipbench/scopes.py`` labels gaps by these names). Only when
+``jax`` is already imported — this module never imports it — and with
+no capture running it is one no-op C++ call each way. Per-item hot
+paths take the annotation alone (:func:`annotation`).
 """
 
 from __future__ import annotations
 
+import contextlib
+import sys
 import threading
 import time
 
@@ -43,9 +56,28 @@ _names: dict = {}         # guarded-by: _lock — ident -> thread name
 _sink = None
 _enabled = True
 
+#: prefix of a span's name on the profiler's host plane
+ANNOTATION_PREFIX = "rocalphago."
+_NO_ANNOTATION = contextlib.nullcontext()
+
 
 class _Frame:
     __slots__ = ("name", "path", "t0", "wall0")
+
+
+def annotation(name: str):
+    """``with annotation("session.wait_eval"):`` — the profiler-only
+    half of a span: a ``TraceAnnotation("rocalphago.<name>")`` on the
+    profiler's clock, and nothing where ``jax`` is not loaded (the
+    jax-free clients and tools stay jax-free). No record, no stack,
+    no lock: for per-item hot paths (a simulation, a train step),
+    where a JSONL record each would flood ``metrics.jsonl`` and
+    :func:`where` gains nothing. Phases use :class:`span`."""
+    jax = sys.modules.get("jax")
+    profiler = getattr(jax, "profiler", None)
+    if profiler is None:
+        return _NO_ANNOTATION
+    return profiler.TraceAnnotation(ANNOTATION_PREFIX + name)
 
 
 def configure(metrics=None, enabled: bool = True) -> None:
@@ -82,7 +114,7 @@ class span:
     an ``error`` string (the exception is NOT swallowed).
     """
 
-    __slots__ = ("name", "tags", "_frame", "_ident")
+    __slots__ = ("name", "tags", "_frame", "_ident", "_ann")
 
     def __init__(self, name: str, **tags):
         self.name = name
@@ -90,6 +122,8 @@ class span:
         self._frame = None
 
     def __enter__(self) -> "span":
+        self._ann = annotation(self.name)
+        self._ann.__enter__()
         f = _Frame()
         f.t0 = time.monotonic()
         f.wall0 = time.time()
@@ -128,6 +162,7 @@ class span:
             fields["error"] = f"{et.__name__}: {ev}"
         fields.update(self.tags)
         emit("span", **fields)
+        self._ann.__exit__(et, ev, tb)
         return False
 
 

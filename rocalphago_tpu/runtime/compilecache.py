@@ -10,11 +10,15 @@ The rule:
 
 * ``JAX_COMPILATION_CACHE_DIR`` set in the environment → the cache is
   placed from outside. JAX reads that variable itself; this code sets
-  no directory and touches no cache option.
+  no directory.
 * unset → ``<checkout>/.jax_cache``: a FIXED, git-ignored path next to
   the package. The directory is part of what a run on a fresh machine
   can find again, so it never comes from ``tempfile``, a pid or the
   clock.
+
+One cache option IS set, in every case: metadata is part of the key
+(see :func:`enable_compile_cache`), so an edit that only renames a
+``jax.named_scope`` — or moves a traced line — compiles once more.
 
 There is no knob of this repo's own: JAX's switches
 (``JAX_ENABLE_COMPILATION_CACHE=false``,
@@ -43,9 +47,16 @@ def cache_dir() -> str:
 def enable_compile_cache() -> str:
     """Apply the rule above; returns the directory in effect. Safe to
     call from any entry point, any number of times."""
-    if not os.environ.get(ENV):
-        import jax
+    import jax
 
+    if not os.environ.get(ENV):
         jax.config.update("jax_compilation_cache_dir",
                           CHECKOUT_CACHE_DIR)
+    # a profiler trace is read by the HLO's metadata (the scope names
+    # of obs/scopes.py), which JAX leaves out of the cache key by
+    # default: a program would then load whichever executable with
+    # the same computation was compiled first — the parent commit's,
+    # say, without the names — and its trace would carry those
+    jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                      True)
     return cache_dir()

@@ -58,7 +58,12 @@ the device had NOTHING in flight — the idle the sync path pays per
 chunk), a ``device_occupancy{runner=...}`` gauge (1 − gap/wall over
 the pipeline's active windows) and ``dispatch_chunks_total``;
 ``scripts/obs_report.py`` renders them and the benches publish
-``host_gap_frac`` for the pipelined-vs-sync A/B.
+``host_gap_frac`` for the pipelined-vs-sync A/B. The same three
+intervals go on the profiler's clock (``obs.trace.annotation``):
+``pipeline.dispatch`` (a push's bookkeeping), ``pipeline.wait`` (a
+retire blocked on the device) and ``pipeline.host_work`` (the gap the
+histogram counts: nothing in flight until the next push) — so a
+device trace shows each gap beside the chip's own idle time.
 """
 
 from __future__ import annotations
@@ -66,6 +71,8 @@ from __future__ import annotations
 import os
 import time
 from collections import deque
+
+from rocalphago_tpu.obs import trace
 
 DEPTH_ENV = "ROCALPHAGO_PIPELINE_DEPTH"
 DEFAULT_DEPTH = 1
@@ -133,6 +140,7 @@ class ChunkPipeline:
         self.runner = runner
         self._inflight: deque = deque()
         self._gap_started = None     # queue drained mid-window
+        self._gap_ann = None         # the open gap on the profiler
         self._window_start = None
         self.chunks = 0
         self.gaps = 0
@@ -154,19 +162,21 @@ class ChunkPipeline:
         """Register a dispatched chunk; block until ≤ ``depth`` stay
         in flight; return the retired ``(payload, handle)`` pairs."""
         now = time.monotonic()
-        if self._window_start is None:
-            self._window_start = now
-        if self._gap_started is not None:
-            gap = now - self._gap_started
-            self._gap_started = None
-            self.gaps += 1
-            self.gap_s += gap
-            if self._gap_h is not None:
-                self._gap_h.observe(gap)
-        self._inflight.append((payload, handle))
-        self.chunks += 1
-        if self._chunks_c is not None:
-            self._chunks_c.inc()
+        self._close_gap_annotation()
+        with trace.annotation("pipeline.dispatch"):
+            if self._window_start is None:
+                self._window_start = now
+            if self._gap_started is not None:
+                gap = now - self._gap_started
+                self._gap_started = None
+                self.gaps += 1
+                self.gap_s += gap
+                if self._gap_h is not None:
+                    self._gap_h.observe(gap)
+            self._inflight.append((payload, handle))
+            self.chunks += 1
+            if self._chunks_c is not None:
+                self._chunks_c.inc()
         retired = []
         while len(self._inflight) > self.depth:
             retired.append(self._retire())
@@ -177,13 +187,21 @@ class ChunkPipeline:
         if handle is not None:
             import jax
 
-            jax.block_until_ready(handle)
+            with trace.annotation("pipeline.wait"):
+                jax.block_until_ready(handle)
         if not self._inflight:
             # nothing left in flight: the device is (potentially)
             # idle from here until the next push — that span is the
             # gap the pipeline exists to remove
             self._gap_started = time.monotonic()
+            self._gap_ann = trace.annotation("pipeline.host_work")
+            self._gap_ann.__enter__()
         return payload, handle
+
+    def _close_gap_annotation(self) -> None:
+        if self._gap_ann is not None:
+            self._gap_ann.__exit__(None, None, None)
+            self._gap_ann = None
 
     def pending(self) -> int:
         return len(self._inflight)
@@ -202,6 +220,7 @@ class ChunkPipeline:
         """Close the accounting window WITHOUT blocking the tail —
         the async (training) paths' natural end, where a downstream
         fetch syncs whatever is still in flight. Idempotent."""
+        self._close_gap_annotation()
         if self._window_start is None:
             return
         end = (self._gap_started if self._gap_started is not None
@@ -240,6 +259,7 @@ class ChunkPipeline:
             raise RuntimeError(
                 "reset_stats with chunks still in flight — drain() "
                 "first")
+        self._close_gap_annotation()
         self.chunks = self.gaps = 0
         self.gap_s = self.wall_s = 0.0
         self._window_start = self._gap_started = None

@@ -66,7 +66,7 @@ from rocalphago_tpu.features.incremental import (
 )
 from rocalphago_tpu.features.planes import batched_encoder, needs_member
 from rocalphago_tpu.features.pyfeatures import output_planes
-from rocalphago_tpu.obs import jaxobs
+from rocalphago_tpu.obs import jaxobs, scopes
 from rocalphago_tpu.obs import registry as obs_registry
 from rocalphago_tpu.runtime import faults
 from rocalphago_tpu.runtime.pipeline import ChunkPipeline
@@ -207,25 +207,37 @@ def make_device_mcts(cfg: GoConfig, policy_features: tuple,
     vterm = jax.vmap(functools.partial(_terminal_value, cfg))
     vterm_komi = jax.vmap(functools.partial(_terminal_value_komi, cfg))
 
+    def _analyse(states: GoState):
+        """Group analysis + value-feature planes of a batch, each
+        under its scope (the from-scratch encode)."""
+        with jax.named_scope(scopes.EVAL_GROUPS):
+            gd = vgd(states)
+        with jax.named_scope(scopes.EVAL_ENCODE):
+            planes = venc(states, gd)                  # [B, s, s, Fv]
+        return gd, planes
+
     def _eval_from(params_p, params_v, states: GoState, gd, planes,
                    komi=None):
         """The NN half of :func:`eval_batch`, on precomputed analysis
         + planes (shared with the delta-encode root path)."""
-        sens = vsens(states, gd)                       # [B, N]
-        logits = policy_apply(params_p,
-                              planes[..., :n_policy_planes])
-        neg = jnp.finfo(logits.dtype).min
-        masked = jnp.where(sens, logits, neg)
-        board_p = jax.nn.softmax(masked, axis=-1)
-        any_sens = sens.any(axis=-1, keepdims=True)
-        board_p = jnp.where(any_sens, board_p, 0.0)
-        pass_p = jnp.where(any_sens[:, 0], 0.0, 1.0)
-        priors = jnp.concatenate(
-            [board_p, pass_p[:, None]], axis=-1).astype(jnp.float32)
-        values = value_apply(params_v, planes).astype(jnp.float32)
-        term = vterm(states) if komi is None \
-            else vterm_komi(states, komi)
-        values = jnp.where(states.done, term, values)
+        with jax.named_scope(scopes.EVAL_POLICY):
+            sens = vsens(states, gd)                   # [B, N]
+            logits = policy_apply(params_p,
+                                  planes[..., :n_policy_planes])
+            neg = jnp.finfo(logits.dtype).min
+            masked = jnp.where(sens, logits, neg)
+            board_p = jax.nn.softmax(masked, axis=-1)
+            any_sens = sens.any(axis=-1, keepdims=True)
+            board_p = jnp.where(any_sens, board_p, 0.0)
+            pass_p = jnp.where(any_sens[:, 0], 0.0, 1.0)
+            priors = jnp.concatenate(
+                [board_p, pass_p[:, None]],
+                axis=-1).astype(jnp.float32)
+        with jax.named_scope(scopes.EVAL_VALUE):
+            values = value_apply(params_v, planes).astype(jnp.float32)
+            term = vterm(states) if komi is None \
+                else vterm_komi(states, komi)
+            values = jnp.where(states.done, term, values)
         return priors, values
 
     def eval_batch(params_p, params_v, states: GoState):
@@ -234,8 +246,7 @@ def make_device_mcts(cfg: GoConfig, policy_features: tuple,
         softmax over sensible moves; the pass action gets probability
         1 exactly when no sensible move exists. Values are the value
         net's output where live, the terminal outcome where done."""
-        gd = vgd(states)
-        planes = venc(states, gd)                      # [B, s, s, Fv]
+        gd, planes = _analyse(states)
         return _eval_from(params_p, params_v, states, gd, planes)
 
     def eval_batch_komi(params_p, params_v, states: GoState, komi):
@@ -246,8 +257,7 @@ def make_device_mcts(cfg: GoConfig, policy_features: tuple,
         per-komi recompile — one program per batch size serves every
         komi, and rows at the default komi score identically to the
         pinned :func:`eval_batch` path."""
-        gd = vgd(states)
-        planes = venc(states, gd)                      # [B, s, s, Fv]
+        gd, planes = _analyse(states)
         return _eval_from(params_p, params_v, states, gd, planes,
                           komi=komi)
 
@@ -286,8 +296,10 @@ def make_device_mcts(cfg: GoConfig, policy_features: tuple,
         Bit-identical priors (the delta path's contract); returns
         ``(tree, caches')`` — the caller carries the cache across
         moves (``DeviceMCTSPlayer._enc_cache``)."""
-        gd = vgd(roots)
-        planes, caches = denc(roots, caches, gd)
+        with jax.named_scope(scopes.EVAL_GROUPS):
+            gd = vgd(roots)
+        with jax.named_scope(scopes.EVAL_ENCODE):
+            planes, caches = denc(roots, caches, gd)
         priors, _ = _eval_from(params_p, params_v, roots, gd, planes)
         return _assemble_tree(roots, priors), caches
 
@@ -394,30 +406,33 @@ def make_device_mcts(cfg: GoConfig, policy_features: tuple,
         ``eval_states`` an evaluator must score. ``root_actions``
         (i32 [B], -1 = free) forces each game's first edge — the
         Gumbel searcher's scheduled candidates."""
-        node, action = jax.vmap(_descend_one)(
-            tree.prior, tree.visits, tree.value_sum, tree.child,
-            tree.states.done, root_actions, tree.root)
+        with jax.named_scope(scopes.MCTS_SELECT):
+            node, action = jax.vmap(_descend_one)(
+                tree.prior, tree.visits, tree.value_sum, tree.child,
+                tree.states.done, root_actions, tree.root)
 
-        # candidate child states: step the selected edge (terminal
-        # descends step a no-op pass on an already-done state — the
-        # result is discarded for those games)
-        parent_states = jax.vmap(_state_at)(tree.states, node)
-        safe_action = jnp.where(action >= 0, action, n)
-        new_states_b = vstep(parent_states, safe_action)
+        with jax.named_scope(scopes.MCTS_EXPAND):
+            # candidate child states: step the selected edge
+            # (terminal descends step a no-op pass on an already-done
+            # state — the result is discarded for those games)
+            parent_states = jax.vmap(_state_at)(tree.states, node)
+            safe_action = jnp.where(action >= 0, action, n)
+            new_states_b = vstep(parent_states, safe_action)
 
-        expanding = action >= 0                       # bool [B]
+            expanding = action >= 0                   # bool [B]
 
-        # evaluate: expanded games evaluate the new child state;
-        # terminal descends evaluate the terminal node's own state
-        eval_states = jax.tree.map(
-            lambda a, b: jnp.where(
-                expanding.reshape((-1,) + (1,) * (a.ndim - 1)), a, b),
-            new_states_b, parent_states)
-        # transposition key per eval row — a handful of XOR lanes off
-        # the carried hash; dead-code-eliminated in the fused
-        # ``simulate`` path (where no external evaluator reads it)
-        eval_keys = jax.vmap(functools.partial(eval_signature, cfg))(
-            eval_states)
+            # evaluate: expanded games evaluate the new child state;
+            # terminal descends evaluate the terminal node's own state
+            eval_states = jax.tree.map(
+                lambda a, b: jnp.where(
+                    expanding.reshape((-1,) + (1,) * (a.ndim - 1)),
+                    a, b),
+                new_states_b, parent_states)
+            # transposition key per eval row — a handful of XOR lanes
+            # off the carried hash; dead-code-eliminated in the fused
+            # ``simulate`` path (where no external evaluator reads it)
+            eval_keys = jax.vmap(
+                functools.partial(eval_signature, cfg))(eval_states)
         return SimStep(node=node, safe_action=safe_action,
                        expanding=expanding, eval_states=eval_states,
                        eval_keys=eval_keys)
@@ -430,52 +445,54 @@ def make_device_mcts(cfg: GoConfig, policy_features: tuple,
         ``ctx.eval_states`` — from the in-search ``eval_batch`` or an
         external (cross-game batching) evaluator; the two compose to
         exactly the fused ``simulate``."""
-        node, safe_action = ctx.node, ctx.safe_action
-        # the written rows are exactly the expanding ones, where
-        # eval_states IS the stepped child (SimStep docstring)
-        expanding, new_states_b = ctx.expanding, ctx.eval_states
-        full = tree.n_nodes >= m
-        idx = jnp.where(expanding & ~full,
-                        jnp.minimum(tree.n_nodes, m - 1), 0)
+        with jax.named_scope(scopes.MCTS_EXPAND):
+            node, safe_action = ctx.node, ctx.safe_action
+            # the written rows are exactly the expanding ones, where
+            # eval_states IS the stepped child (SimStep docstring)
+            expanding, new_states_b = ctx.expanding, ctx.eval_states
+            full = tree.n_nodes >= m
+            idx = jnp.where(expanding & ~full,
+                            jnp.minimum(tree.n_nodes, m - 1), 0)
 
-        # write the new node (only where expanding & not full)
-        write = expanding & ~full
+            # write the new node (only where expanding & not full)
+            write = expanding & ~full
 
-        def write_state(slab, i, st, w):
-            return jax.tree.map(
-                lambda buf, v: jnp.where(w, buf.at[i].set(v), buf),
-                slab, st)
+            def write_state(slab, i, st, w):
+                return jax.tree.map(
+                    lambda buf, v: jnp.where(w, buf.at[i].set(v), buf),
+                    slab, st)
 
-        states = jax.vmap(write_state)(tree.states, idx, new_states_b,
-                                       write)
-        prior = jax.vmap(
-            lambda p, i, row, w: jnp.where(w, p.at[i].set(row), p))(
-                tree.prior, idx, priors, write)
-        child = jax.vmap(
-            lambda c, nd, a, i, w: jnp.where(
-                w, c.at[nd, a].set(i), c))(
-                tree.child, node, safe_action, idx, write)
-        parent = jax.vmap(
-            lambda p, i, nd, w: jnp.where(w, p.at[i].set(nd), p))(
-                tree.parent, idx, node, write)
-        paction = jax.vmap(
-            lambda p, i, a, w: jnp.where(w, p.at[i].set(a), p))(
-                tree.paction, idx, safe_action, write)
-        n_nodes = tree.n_nodes + write.astype(jnp.int32)
+            states = jax.vmap(write_state)(tree.states, idx, new_states_b,
+                                           write)
+            prior = jax.vmap(
+                lambda p, i, row, w: jnp.where(w, p.at[i].set(row), p))(
+                    tree.prior, idx, priors, write)
+            child = jax.vmap(
+                lambda c, nd, a, i, w: jnp.where(
+                    w, c.at[nd, a].set(i), c))(
+                    tree.child, node, safe_action, idx, write)
+            parent = jax.vmap(
+                lambda p, i, nd, w: jnp.where(w, p.at[i].set(nd), p))(
+                    tree.parent, idx, node, write)
+            paction = jax.vmap(
+                lambda p, i, a, w: jnp.where(w, p.at[i].set(a), p))(
+                    tree.paction, idx, safe_action, write)
+            n_nodes = tree.n_nodes + write.astype(jnp.int32)
 
-        # backup start: the edge INTO the evaluated state — (node,
-        # action) for expansions (stored or capacity-skipped alike),
-        # the terminal node's own parent edge otherwise. A terminal
-        # ROOT (parent -1) skips the backup loop entirely.
-        start_node = jnp.where(expanding, node,
-                               jax.vmap(lambda p, nd: p[nd])(
-                                   tree.parent, node))
-        start_action = jnp.where(
-            expanding, safe_action,
-            jax.vmap(lambda p, nd: p[nd])(tree.paction, node))
-        visits, value_sum = jax.vmap(_backup_one)(
-            tree.visits, tree.value_sum, parent, paction,
-            start_node, start_action, values)
+        with jax.named_scope(scopes.MCTS_BACKUP):
+            # backup start: the edge INTO the evaluated state — (node,
+            # action) for expansions (stored or capacity-skipped alike),
+            # the terminal node's own parent edge otherwise. A terminal
+            # ROOT (parent -1) skips the backup loop entirely.
+            start_node = jnp.where(expanding, node,
+                                   jax.vmap(lambda p, nd: p[nd])(
+                                       tree.parent, node))
+            start_action = jnp.where(
+                expanding, safe_action,
+                jax.vmap(lambda p, nd: p[nd])(tree.paction, node))
+            visits, value_sum = jax.vmap(_backup_one)(
+                tree.visits, tree.value_sum, parent, paction,
+                start_node, start_action, values)
 
         return DeviceTree(states, prior, visits, value_sum, child,
                           parent, paction, n_nodes, tree.root)

@@ -52,6 +52,7 @@ from rocalphago_tpu.features.planes import (
     true_eyes,
 )
 from rocalphago_tpu.obs import registry as obs_registry
+from rocalphago_tpu.obs import scopes
 from rocalphago_tpu.runtime import faults
 from rocalphago_tpu.runtime.pipeline import ChunkPipeline
 
@@ -137,29 +138,35 @@ def _make_ply(cfg: GoConfig, features: tuple, apply_a: Callable,
         rng, sub = jax.random.split(rng)
         # one loop-free analysis per ply, shared by the encoder, the
         # sensibleness mask and the rules step
-        gd = vgd(states)
-        if incremental:
-            planes, caches = enc(states, caches, gd)
-        else:
-            planes = enc(states, gd)
-        # which half faces net A this ply (see module docstring)
-        swap = (t % 2) == 1
-        rolled = _half_swap(planes, swap)
-        half = batch // 2
-        logits_a = apply_a(params_a, rolled[:half])
-        logits_b = apply_b(params_b, rolled[half:])
-        logits = _half_swap(
-            jnp.concatenate([logits_a, logits_b], axis=0), swap)
+        with jax.named_scope(scopes.PLY_GROUPS):
+            gd = vgd(states)
+        with jax.named_scope(scopes.PLY_ENCODE):
+            if incremental:
+                planes, caches = enc(states, caches, gd)
+            else:
+                planes = enc(states, gd)
+        with jax.named_scope(scopes.PLY_FORWARD):
+            # which half faces net A this ply (see module docstring)
+            swap = (t % 2) == 1
+            rolled = _half_swap(planes, swap)
+            half = batch // 2
+            logits_a = apply_a(params_a, rolled[:half])
+            logits_b = apply_b(params_b, rolled[half:])
+            logits = _half_swap(
+                jnp.concatenate([logits_a, logits_b], axis=0), swap)
 
-        sens = vsens(states, gd)                          # bool [B, N]
-        neg = jnp.finfo(logits.dtype).min
-        masked = jnp.where(sens, logits / temperature, neg)
-        board_action = jax.random.categorical(sub, masked, axis=-1)
-        must_pass = ~sens.any(axis=-1)
-        action = jnp.where(must_pass, n, board_action).astype(jnp.int32)
+        with jax.named_scope(scopes.PLY_SAMPLE):
+            sens = vsens(states, gd)                      # bool [B, N]
+            neg = jnp.finfo(logits.dtype).min
+            masked = jnp.where(sens, logits / temperature, neg)
+            board_action = jax.random.categorical(sub, masked, axis=-1)
+            must_pass = ~sens.any(axis=-1)
+            action = jnp.where(must_pass, n,
+                               board_action).astype(jnp.int32)
 
         live = ~states.done
-        new = vstep(states, action, gd)
+        with jax.named_scope(scopes.PLY_STEP):
+            new = vstep(states, action, gd)
         return new, caches, rng, action, live
 
     return ply

@@ -82,6 +82,7 @@ from collections import deque
 
 from rocalphago_tpu.analysis import lockcheck
 from rocalphago_tpu.obs import registry as obs_registry
+from rocalphago_tpu.obs import trace
 from rocalphago_tpu.runtime import faults, supervisor
 
 MAX_WAIT_ENV = "ROCALPHAGO_SERVE_MAX_WAIT_US"
@@ -453,7 +454,7 @@ class BatchingEvaluator:
             # takes the THREAD down with the queue intact — the
             # supervised restart serves the same requests
             faults.barrier("serve.dispatch", iteration=self.batches)
-            with self._cond:
+            with trace.span("serve.collect"), self._cond:
                 while not self._queue and not self._stop:
                     self._cond.wait(0.1)
                 if self._stop and not self._queue:
@@ -499,28 +500,26 @@ class BatchingEvaluator:
             # the soak tests' injection point: a fault here fails
             # exactly this batch's requests, never the dispatcher
             faults.barrier("serve.eval", iteration=self.batches)
-            states = take[0].states
-            if len(take) > 1:
-                states = jax.tree.map(
-                    lambda *xs: jnp.concatenate(xs, axis=0),
-                    *[r.states for r in take])
-            komi = None
-            if any(r.komi is not None for r in take):
-                # a custom-komi request switches the WHOLE batch to
-                # the komi program; default-komi requests ride along
-                # at default_komi, which scores identically
-                self.komi_batches += 1
-                komi = jnp.concatenate([
-                    jnp.full((r.rows,), self.default_komi,
-                             jnp.float32) if r.komi is None
-                    else jnp.broadcast_to(
-                        jnp.asarray(r.komi, jnp.float32), (r.rows,))
-                    for r in take])
-            if self.cache is not None:
-                priors, values, devrows, size = self._eval_cached(
-                    states, komi, take, total)
-            else:
-                if size > total:
+            with trace.span("serve.assemble"):
+                states = take[0].states
+                if len(take) > 1:
+                    states = jax.tree.map(
+                        lambda *xs: jnp.concatenate(xs, axis=0),
+                        *[r.states for r in take])
+                komi = None
+                if any(r.komi is not None for r in take):
+                    # a custom-komi request switches the WHOLE batch
+                    # to the komi program; default-komi requests ride
+                    # along at default_komi, which scores identically
+                    self.komi_batches += 1
+                    komi = jnp.concatenate([
+                        jnp.full((r.rows,), self.default_komi,
+                                 jnp.float32) if r.komi is None
+                        else jnp.broadcast_to(
+                            jnp.asarray(r.komi, jnp.float32),
+                            (r.rows,))
+                        for r in take])
+                if self.cache is None and size > total:
                     # pad rows replicate row 0 (valid states, no NaN
                     # hazards) and are sliced off below — per-row
                     # programs make real rows independent of them
@@ -535,9 +534,16 @@ class BatchingEvaluator:
                         komi = jnp.concatenate(
                             [komi, jnp.broadcast_to(komi[:1],
                                                     (pad,))])
-                priors, values = self.eval_direct(
-                    states, komi=komi, version=take[0].version)
-                devrows = total
+            with trace.span("serve.dispatch"):
+                if self.cache is not None:
+                    # the cached path keys, dedups and pads the
+                    # unique rows itself
+                    priors, values, devrows, size = self._eval_cached(
+                        states, komi, take, total)
+                else:
+                    priors, values = self.eval_direct(
+                        states, komi=komi, version=take[0].version)
+                    devrows = total
         except Exception as e:  # noqa: BLE001 — fail the batch, not
             #                     the dispatcher (classified by the
             #                     sessions' resilience ladders)
@@ -557,12 +563,13 @@ class BatchingEvaluator:
             self._occ_h.observe(devrows / size)
             obs_registry.counter("serve_eval_batches_total",
                                  size=str(size)).inc()
-        offset = 0
-        for req in take:
-            req._finish((priors[offset:offset + req.rows],
-                         values[offset:offset + req.rows]))
-            offset += req.rows
-            self.release(req.version)
+        with trace.span("serve.deliver"):
+            offset = 0
+            for req in take:
+                req._finish((priors[offset:offset + req.rows],
+                             values[offset:offset + req.rows]))
+                offset += req.rows
+                self.release(req.version)
 
     # ------------------------------------------------- cached dispatch
 

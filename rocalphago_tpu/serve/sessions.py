@@ -50,6 +50,7 @@ import time
 
 from rocalphago_tpu.analysis import lockcheck
 from rocalphago_tpu.obs import registry as obs_registry
+from rocalphago_tpu.obs import trace
 from rocalphago_tpu.runtime.deadline import Deadline
 from rocalphago_tpu.serve.admission import AdmissionController
 from rocalphago_tpu.serve.evaluator import BatchingEvaluator
@@ -174,18 +175,25 @@ class SessionPlayer:
             # steady state is ONE device call per simulation
             # (advance_sim: apply + next prepare fused); the deadline
             # is checked between simulations, one-sim anytime floor
-            ctx = search.prepare_sim(tree, self._free)
+            # per-simulation annotations on the profiler's clock
+            # (obs.trace.annotation: no record, no lock) — what this
+            # session's thread was doing in a device idle gap
+            with trace.annotation("session.prepare"):
+                ctx = search.prepare_sim(tree, self._free)
             ran = 0
             while True:
-                priors, values = pool.evaluator.evaluate(
-                    ctx.eval_states, komi=komi, version=ver,
-                    keys=ctx.eval_keys)
+                with trace.annotation("session.wait_eval"):
+                    priors, values = pool.evaluator.evaluate(
+                        ctx.eval_states, komi=komi, version=ver,
+                        keys=ctx.eval_keys)
                 ran += 1
-                if ran >= eff or (enforce and deadline.expired()):
-                    tree = search.apply_sim(tree, ctx, priors, values)
-                    break
-                tree, ctx = search.advance_sim(tree, ctx, priors,
-                                               values, self._free)
+                with trace.annotation("session.apply"):
+                    if ran >= eff or (enforce and deadline.expired()):
+                        tree = search.apply_sim(tree, ctx, priors,
+                                                values)
+                        break
+                    tree, ctx = search.advance_sim(
+                        tree, ctx, priors, values, self._free)
         finally:
             pool.evaluator.release(ver)
         visits, _ = search.root_stats(tree)
@@ -277,19 +285,23 @@ class FleetDriver:
                                                  keys=keys0)
             tree = search.assemble_tree(roots, priors0)
             free = jnp.full((n,), -1, jnp.int32)
-            ctx = search.prepare_sim(tree, free)
+            with trace.annotation("session.prepare"):
+                ctx = search.prepare_sim(tree, free)
             ran = 0
             while True:
-                priors, values = pool.evaluator.evaluate(
-                    ctx.eval_states, rows=n, komi=komi, version=ver,
-                    keys=ctx.eval_keys)
+                with trace.annotation("session.wait_eval"):
+                    priors, values = pool.evaluator.evaluate(
+                        ctx.eval_states, rows=n, komi=komi,
+                        version=ver, keys=ctx.eval_keys)
                 ran += 1
-                if ran >= pool.n_sim or (enforce
-                                         and deadline.expired()):
-                    tree = search.apply_sim(tree, ctx, priors, values)
-                    break
-                tree, ctx = search.advance_sim(tree, ctx, priors,
-                                               values, free)
+                with trace.annotation("session.apply"):
+                    if ran >= pool.n_sim or (enforce
+                                             and deadline.expired()):
+                        tree = search.apply_sim(tree, ctx, priors,
+                                                values)
+                        break
+                    tree, ctx = search.advance_sim(
+                        tree, ctx, priors, values, free)
         finally:
             pool.evaluator.release(ver)
         visits, _ = search.root_stats(tree)

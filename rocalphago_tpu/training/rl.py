@@ -53,7 +53,7 @@ from rocalphago_tpu.io.checkpoint import (
 )
 from rocalphago_tpu.io.metrics import MetricsLogger
 from rocalphago_tpu.models.nn_util import NeuralNetBase
-from rocalphago_tpu.obs import jaxobs, trace
+from rocalphago_tpu.obs import jaxobs, scopes, trace
 from rocalphago_tpu.obs import registry as obs_registry
 from rocalphago_tpu.runtime.pipeline import ChunkPipeline
 from rocalphago_tpu.parallel import mesh as meshlib
@@ -121,12 +121,14 @@ def _make_replay_ply(cfg: jaxgo.GoConfig, features: tuple, apply_fn,
 
         def loss_fn(p):
             logits = apply_fn(p, planes)
-            neg = jnp.finfo(logits.dtype).min
-            masked = jnp.where(sens, logits / temperature, neg)
-            logp = jax.nn.log_softmax(masked, axis=-1)
-            lp = jnp.take_along_axis(
-                logp, jnp.minimum(acts, n - 1)[:, None], axis=1)[:, 0]
-            return -(w * lp).sum() / batch
+            with jax.named_scope(scopes.TRAIN_LOSS):
+                neg = jnp.finfo(logits.dtype).min
+                masked = jnp.where(sens, logits / temperature, neg)
+                logp = jax.nn.log_softmax(masked, axis=-1)
+                lp = jnp.take_along_axis(
+                    logp, jnp.minimum(acts, n - 1)[:, None],
+                    axis=1)[:, 0]
+                return -(w * lp).sum() / batch
 
         grads = jax.tree.map(jnp.add, grads, jax.grad(loss_fn)(params))
         return (vstep(states, actions_t), grads)
@@ -143,8 +145,10 @@ def _learner_z(winners: jax.Array, half: int) -> jax.Array:
 
 def _update_and_metrics(tx, state: RLState, grads, z, num_moves, key):
     """Shared SGD apply + metrics assembly for both iterations."""
-    updates, opt_state = tx.update(grads, state.opt_state, state.params)
-    params = optax.apply_updates(state.params, updates)
+    with jax.named_scope(scopes.TRAIN_UPDATE):
+        updates, opt_state = tx.update(
+            grads, state.opt_state, state.params)
+        params = optax.apply_updates(state.params, updates)
     # win rate over DECIDED games (draws excluded, reported
     # separately) — counting draws as losses biases the learner
     # win-rate low on integer-komi configs

@@ -46,7 +46,7 @@ from rocalphago_tpu.io.checkpoint import (
 )
 from rocalphago_tpu.io.metrics import MetricsLogger
 from rocalphago_tpu.models.nn_util import NeuralNetBase
-from rocalphago_tpu.obs import jaxobs, trace
+from rocalphago_tpu.obs import jaxobs, scopes, trace
 from rocalphago_tpu.obs import registry as obs_registry
 from rocalphago_tpu.parallel import mesh as meshlib
 from rocalphago_tpu.runtime import faults
@@ -93,18 +93,20 @@ def make_optimizer(cfg: SLConfig) -> optax.GradientTransformation:
 
 def policy_loss_fn(apply_fn, params, planes, actions, weights=None):
     logits = apply_fn(params, planes)
-    # pass actions (== N, present when a corpus was converted with
-    # include_passes) are outside the policy's board-point output space
-    # — mask them out rather than letting the xent gather clamp them
-    # onto the last board point
-    valid = (actions < logits.shape[-1]).astype(jnp.float32)
-    if weights is not None:
-        valid = valid * weights
-    denom = jnp.maximum(valid.sum(), 1.0)
-    xent = optax.softmax_cross_entropy_with_integer_labels(
-        logits, jnp.minimum(actions, logits.shape[-1] - 1))
-    loss = (xent * valid).sum() / denom
-    acc = (((logits.argmax(axis=-1) == actions) * valid).sum() / denom)
+    with jax.named_scope(scopes.TRAIN_LOSS):
+        # pass actions (== N, present when a corpus was converted with
+        # include_passes) are outside the policy's board-point output
+        # space — mask them out rather than letting the xent gather
+        # clamp them onto the last board point
+        valid = (actions < logits.shape[-1]).astype(jnp.float32)
+        if weights is not None:
+            valid = valid * weights
+        denom = jnp.maximum(valid.sum(), 1.0)
+        xent = optax.softmax_cross_entropy_with_integer_labels(
+            logits, jnp.minimum(actions, logits.shape[-1] - 1))
+        loss = (xent * valid).sum() / denom
+        acc = (((logits.argmax(axis=-1) == actions) * valid).sum()
+               / denom)
     return loss, acc
 
 
@@ -114,15 +116,18 @@ def make_train_step(apply_fn, tx, size: int, symmetries: bool):
     def train_step(state: SLState, planes, actions):
         key = unpack_rng(state.rng)
         key, sub = jax.random.split(key)
-        planes = planes.astype(jnp.float32)
-        if symmetries:
-            planes, actions = random_transform_batch(
-                sub, planes, actions, size)
+        with jax.named_scope(scopes.TRAIN_AUGMENT):
+            planes = planes.astype(jnp.float32)
+            if symmetries:
+                planes, actions = random_transform_batch(
+                    sub, planes, actions, size)
         (loss, acc), grads = jax.value_and_grad(
             functools.partial(policy_loss_fn, apply_fn), has_aux=True)(
                 state.params, planes, actions)
-        updates, opt_state = tx.update(grads, state.opt_state, state.params)
-        params = optax.apply_updates(state.params, updates)
+        with jax.named_scope(scopes.TRAIN_UPDATE):
+            updates, opt_state = tx.update(
+                grads, state.opt_state, state.params)
+            params = optax.apply_updates(state.params, updates)
         new = SLState(params, opt_state, state.step + 1, pack_rng(key))
         return new, {"loss": loss, "accuracy": acc}
 
@@ -287,11 +292,15 @@ class SLTrainer:
             losses, accs = [], []
             with trace.span("sl.train"):
               for i, (planes, actions) in enumerate(obs_registry.timed(
-                      device_prefetch(it, size=2), data_wait)):
+                      device_prefetch(it, size=2), data_wait,
+                      annotate="sl.data_wait")):
                 if i >= steps_per_epoch - skip:
                     break
-                self.state, m = self._train_step(
-                    self.state, planes, actions)
+                # per-step, so on the profiler's clock only: a record
+                # each would flood metrics.jsonl
+                with trace.annotation("sl.step"):
+                    self.state, m = self._train_step(
+                        self.state, planes, actions)
                 losses.append(m["loss"])
                 accs.append(m["accuracy"])
                 if cfg.save_every:

@@ -41,7 +41,7 @@ from rocalphago_tpu.io.checkpoint import (
 )
 from rocalphago_tpu.io.metrics import MetricsLogger
 from rocalphago_tpu.models.nn_util import NeuralNetBase
-from rocalphago_tpu.obs import jaxobs, trace
+from rocalphago_tpu.obs import jaxobs, scopes, trace
 from rocalphago_tpu.obs import registry as obs_registry
 from rocalphago_tpu.parallel import mesh as meshlib
 from rocalphago_tpu.runtime import faults
@@ -79,27 +79,30 @@ class ValueState(NamedTuple):
 
 def value_loss_fn(apply_fn, params, planes, outcomes, weights=None):
     pred = apply_fn(params, planes)
-    z = outcomes.astype(jnp.float32)
-    sq = (pred - z) ** 2
-    if weights is None:
-        return jnp.mean(sq)
-    return (sq * weights).sum() / jnp.maximum(weights.sum(), 1.0)
+    with jax.named_scope(scopes.TRAIN_LOSS):
+        z = outcomes.astype(jnp.float32)
+        sq = (pred - z) ** 2
+        if weights is None:
+            return jnp.mean(sq)
+        return (sq * weights).sum() / jnp.maximum(weights.sum(), 1.0)
 
 
 def make_train_step(apply_fn, tx, symmetries: bool):
     def train_step(state: ValueState, planes, outcomes):
         key = unpack_rng(state.rng)
         key, sub = jax.random.split(key)
-        planes = planes.astype(jnp.float32)
-        if symmetries:
-            t = jax.random.randint(sub, (planes.shape[0],), 0, 8)
-            planes = jax.vmap(transform_planes)(planes, t)
+        with jax.named_scope(scopes.TRAIN_AUGMENT):
+            planes = planes.astype(jnp.float32)
+            if symmetries:
+                t = jax.random.randint(sub, (planes.shape[0],), 0, 8)
+                planes = jax.vmap(transform_planes)(planes, t)
         loss, grads = jax.value_and_grad(
             lambda p: value_loss_fn(apply_fn, p, planes, outcomes))(
                 state.params)
-        updates, opt_state = tx.update(grads, state.opt_state,
-                                       state.params)
-        params = optax.apply_updates(state.params, updates)
+        with jax.named_scope(scopes.TRAIN_UPDATE):
+            updates, opt_state = tx.update(grads, state.opt_state,
+                                           state.params)
+            params = optax.apply_updates(state.params, updates)
         new = ValueState(params, opt_state, state.step + 1,
                          pack_rng(key))
         return new, {"mse": loss}

@@ -1,102 +1,59 @@
-"""Summarize a ``jax.profiler`` trace: top device ops by time.
+"""Summarize a ``jax.profiler`` capture: device time by scope, the
+top device operations, and what the host was doing while the device
+was idle.
 
-Every benchmark in this repo takes ``--profile DIR`` and drops a
-Perfetto ``*.trace.json.gz`` under ``DIR/plugins/profile/<ts>/``; this
-tool turns that into the flat answer perf work actually needs — which
-ops own the wall time — without hauling the trace into a GUI (this
-environment has no browser; VERDICT r3 item 6 asks for trace-backed
-bottleneck analysis).
+A front for the chip benchmark's reducers — ``chipbench/scopes.py``
+(device self time by the program's ``jax.named_scope`` names and
+Flax's module scopes, idle gaps by the program's ``rocalphago.*``
+spans) and ``chipbench/trace_reduce.py`` (busy/idle time, top
+operations) — over the ``.xplane.pb`` the profiler writes, so an
+operator's ``--profile-dir`` / ``ROCALPHAGO_JAX_PROFILE`` capture is
+read by the same code, into the same tables, as the benchmark's
+traced window. No GUI needed.
 
 Usage:
-    python scripts/analyze_trace.py DIR [--top 25] [--lane SUBSTR]
-        [--json]
+    python scripts/analyze_trace.py DIR [--top 25] [--json]
 
 ``DIR`` may be the profile dir itself or any ancestor (the newest
-trace under it is picked). Events are grouped by the thread lane they
-run on (XLA device traces put compiled ops on an "XLA Ops" lane,
-module launches on "XLA Modules"; host Python frames land on a
-"python" lane). By default every lane except host-Python is
-summarized; ``--lane`` filters to lanes whose name contains SUBSTR
-(e.g. ``--lane "XLA Ops"``).
+``*.xplane.pb`` under it is picked).
 """
 
 from __future__ import annotations
 
 import argparse
-import collections
 import glob
-import gzip
 import json
 import os
 import sys
 
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
 
 def newest_trace(root: str) -> str:
-    direct = glob.glob(os.path.join(root, "*.trace.json.gz"))
-    nested = glob.glob(os.path.join(root, "**", "*.trace.json.gz"),
-                       recursive=True)
-    paths = direct or nested
+    paths = glob.glob(os.path.join(root, "**", "*.xplane.pb"),
+                      recursive=True)
     if not paths:
-        raise SystemExit(f"no *.trace.json.gz under {root}")
+        raise SystemExit(f"no *.xplane.pb under {root}")
     return max(paths, key=os.path.getmtime)
 
 
-def load_events(path: str) -> list[dict]:
-    with gzip.open(path, "rt") as f:
-        return json.load(f).get("traceEvents", [])
+def summarize(path: str, top: int = 25) -> dict:
+    """The by-scope account of one trace file plus its top device
+    operations by self time (``[]`` when the capture holds no device
+    operation line the older reducer knows)."""
+    from chipbench import scopes, trace_reduce
 
-
-def summarize(events: list[dict], lane_filter: str | None = None,
-              include_python: bool = False):
-    """-> {lane_name: {"total_us", "span_us", "ops": [(name, us, n)]}}.
-
-    Total is the plain sum of event durations per lane; span is the
-    first-start→last-end extent (overlap/nesting makes total > span on
-    busy lanes — both are reported so neither misleads alone).
-    """
-    proc = {}
-    thread = {}
-    for e in events:
-        if e.get("ph") != "M":
-            continue
-        args = e.get("args") or {}
-        if e.get("name") == "process_name":
-            proc[e.get("pid")] = str(args.get("name", e.get("pid")))
-        elif e.get("name") == "thread_name":
-            thread[(e.get("pid"), e.get("tid"))] = str(
-                args.get("name", e.get("tid")))
-
-    lanes: dict = {}
-    for e in events:
-        if e.get("ph") != "X" or "dur" not in e:
-            continue
-        key = (e.get("pid"), e.get("tid"))
-        lane = f"{proc.get(e.get('pid'), e.get('pid'))}/" \
-               f"{thread.get(key, e.get('tid'))}"
-        if not include_python and thread.get(key, "") == "python":
-            continue
-        if lane_filter and lane_filter.lower() not in lane.lower():
-            continue
-        d = lanes.setdefault(lane, {
-            "ops": collections.defaultdict(lambda: [0.0, 0]),
-            "t0": float("inf"), "t1": 0.0, "total": 0.0})
-        dur = float(e["dur"])
-        ts = float(e.get("ts", 0.0))
-        agg = d["ops"][e.get("name", "?")]
-        agg[0] += dur
-        agg[1] += 1
-        d["total"] += dur
-        d["t0"] = min(d["t0"], ts)
-        d["t1"] = max(d["t1"], ts + dur)
-
-    out = {}
-    for lane, d in lanes.items():
-        ops = sorted(((n, v[0], v[1]) for n, v in d["ops"].items()),
-                     key=lambda x: -x[1])
-        out[lane] = {"total_us": d["total"],
-                     "span_us": max(d["t1"] - d["t0"], 0.0),
-                     "ops": ops}
-    return out
+    acct = scopes.reduce(scopes.load(path))
+    acct["by_scope"] = dict(list(acct["by_scope"].items())[:top])
+    acct.pop("scope_names")
+    try:
+        acct["device_ops"] = trace_reduce.reduce(
+            trace_reduce.load_events(path), top=top)["device_ops"]
+    except ValueError:
+        acct["device_ops"] = []
+    return dict(acct, trace=path)
 
 
 def main(argv=None) -> int:
@@ -105,37 +62,32 @@ def main(argv=None) -> int:
         formatter_class=argparse.RawDescriptionHelpFormatter)
     ap.add_argument("dir", help="profile dir (or any ancestor)")
     ap.add_argument("--top", type=int, default=25)
-    ap.add_argument("--lane", default=None,
-                    help="only lanes whose name contains this")
-    ap.add_argument("--python", action="store_true",
-                    help="include host-Python frame lanes")
     ap.add_argument("--json", action="store_true",
                     help="machine-readable output")
     a = ap.parse_args(argv)
 
-    path = newest_trace(a.dir)
-    lanes = summarize(load_events(path), a.lane, a.python)
+    s = summarize(newest_trace(a.dir), a.top)
     if a.json:
-        print(json.dumps({
-            "trace": path,
-            "lanes": {k: {"total_us": v["total_us"],
-                          "span_us": v["span_us"],
-                          "top": v["ops"][:a.top]}
-                      for k, v in lanes.items()}}))
+        print(json.dumps(s))
         return 0
-
-    print(f"trace: {path}")
-    # busiest lanes first
-    for lane, d in sorted(lanes.items(),
-                          key=lambda kv: -kv[1]["total_us"]):
-        if not d["ops"]:
-            continue
-        print(f"\n== {lane}  (sum {d['total_us'] / 1e3:.1f} ms, "
-              f"span {d['span_us'] / 1e3:.1f} ms, "
-              f"{len(d['ops'])} distinct)")
-        for name, us, n in d["ops"][:a.top]:
-            pct = 100.0 * us / d["total_us"] if d["total_us"] else 0.0
-            print(f"  {us / 1e3:10.2f} ms {pct:5.1f}% x{n:<6d} {name}")
+    busy, window = s["busy_s"], s["window_s"]
+    print(f"trace: {s['trace']}")
+    print(f"window {window:.4f} s, device busy {busy:.4f} s "
+          f"({100 * busy / window if window else 0:.2f} %), "
+          f"{s['devices']} device plane(s)")
+    for name, runs in sorted(s["program_runs"].items(),
+                             key=lambda kv: -kv[1])[:a.top]:
+        print(f"  ran x{runs:<6d} {name}")
+    print("\n== device self time by scope")
+    for scope, t in s["by_scope"].items():
+        pct = 100.0 * t / busy if busy else 0.0
+        print(f"  {t * 1e3:10.3f} ms {pct:5.1f}%  {scope}")
+    print("\n== top device operations (self time)")
+    for name, t in s["device_ops"]:
+        print(f"  {t * 1e3:10.3f} ms  {name}")
+    print("\n== device idle time by the innermost host span")
+    for name, t in s["idle_by_span"][:a.top]:
+        print(f"  {t * 1e3:10.3f} ms  {name}")
     return 0
 
 

@@ -184,47 +184,54 @@ def test_fixed_override_ignored_off_tpu(monkeypatch):
     assert rec["chunk"] == 40
 
 
-def test_analyze_trace_summarizes_device_lane(tmp_path, monkeypatch):
-    """scripts/analyze_trace.py: lane grouping, python-lane exclusion,
-    per-op aggregation over a synthetic Perfetto trace."""
-    import gzip
+def test_analyze_trace_reads_scopes_and_spans(tmp_path, monkeypatch,
+                                              capsys):
+    """scripts/analyze_trace.py over a real (CPU) profiler capture:
+    the newest ``.xplane.pb`` is found, device time lands under the
+    ``named_scope`` the program wrote, and the idle time under the
+    ``obs.trace`` span the host was in — the chip benchmark's
+    reducers, fronted for an operator's capture."""
+    import time as _time
 
-    events = [
-        {"ph": "M", "pid": 1, "name": "process_name",
-         "args": {"name": "/device:TPU:0"}},
-        {"ph": "M", "pid": 1, "tid": 2, "name": "thread_name",
-         "args": {"name": "XLA Ops"}},
-        {"ph": "M", "pid": 9, "name": "process_name",
-         "args": {"name": "/host:CPU"}},
-        {"ph": "M", "pid": 9, "tid": 3, "name": "thread_name",
-         "args": {"name": "python"}},
-        {"ph": "X", "pid": 1, "tid": 2, "name": "fusion.1",
-         "ts": 0.0, "dur": 100.0},
-        {"ph": "X", "pid": 1, "tid": 2, "name": "fusion.1",
-         "ts": 150.0, "dur": 50.0},
-        {"ph": "X", "pid": 1, "tid": 2, "name": "dot.2",
-         "ts": 300.0, "dur": 700.0},
-        {"ph": "X", "pid": 9, "tid": 3, "name": "frame",
-         "ts": 0.0, "dur": 9999.0},
-    ]
-    d = tmp_path / "plugins" / "profile" / "t1"
-    d.mkdir(parents=True)
-    with gzip.open(d / "m.trace.json.gz", "wt") as f:
-        json.dump({"traceEvents": events}, f)
+    import jax
+    import jax.numpy as jnp
+
+    from rocalphago_tpu.obs import trace
+
+    @jax.jit
+    def work(x):
+        with jax.named_scope("analyze.scope"):
+            return jnp.tanh(x @ x).sum()
+
+    x = jnp.ones((256, 256))
+    jax.block_until_ready(work(x))
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        with trace.span("analyze.phase"):
+            jax.block_until_ready(work(x))
+            _time.sleep(0.05)            # idle under the span
+            jax.block_until_ready(work(x))
+    finally:
+        jax.profiler.stop_trace()
 
     monkeypatch.syspath_prepend(os.path.join(os.path.dirname(
         os.path.dirname(os.path.abspath(__file__))), "scripts"))
     import analyze_trace
 
-    lanes = analyze_trace.summarize(
-        analyze_trace.load_events(analyze_trace.newest_trace(
-            str(tmp_path))))
-    assert list(lanes) == ["/device:TPU:0/XLA Ops"]   # python excluded
-    lane = lanes["/device:TPU:0/XLA Ops"]
-    assert lane["total_us"] == 850.0
-    assert lane["span_us"] == 1000.0
-    assert lane["ops"][0] == ("dot.2", 700.0, 1)
-    assert lane["ops"][1] == ("fusion.1", 150.0, 2)
+    path = analyze_trace.newest_trace(str(tmp_path))
+    assert path.endswith(".xplane.pb")
+    s = analyze_trace.summarize(path)
+    assert 0 < s["busy_s"] <= s["window_s"]
+    scoped = sum(t for k, t in s["by_scope"].items()
+                 if "analyze.scope" in k)
+    assert scoped > 0.5 * s["busy_s"], s["by_scope"]
+    idle = dict(s["idle_by_span"])
+    assert idle.get("rocalphago.analyze.phase", 0) >= 0.04, idle
+    assert analyze_trace.main([str(tmp_path), "--top", "5"]) == 0
+    out = capsys.readouterr().out
+    assert "analyze.scope" in out and "rocalphago.analyze.phase" in out
 
 
 def test_self_size_from_results(tmp_path, monkeypatch):

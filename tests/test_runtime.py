@@ -342,22 +342,26 @@ def _recorded_config_updates(monkeypatch):
     return calls
 
 
-def test_compile_cache_placed_from_outside_sets_nothing(monkeypatch):
-    """``JAX_COMPILATION_CACHE_DIR`` set → JAX's own handling of it is
-    the only configuration: the helper reports the directory and
-    makes NO ``jax.config.update`` call."""
+METADATA_IN_KEY = ("jax_compilation_cache_include_metadata_in_key", True)
+
+
+def test_compile_cache_placed_from_outside_sets_no_directory(monkeypatch):
+    """``JAX_COMPILATION_CACHE_DIR`` set → JAX's own handling of it
+    places the cache: the helper reports the directory and sets none.
+    What it does set, always: metadata in the cache key, so a loaded
+    executable carries THIS program's scope names (obs/scopes.py)."""
     from rocalphago_tpu.runtime.compilecache import enable_compile_cache
 
     calls = _recorded_config_updates(monkeypatch)
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/placed/outside")
     assert enable_compile_cache() == "/placed/outside"
-    assert calls == []
+    assert calls == [METADATA_IN_KEY]
 
 
 def test_compile_cache_defaults_to_the_checkout(monkeypatch):
     """Unset → ``<checkout>/.jax_cache``: one fixed, git-ignored path
     (never from tempfile, a pid or the clock), and nothing but the
-    directory is configured."""
+    directory and the metadata-in-key rule is configured."""
     from rocalphago_tpu.runtime.compilecache import enable_compile_cache
 
     calls = _recorded_config_updates(monkeypatch)
@@ -365,7 +369,8 @@ def test_compile_cache_defaults_to_the_checkout(monkeypatch):
     want = os.path.join(REPO, ".jax_cache")
     assert enable_compile_cache() == want
     assert enable_compile_cache() == want
-    assert calls == [("jax_compilation_cache_dir", want)] * 2
+    assert calls == [("jax_compilation_cache_dir", want),
+                     METADATA_IN_KEY] * 2
     with open(os.path.join(REPO, ".gitignore")) as f:
         assert ".jax_cache/" in f.read().split()
 

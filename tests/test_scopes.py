@@ -1,0 +1,265 @@
+"""The names a profiler trace is read by (``obs/scopes.py``,
+``obs/trace.py``): every scope constant is lowered through the
+program that owns it at a toy size and looked for in ``op_name`` of
+the HLO, and a span is looked for in a real profiler capture.
+
+The train step is COMPILED (its fusions decide the by-scope account
+of ``chipbench/scopes.py``, and the backward pass must keep the
+names); the self-play ply, the leaf evaluation and the tree phases
+are read from the module as lowered, name stacks and all, before
+XLA's optimiser — a compile of the ladder chase per case would cost
+the suite minutes and add nothing about names.
+"""
+
+from __future__ import annotations
+
+import functools
+import os
+import re
+import subprocess
+import sys
+
+import jax
+import jax.numpy as jnp
+import optax
+import pytest
+
+from rocalphago_tpu.engine.jaxgo import GoConfig, new_states
+from rocalphago_tpu.obs import scopes, trace
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+SIZE = 5
+CFG = GoConfig(size=SIZE)
+#: both ladder planes (the shared chase) and a candidate-analysis
+#: plane, so that every ``encode.*`` stage has something to do
+FEATS = ("board", "ones", "capture_size", "ladder_capture",
+         "ladder_escape", "sensibleness")
+VFEATS = FEATS + ("color",)
+
+
+def op_names(hlo_text: str) -> list:
+    return re.findall(r'op_name="([^"]*)"', hlo_text)
+
+
+def lowered_hlo(fn, *args) -> list:
+    """The name-stack of every operation of ``fn`` as lowered: the
+    locations that become ``op_name`` in the compiled HLO, before
+    the optimiser."""
+    text = jax.jit(fn).lower(*args).as_text(debug_info=True)
+    return re.findall(r'loc\("([^"]*)"', text)
+
+
+@pytest.fixture(scope="module")
+def policy():
+    from rocalphago_tpu.models import CNNPolicy
+
+    return CNNPolicy(("board", "ones"), board=SIZE, layers=3,
+                     filters_per_layer=4)
+
+
+@pytest.fixture(scope="module")
+def train_ops(policy):
+    """``op_name``s of the COMPILED supervised train step."""
+    from rocalphago_tpu.io.checkpoint import pack_rng
+    from rocalphago_tpu.training import sl
+
+    tx = sl.make_optimizer(sl.SLConfig())
+    step = sl.make_train_step(policy.module.apply, tx, SIZE, True)
+    state = sl.SLState(policy.params, tx.init(policy.params),
+                       jnp.int32(0), pack_rng(jax.random.key(0)))
+    planes = jnp.zeros((4, SIZE, SIZE, policy.preprocess.output_dim),
+                       jnp.uint8)
+    actions = jnp.zeros((4,), jnp.int32)
+    return op_names(
+        jax.jit(step).lower(state, planes, actions).compile().as_text())
+
+
+@pytest.fixture(scope="module")
+def value_ops():
+    from rocalphago_tpu.io.checkpoint import pack_rng
+    from rocalphago_tpu.models import CNNValue
+    from rocalphago_tpu.training import value as value_lib
+
+    net = CNNValue(("board", "ones", "color"), board=SIZE, layers=2,
+                   filters_per_layer=4)
+    tx = optax.sgd(0.01)
+    step = value_lib.make_train_step(net.module.apply, tx, True)
+    state = value_lib.ValueState(net.params, tx.init(net.params),
+                                 jnp.int32(0),
+                                 pack_rng(jax.random.key(0)))
+    planes = jnp.zeros((4, SIZE, SIZE, net.preprocess.output_dim),
+                       jnp.uint8)
+    return lowered_hlo(step, state, planes, jnp.zeros((4,), jnp.int8))
+
+
+@pytest.fixture(scope="module")
+def ply_ops():
+    from rocalphago_tpu.search.selfplay import _make_ply
+
+    def apply(params, planes):     # reads the planes: no dead encode
+        return jnp.zeros((planes.shape[0], SIZE * SIZE)) \
+            + planes.sum(axis=(1, 2, 3))[:, None]
+
+    ply = _make_ply(CFG, FEATS, apply, apply, 2, 1.0)
+    return lowered_hlo(
+        functools.partial(ply, None, None), new_states(CFG, 2), None,
+        jax.random.key(0), jnp.int32(0))
+
+
+@pytest.fixture(scope="module")
+def search():
+    from rocalphago_tpu.search.device_mcts import make_device_mcts
+
+    def fake_policy(params, planes):
+        return jnp.zeros((planes.shape[0], SIZE * SIZE))
+
+    def fake_value(params, planes):
+        return planes[..., 0].sum(axis=(1, 2)) / (SIZE * SIZE)
+
+    return make_device_mcts(CFG, FEATS, VFEATS, fake_policy,
+                            fake_value, n_sim=4, max_nodes=8)
+
+
+@pytest.fixture(scope="module")
+def sim_ops(search):
+    """One fused simulation — select, expand, the leaf evaluation
+    (``eval_batch``: groups, encode, both nets), backup."""
+    roots = new_states(CFG, 2)
+    tree = search.assemble_tree(
+        roots, jnp.full((2, SIZE * SIZE + 1), 1.0 / (SIZE * SIZE + 1)))
+    return lowered_hlo(lambda t: search.simulate(None, None, t), tree)
+
+
+def has(ops: list, name: str) -> bool:
+    return any(name in op.split("/") or f"({name})" in op
+               for op in ops)
+
+
+# --------------------------------------------------------- every name
+
+TRAIN = [scopes.TRAIN_AUGMENT, scopes.TRAIN_LOSS, scopes.TRAIN_UPDATE]
+PLY = [scopes.PLY_GROUPS, scopes.PLY_ENCODE, scopes.PLY_FORWARD,
+       scopes.PLY_SAMPLE, scopes.PLY_STEP]
+ENCODE = [scopes.ENCODE_CANDIDATES, scopes.ENCODE_LADDER,
+          scopes.ENCODE_PLANES]
+EVAL = [scopes.EVAL_GROUPS, scopes.EVAL_ENCODE, scopes.EVAL_POLICY,
+        scopes.EVAL_VALUE]
+MCTS = [scopes.MCTS_SELECT, scopes.MCTS_EXPAND, scopes.MCTS_BACKUP]
+
+
+def test_every_constant_has_a_case():
+    assert sorted(TRAIN + PLY + ENCODE + EVAL + MCTS) == sorted(
+        scopes.ALL)
+    assert len(set(scopes.ALL)) == len(scopes.ALL)
+
+
+@pytest.mark.parametrize("name", TRAIN)
+def test_train_step_scope_survives_the_compile(train_ops, name):
+    assert has(train_ops, name), sorted(set(train_ops))[:40]
+
+
+def test_train_loss_is_scoped_forward_and_backward(train_ops):
+    loss = [op for op in train_ops if scopes.TRAIN_LOSS in op]
+    assert any(f"jvp({scopes.TRAIN_LOSS})" in op
+               and "transpose(" not in op for op in loss), loss
+    assert any(f"transpose(jvp({scopes.TRAIN_LOSS}))" in op
+               for op in loss), loss
+
+
+def test_flax_module_scopes_are_pinned(train_ops):
+    """The by-scope account leans on Flax naming the network's ops by
+    module (``flax_profile``): forward under ``jvp(<Net>)``, backward
+    under ``transpose(jvp(<Net>))``."""
+    assert any("/jvp(PolicyNet)/trunk/conv1/" in op
+               for op in train_ops)
+    assert any("/transpose(jvp(PolicyNet))/trunk/conv1/" in op
+               for op in train_ops)
+    assert any("/jvp(PolicyNet)/head/conv/" in op for op in train_ops)
+
+
+@pytest.mark.parametrize("name", TRAIN)
+def test_value_step_carries_the_same_scopes(value_ops, name):
+    assert has(value_ops, name)
+
+
+@pytest.mark.parametrize("name", PLY + ENCODE)
+def test_selfplay_ply_scope(ply_ops, name):
+    assert has(ply_ops, name), name
+    if name in ENCODE:      # the encode's stages sit inside the ply's
+        assert any(scopes.PLY_ENCODE in op and name in op
+                   for op in ply_ops)
+
+
+@pytest.mark.parametrize("name", MCTS + EVAL + ENCODE)
+def test_simulation_scope(sim_ops, name):
+    assert has(sim_ops, name), name
+    if name in ENCODE:      # … and inside the leaf evaluation's
+        assert any(scopes.EVAL_ENCODE in op and name in op
+                   for op in sim_ops)
+
+
+def test_scopes_are_written_once():
+    """Every ``named_scope`` in the package takes a constant of
+    ``obs/scopes.py``, and every constant has a call site."""
+    used, pkg = set(), os.path.join(ROOT, "rocalphago_tpu")
+    for folder, _, files in os.walk(pkg):
+        for f in files:
+            if not f.endswith(".py"):
+                continue
+            path = os.path.join(folder, f)
+            with open(path) as fh:
+                text = fh.read()
+            for arg in re.findall(r"named_scope\(\s*([^)]*)\)", text):
+                if os.path.relpath(path, pkg) == os.path.join(
+                        "obs", "scopes.py"):
+                    continue        # its docstring names the call
+                m = re.fullmatch(r"scopes\.([A-Z_]+)", arg.strip())
+                assert m, f"{path}: named_scope({arg}) is not a " \
+                          "constant of obs/scopes.py"
+                used.add(getattr(scopes, m.group(1)))
+    assert used == set(scopes.ALL), set(scopes.ALL) - used
+
+
+# ------------------------------------------- spans on the profiler
+
+def test_span_lands_in_a_profiler_capture(tmp_path):
+    from jax.profiler import ProfileData
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        with trace.span("test.phase"):
+            with trace.annotation("test.item"):
+                jax.block_until_ready(jnp.arange(8) + 1)
+    finally:
+        jax.profiler.stop_trace()
+    found = sorted(
+        os.path.join(folder, f)
+        for folder, _, files in os.walk(tmp_path) for f in files
+        if f.endswith(".xplane.pb"))
+    assert found
+    events = {e.name: (e.start_ns, e.duration_ns)
+              for plane in ProfileData.from_file(found[-1]).planes
+              if plane.name.startswith("/host:")
+              for line in plane.lines for e in line.events
+              if e.name.startswith(trace.ANNOTATION_PREFIX)}
+    assert set(events) == {"rocalphago.test.phase",
+                           "rocalphago.test.item"}
+    (p0, pd), (i0, idur) = (events["rocalphago.test.phase"],
+                            events["rocalphago.test.item"])
+    assert p0 <= i0 and i0 + idur <= p0 + pd     # nested, one clock
+
+
+def test_obs_imports_without_jax_and_spans_still_work():
+    code = (
+        "import sys\n"
+        "import rocalphago_tpu.obs as obs\n"
+        "assert 'jax' not in sys.modules\n"
+        "with obs.span('a'):\n"
+        "    with obs.annotation('b'):\n"
+        "        assert obs.current_path() == 'a'\n"
+        "assert 'jax' not in sys.modules\n")
+    done = subprocess.run([sys.executable, "-c", code], cwd=ROOT,
+                          capture_output=True, text=True, timeout=120)
+    assert done.returncode == 0, done.stderr[-2000:]
