@@ -2,15 +2,19 @@
 
 Parity: the reference SL trainer's ``BOARD_TRANSFORMATIONS`` — 8 board
 symmetries applied randomly per sample on the *host* with
-``np.rot90/fliplr`` (SURVEY.md §2 "SL trainer"). Here the transform is
-a jitted gather on device: one random int per sample picks the group
-element, applied to both the NHWC plane stack and the flat action
-index, so augmentation rides along inside the compiled train step at
-zero host cost.
+``np.rot90/fliplr`` (SURVEY.md §2 "SL trainer"). Here one random int
+per sample picks the group element inside the compiled train step: the
+NHWC plane stack goes through a ``lax.switch`` over the eight
+flip/rot90 variants, and the flat action index through the same map
+written as integer arithmetic on ``(row, col)`` — elementwise on
+``[B]``, no permutation is built.
 
-Group element ``t`` in 0..7 = ``rot90^(t % 4)`` then horizontal flip if
-``t >= 4``; ``inverse_transform`` provides the inverse permutation for
-symmetry-averaged evaluation (used by search).
+The convention, once: group element ``t`` in 0..7 is a flip of axis 1
+(columns, ``c → m − c`` with ``m = size − 1``) when ``t >= 4``,
+*followed by* ``t % 4`` quarter turns as ``jnp.rot90`` makes them
+(counter-clockwise: the value at ``(r, c)`` moves to ``(m − c, r)``).
+``inverse_transform_planes`` undoes it for symmetry-averaged
+evaluation (``models/nn_util.py::forward_symmetric``, search).
 """
 
 from __future__ import annotations
@@ -37,17 +41,20 @@ def transform_planes(x: jax.Array, t: jax.Array) -> jax.Array:
 
 def transform_action(action: jax.Array, t: jax.Array, size: int
                      ) -> jax.Array:
-    """Apply group element ``t`` to a flat board action (pass = ``size²``
-    maps to itself)."""
-    n = size * size
-    grid = jnp.arange(n, dtype=action.dtype).reshape(size, size)
-    # forward-transform the *index grid*: entry (r, c) of the transformed
-    # grid names the source point that lands at (r, c); we need the
-    # inverse map (where does `action` land), so scatter instead:
-    moved = transform_planes(grid, t).reshape(n)      # moved[dst] = src
-    dest = jnp.zeros((n,), action.dtype).at[moved].set(
-        jnp.arange(n, dtype=action.dtype))            # dest[src] = dst
-    return jnp.where(action >= n, action, dest[jnp.minimum(action, n - 1)])
+    """Where group element ``t`` moves a flat board action: the index
+    of the point at which ``transform_planes`` puts that point's value
+    (pass = ``size²`` maps to itself). Elementwise, so scalars and
+    ``[B]`` arrays both work."""
+    n, m = size * size, size - 1
+    t = jnp.asarray(t)
+    p = jnp.minimum(action, n - 1)
+    r, c = jnp.divmod(p, size)
+    c = jnp.where(t >= 4, m - c, c)
+    k = t % 4
+    turns = [k == 0, k == 1, k == 2]
+    r2 = jnp.select(turns, [r, m - c, m - r], c)
+    c2 = jnp.select(turns, [c, r, m - c], m - r)
+    return jnp.where(action >= n, action, r2 * size + c2)
 
 
 def inverse_transform_planes(x: jax.Array, t: jax.Array) -> jax.Array:
@@ -72,6 +79,5 @@ def random_transform_batch(rng: jax.Array, planes: jax.Array,
     (``planes [B,s,s,F]``, ``actions [B]``)."""
     t = jax.random.randint(rng, (planes.shape[0],), 0, 8)
     planes = jax.vmap(transform_planes)(planes, t)
-    actions = jax.vmap(
-        lambda a, ti: transform_action(a, ti, size))(actions, t)
+    actions = transform_action(actions, t, size)
     return planes, actions

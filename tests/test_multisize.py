@@ -120,27 +120,80 @@ def test_value_symmetric_invariant_across_sizes(fcn_nets, size):
 
 @pytest.mark.parametrize("size", [5, 9, 13, 19])
 def test_symmetry_transforms_round_trip(size):
-    """transform/inverse_transform are exact inverses and the action
-    map agrees with the plane map, at every supported size (pass maps
-    to itself)."""
+    """transform/inverse_transform are exact inverses at every
+    supported size (the action map has its own test, below)."""
     from rocalphago_tpu.training.symmetries import (
         inverse_transform_planes,
-        transform_action,
         transform_planes,
     )
 
     rng = np.random.default_rng(size)
     x = jnp.asarray(rng.standard_normal((size, size, 2)), jnp.float32)
-    n = size * size
-    action = jnp.int32(1 * size + 2)       # an off-axis point
-    onehot = jnp.zeros((size, size, 1)).at[1, 2, 0].set(1.0)
     for t in range(8):
         rt = inverse_transform_planes(transform_planes(x, t), t)
         np.testing.assert_array_equal(np.asarray(rt), np.asarray(x))
-        moved = int(transform_action(action, t, size))
-        grid = np.asarray(transform_planes(onehot, t))[:, :, 0]
-        assert moved == int(np.flatnonzero(grid.reshape(n))[0])
-        assert int(transform_action(jnp.int32(n), t, size)) == n
+
+
+@pytest.mark.parametrize("t", range(8))
+@pytest.mark.parametrize("size", [5, 9, 13, 19])
+def test_transform_action_follows_the_planes(size, t):
+    """Every point, every group element: the action map sends a point
+    to where ``transform_planes`` puts that point's stone, it is a
+    permutation of the board, and pass stays pass."""
+    from rocalphago_tpu.training.symmetries import (
+        transform_action,
+        transform_planes,
+    )
+
+    n = size * size
+    moved = np.asarray(transform_action(
+        jnp.arange(n + 1, dtype=jnp.int32), jnp.int32(t), size))
+    # row a of `boards` is the one-hot board of action a
+    boards = jnp.eye(n, dtype=jnp.float32).reshape(n, size, size)
+    landed = np.asarray(jax.vmap(
+        lambda b: transform_planes(b, jnp.int32(t)))(boards))
+    landed = landed.reshape(n, n)
+    assert (landed.sum(axis=1) == 1).all()
+    np.testing.assert_array_equal(moved[:n], landed.argmax(axis=1))
+    assert sorted(moved[:n]) == list(range(n))
+    assert moved[n] == n
+
+
+def test_random_transform_batch_matches_numpy_oracle():
+    """The draw of ``t`` from the key and the pairing of each sample's
+    planes with its action, against ``np.rot90``/``np.flip`` on one-hot
+    boards — what the benchmark's reference inputs rely on."""
+    from rocalphago_tpu.training.symmetries import random_transform_batch
+
+    size, batch = 19, 16
+    n = size * size
+    key = jax.random.key(25)
+    rng = np.random.default_rng(25)
+    planes = rng.standard_normal((batch, size, size, 3)).astype(
+        np.float32)
+    actions = rng.integers(0, n + 1, batch).astype(np.int32)
+    actions[0], actions[1] = n, 0        # a pass and a corner for sure
+    got_p, got_a = random_transform_batch(
+        key, jnp.asarray(planes), jnp.asarray(actions), size)
+
+    ts = np.asarray(jax.random.randint(key, (batch,), 0, 8))
+    assert len(set(ts)) > 4              # the key exercises the group
+
+    def oracle(a, t):
+        if t >= 4:
+            a = np.flip(a, axis=1)
+        return np.rot90(a, t % 4)
+
+    for i, t in enumerate(ts):
+        np.testing.assert_array_equal(
+            np.asarray(got_p[i]), oracle(planes[i], t))
+        if actions[i] == n:
+            assert int(got_a[i]) == n
+            continue
+        onehot = np.zeros((size, size), np.float32)
+        onehot[actions[i] // size, actions[i] % size] = 1.0
+        assert int(got_a[i]) == int(
+            np.flatnonzero(oracle(onehot, t).reshape(n))[0])
 
 
 # ------------------------------------------------- per-session komi

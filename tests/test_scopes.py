@@ -58,8 +58,8 @@ def policy():
 
 
 @pytest.fixture(scope="module")
-def train_ops(policy):
-    """``op_name``s of the COMPILED supervised train step."""
+def train_hlo(policy):
+    """The COMPILED supervised train step, as HLO text."""
     from rocalphago_tpu.io.checkpoint import pack_rng
     from rocalphago_tpu.training import sl
 
@@ -70,8 +70,13 @@ def train_ops(policy):
     planes = jnp.zeros((4, SIZE, SIZE, policy.preprocess.output_dim),
                        jnp.uint8)
     actions = jnp.zeros((4,), jnp.int32)
-    return op_names(
-        jax.jit(step).lower(state, planes, actions).compile().as_text())
+    return jax.jit(step).lower(
+        state, planes, actions).compile().as_text()
+
+
+@pytest.fixture(scope="module")
+def train_ops(train_hlo):
+    return op_names(train_hlo)
 
 
 @pytest.fixture(scope="module")
@@ -164,6 +169,16 @@ def test_train_loss_is_scoped_forward_and_backward(train_ops):
                and "transpose(" not in op for op in loss), loss
     assert any(f"transpose(jvp({scopes.TRAIN_LOSS}))" in op
                for op in loss), loss
+
+
+def test_augmentation_holds_no_scatter(train_hlo):
+    """The expert moves go through the dihedral transform by
+    arithmetic: a scatter under ``train.augment`` was the longest
+    non-convolution op of the step on the chip (PERF.md, PR 25)."""
+    augment = [line for line in train_hlo.splitlines()
+               if scopes.TRAIN_AUGMENT in line]
+    assert augment
+    assert not [line for line in augment if "scatter" in line]
 
 
 def test_flax_module_scopes_are_pinned(train_ops):
