@@ -155,7 +155,8 @@ class GameConverter:
 
     # ------------------------------------------------------------- corpora
 
-    def _iter_sgf_files(self, directory: str, recurse: bool):
+    @staticmethod
+    def _iter_sgf_files(directory: str, recurse: bool):
         if recurse:
             for root, _, names in sorted(os.walk(directory)):
                 for name in sorted(names):
@@ -272,6 +273,76 @@ class GameConverter:
         return n_positions
 
 
+def game_ids(game) -> list:
+    """One game as move ids: a board point is ``x·size + y`` (the
+    action the plane converter emits), a pass ``size²``."""
+    n = game.size * game.size
+    return [n if move is None else move[0] * game.size + move[1]
+            for _, move in game.moves]
+
+
+def sgfs_to_sequences(files, out_prefix: str, seq_len: int,
+                      board_size: int = 19, shard_size: int = 1024,
+                      ignore_errors: bool = True) -> dict:
+    """Convert SGF files to packed id rows for a sequence policy
+    (``models/seqpolicy.py``): games end to end, a separator
+    (``size² + 1``) after each, cut into rows of ``seq_len`` inputs
+    whose label at each token is the next token. Same shard files and
+    manifest as :meth:`GameConverter.sgfs_to_shards` — ``states`` are
+    the id rows ``[n, seq_len]``, ``actions`` the next ids, and
+    ``planes`` is 0 — so ``ShardedDataset`` and the SL trainer read
+    them as they read plane shards. A tail shorter than a row is
+    dropped."""
+    parent = os.path.dirname(out_prefix)
+    if parent:
+        os.makedirs(parent, exist_ok=True)
+    separator = board_size * board_size + 1
+    stream, rows, counts, errors = [], [], [], []
+    n_games = 0
+
+    def flush(everything: bool = False):
+        while rows and (everything or len(rows) >= shard_size):
+            part = np.asarray(rows[:shard_size], np.int32)
+            del rows[:shard_size]
+            np.savez_compressed(
+                f"{out_prefix}-{len(counts):05d}.npz",
+                states=part[:, :-1], actions=part[:, 1:])
+            counts.append(len(part))
+
+    for path in files:
+        try:
+            with open(path, "r", errors="replace") as f:
+                game = sgflib.parse(f.read())
+            if game.size != board_size:
+                raise sgflib.SGFError(
+                    f"board size {game.size} != {board_size}")
+        except (sgflib.SGFError, OSError, ValueError) as e:
+            if not ignore_errors:
+                raise
+            errors.append({"file": path, "error": str(e)})
+            warnings.warn(f"skipping {path}: {e}")
+            continue
+        n_games += 1
+        stream.extend(game_ids(game) + [separator])
+        # a row is seq_len + 1 ids; the next row starts at its last
+        while len(stream) > seq_len:
+            rows.append(stream[:seq_len + 1])
+            del stream[:seq_len]
+        flush()
+    flush(everything=True)
+    manifest = {
+        "format": "rocalphago_tpu/npz-shards/v1",
+        "board_size": board_size, "features": [], "planes": 0,
+        "layout": "id rows", "seq_len": seq_len,
+        "num_shards": len(counts), "num_positions": sum(counts),
+        "num_games": n_games, "shard_counts": counts,
+        "errors": errors,
+    }
+    with open(f"{out_prefix}-manifest.json", "w") as f:
+        json.dump(manifest, f, indent=2)
+    return manifest
+
+
 def run_game_converter(argv=None):
     """CLI mirroring the reference's ``run_game_converter``."""
     ap = argparse.ArgumentParser(
@@ -284,8 +355,21 @@ def run_game_converter(argv=None):
     ap.add_argument("--size", type=int, default=19)
     ap.add_argument("--format", choices=("npz", "hdf5"), default="npz")
     ap.add_argument("--shard-size", type=int, default=8192)
+    ap.add_argument("--sequence", type=int, default=None, metavar="LEN",
+                    help="write packed move-id rows of LEN tokens for "
+                         "a sequence policy instead of feature planes "
+                         "(npz shards; --shard-size counts rows)")
     args = ap.parse_args(argv)
 
+    if args.sequence:
+        manifest = sgfs_to_sequences(
+            GameConverter._iter_sgf_files(args.directory,
+                                          args.recurse),
+            args.outfile, args.sequence, board_size=args.size,
+            shard_size=args.shard_size)
+        print(json.dumps({k: manifest[k] for k in
+                          ("num_shards", "num_positions", "num_games")}))
+        return
     conv = GameConverter(tuple(args.features.split(",")),
                          board_size=args.size)
     files = conv._iter_sgf_files(args.directory, args.recurse)

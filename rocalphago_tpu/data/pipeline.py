@@ -57,9 +57,9 @@ class ShardedDataset:
 
     def gather(self, indices: np.ndarray):
         """(states [b,s,s,F] uint8, actions [b] int32) for global
-        indices (any order)."""
-        states = None
-        actions = np.empty(len(indices), np.int32)
+        indices (any order) — or a sequence corpus's id rows and next
+        ids, both ``[b, seq_len]`` int32."""
+        states = actions = None
         shard_ids = np.searchsorted(self._starts, indices, "right") - 1
         for sid in np.unique(shard_ids):
             s_states, s_actions = self._shard(int(sid))
@@ -68,8 +68,12 @@ class ShardedDataset:
             if states is None:
                 states = np.empty(
                     (len(indices),) + s_states.shape[1:], s_states.dtype)
+                actions = np.empty(
+                    (len(indices),) + s_actions.shape[1:], np.int32)
             states[sel] = s_states[local]
             actions[sel] = s_actions[local]
+        if actions is None:         # no index: no shard was opened
+            actions = np.empty(0, np.int32)
         return states, actions
 
 

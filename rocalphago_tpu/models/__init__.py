@@ -14,4 +14,8 @@ from rocalphago_tpu.models.rollout import (  # noqa: F401
     CNNRollout,
     RolloutNet,
 )
+from rocalphago_tpu.models.seqpolicy import (  # noqa: F401
+    SeqPolicy,
+    SeqPolicyNet,
+)
 from rocalphago_tpu.models.value import CNNValue, ValueNet  # noqa: F401

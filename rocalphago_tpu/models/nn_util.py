@@ -28,14 +28,15 @@ import os
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import msgpack
 import numpy as np
 from flax import serialization
 
 from rocalphago_tpu.engine import jaxgo, pygo
 from rocalphago_tpu.features import DEFAULT_FEATURES, Preprocess
 from rocalphago_tpu.runtime.atomic import (
-    atomic_write_bytes,
     atomic_write_json,
+    atomic_writer,
 )
 
 NEURALNETS: dict[str, type] = {}
@@ -142,6 +143,28 @@ class PointHead(nn.Module):
         return logits
 
 
+def stream_msgpack(f, state) -> None:
+    """Write a state dict to ``f`` as Flax's msgpack, one leaf at a
+    time: only the leaf being written is copied to the host."""
+    packer = msgpack.Packer(default=serialization._msgpack_ext_pack,
+                            strict_types=True)
+
+    def walk(node):
+        if isinstance(node, dict):
+            f.write(packer.pack_map_header(len(node)))
+            for key, value in node.items():
+                f.write(packer.pack(key))
+                walk(value)
+        elif isinstance(node, (jax.Array, np.ndarray)):
+            leaf = {"": np.asarray(node)}   # chunked if over 1 GiB,
+            f.write(packer.pack(            # as to_bytes does
+                serialization._chunk_array_leaves_in_place(leaf)[""]))
+        else:
+            f.write(packer.pack(node))
+
+    walk(state)
+
+
 def neuralnet(cls):
     """Class decorator registering a network for spec-based loading."""
     NEURALNETS[cls.__name__] = cls
@@ -157,6 +180,9 @@ class NeuralNetBase:
     """
 
     module = None  # flax module, set by subclass __init__
+    #: ranks of a training batch's (inputs, targets): feature planes
+    #: ``[B, s, s, F]`` and one move or outcome per position
+    batch_ranks = (4, 1)
 
     def __init__(self, feature_list=DEFAULT_FEATURES, *, board: int = 19,
                  init_weights: bool = True, seed: int = 0, **kwargs):
@@ -173,6 +199,16 @@ class NeuralNetBase:
                 (1, board, board, self.preprocess.output_dim), jnp.float32)
             self.params = self.module.init(jax.random.key(seed), dummy)
         self._apply = jax.jit(self.module.apply)
+
+    @property
+    def input_planes(self) -> int:
+        """Feature planes a training corpus must carry for this net."""
+        return self.preprocess.output_dim
+
+    @property
+    def num_outputs(self) -> int:
+        """Size of a policy's output space: the board's points."""
+        return self.board * self.board
 
     # ------------------------------------------------------------- forward
 
@@ -274,16 +310,25 @@ class NeuralNetBase:
     def save_weights(self, weights_file: str):
         # atomic tmp+fsync+rename: concurrent readers (multi-host
         # opponent pools waiting on snapshot visibility) and post-crash
-        # resumes must never see a half-written msgpack
-        atomic_write_bytes(weights_file,
-                           serialization.to_bytes(self.params))
+        # resumes must never see a half-written msgpack. Streamed a
+        # leaf at a time (the bytes ``serialization.to_bytes`` would
+        # build, without building them): a billion-parameter tree is
+        # never on the host twice
+        with atomic_writer(weights_file) as f:
+            stream_msgpack(f, serialization.to_state_dict(self.params))
 
     def load_weights(self, weights_file: str):
-        with open(weights_file, "rb") as f:
-            data = f.read()
         try:
-            self.params = serialization.from_bytes(self.params, data)
-        except (ValueError, KeyError) as e:
+            with open(weights_file, "rb") as f:
+                # unpacked from the file as it is read: the tree, not
+                # the file's bytes and then the tree
+                state = msgpack.Unpacker(
+                    f, ext_hook=serialization._msgpack_ext_unpack,
+                    raw=False, max_buffer_size=0).unpack()
+            self.params = serialization.from_state_dict(
+                self.params,
+                serialization._unchunk_array_leaves_in_place(state))
+        except (ValueError, KeyError, msgpack.UnpackException) as e:
             # surface pytree mismatches with the likely causes instead
             # of a bare msgpack error; don't over-claim which one it is
             raise ValueError(

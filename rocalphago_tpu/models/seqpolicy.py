@@ -1,0 +1,707 @@
+"""Move-sequence policy: a causal decoder over the game record.
+
+The SL stage learns "next expert move given what came before". The
+conv policy reads what came before from 48 hand-made planes of one
+position; this one reads the record itself. A token is a move (board
+points ``0..size²-1``, pass ``size²``, a game separator ``size²+1``),
+a row is several games packed end to end, the label at each token is
+the next token, and a *position* is one token at which the next move
+is predicted. Logits are ``[B, S, vocab_held]``.
+
+The decoder is the published ``laguna`` block (poolside Laguna-S-2.1,
+``config.json``), driven by that file's keys under their own names:
+
+* pre-norm residual blocks, RMSNorm, no biases;
+* grouped-query attention with a PER-LAYER query-head count
+  (``num_attention_heads_per_layer``), a per-head sigmoid output gate
+  (``gating: per-head``), and a layer pattern (``layer_types``) of
+  full and sliding-window layers, each kind with its own rotary
+  (``rope_parameters``: YaRN on half the head for full layers, plain
+  RoPE on the whole head for sliding ones);
+* a dense SwiGLU MLP where ``mlp_layer_types`` says ``dense`` and
+  elsewhere ``num_experts`` routed SwiGLU experts, top
+  ``num_experts_per_tok`` by a float32 softmax router, renormalised
+  (``norm_topk_prob``), scaled (``moe_routed_scaling_factor``), plus
+  one shared expert.
+
+**The held share.** A spec also says what part of the model lives
+here: ``layers_held`` leading layers, ``vocab_held`` leading rows of
+the vocabulary (embedding, head, logits and loss are over the slice),
+and ``experts_held`` routed experts starting at ``expert_offset``.
+The router keeps its published width and its experts per token; the
+expert layer computes its own experts' part of the result for the
+tokens routed to them, and what the absent experts would add is left
+out — that partial result goes on to the next layer. Nothing stands
+in for the absent chips or their exchange.
+
+**How it runs on the chip** (bf16 compute, float32 parameters, float32
+router, softmax and loss):
+
+* attention never holds an ``S × S`` array. On a TPU, at shapes its
+  tiles divide, it is JAX's own block-sparse flash kernel (Pallas
+  ``splash_attention``: grouped-query natively, a causal or a local
+  mask per layer kind, fully masked blocks skipped, its own backward
+  kernels) — :func:`kernel_attention`. Anywhere else (the CPU tests,
+  a toy shape) it is plain XLA, one key/value group at a time
+  (``lax.map``): causal query blocks over their key prefix (full
+  layers; each block recomputed in the backward pass) or one banded
+  product of every query block against itself and its predecessor
+  (sliding layers, ``block = window``) — :func:`grouped_attention`.
+  On the v5e the XLA form spent 3.2 s a step in its softmax's lane
+  reductions (PERF.md, PR 26), which is why the kernel is there;
+* the expert product is ``jax.lax.ragged_dot`` over token–expert
+  pairs sorted by held expert — a grouped matrix product over a
+  ragged split. The pairs sent here are sorted to the front of a
+  buffer that holds EVERY pair a chunk of tokens could send
+  (``chunk × top_k`` rows), so no pair is dropped however unbalanced
+  the routing; rows past the held pairs are skipped by the product.
+  What the buffer left out is counted from the buffer (``moe_dropped``),
+  not assumed;
+* every layer is recomputed in the backward pass (``nn.remat``): what
+  a step saves is one ``[B, S, hidden]`` input per layer.
+"""
+
+from __future__ import annotations
+
+import functools
+import math
+from typing import NamedTuple
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from rocalphago_tpu.models.nn_util import NeuralNetBase, neuralnet
+from rocalphago_tpu.obs import scopes
+
+#: masked scores: far below any real one, and finite
+NEG = -1e30
+#: the parts of a step's returned metrics that count routing
+MOE_STATS = ("moe_routed", "moe_held", "moe_dropped", "moe_load_max")
+
+
+class Rope(NamedTuple):
+    """One layer kind's rotary embedding, as ``rope_parameters`` has
+    it: ``dims`` leading dimensions of the head are rotated."""
+
+    kind: str               # "default" | "yarn"
+    theta: float
+    dims: int
+    factor: float = 1.0
+    original: int = 0
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+
+class LayerSpec(NamedTuple):
+    heads: int              # query heads of this layer
+    window: int             # 0 = full attention
+    rope: Rope
+    sparse: bool            # routed experts (else the dense MLP)
+
+
+def rope_inv_freq(rope: Rope) -> np.ndarray:
+    """Inverse frequencies ``[dims / 2]``. YaRN blends interpolated
+    and extrapolated frequencies by a linear ramp between the
+    dimensions that turn ``beta_fast`` and ``beta_slow`` times over
+    the original context (Peng et al. 2023, as ``transformers``
+    computes it)."""
+    dims = rope.dims
+    pos = rope.theta ** (np.arange(0, dims, 2, dtype=np.float64) / dims)
+    if rope.kind == "default":
+        return (1.0 / pos).astype(np.float32)
+
+    def correction_dim(turns: float) -> float:
+        return (dims * math.log(rope.original / (turns * 2 * math.pi))
+                / (2 * math.log(rope.theta)))
+
+    low = max(math.floor(correction_dim(rope.beta_fast)), 0)
+    high = min(math.ceil(correction_dim(rope.beta_slow)), dims - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dims // 2) - low) / (high - low), 0, 1)
+    extrapolation = 1.0 - ramp
+    inv = (1.0 / (rope.factor * pos)) * (1 - extrapolation) \
+        + (1.0 / pos) * extrapolation
+    return inv.astype(np.float32)
+
+
+def apply_rope(x: jax.Array, rope: Rope, scale: float = 1.0
+               ) -> jax.Array:
+    """Rotate the leading ``rope.dims`` of each head of ``x``
+    ``[B, S, H, head_dim]`` by position (half-split pairing:
+    dimension ``i`` with ``i + dims/2``), in float32; ``scale``
+    (the queries' ``1/√d``) is applied before the cast back."""
+    d = rope.dims
+    pos = jnp.arange(x.shape[1], dtype=jnp.float32)
+    angle = pos[:, None] * jnp.asarray(rope_inv_freq(rope))[None, :]
+    cos = (jnp.cos(angle) * rope.attention_factor)[None, :, None, :]
+    sin = (jnp.sin(angle) * rope.attention_factor)[None, :, None, :]
+    xf = x.astype(jnp.float32)
+    a, b, rest = xf[..., :d // 2], xf[..., d // 2:d], xf[..., d:]
+    out = jnp.concatenate(
+        [a * cos - b * sin, b * cos + a * sin, rest], axis=-1)
+    return (out * scale).astype(x.dtype)
+
+
+# ------------------------------------------------------------ attention
+#
+# Both forms take queries already scaled by 1/√d.
+
+#: tile of the attention kernel: query and key blocks, forward and
+#: backward (MaxText's choice for the v5e)
+KERNEL_BLOCK = 512
+#: query block of the XLA form's full layers (a shorter row is one
+#: block)
+ATTENTION_BLOCK = 1024
+
+
+def kernel_platform() -> str:
+    """The platform whose attention the trace is for."""
+    return jax.default_backend()
+
+
+def use_kernel(s_len: int, d: int) -> bool:
+    return (kernel_platform() == "tpu" and d % 128 == 0
+            and s_len % KERNEL_BLOCK == 0)
+
+
+def kernel_attention(q, k, v, window: int, interpret: bool = False):
+    """Causal grouped-query attention by the splash kernel:
+    ``q [B, S, H, d]``, ``k, v [B, S, G, d]`` → ``[B, S, H, d]``.
+    ``window`` 0 is full attention, else ``i − j < window``."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as splash,
+        splash_attention_mask as masks,
+    )
+
+    s_len, h = q.shape[1], q.shape[2]
+    one = (masks.LocalMask((s_len, s_len), (window - 1, 0), 0)
+           if window and window < s_len
+           else masks.CausalMask((s_len, s_len)))
+    b = KERNEL_BLOCK
+    kernel = splash.make_splash_mha_single_device(
+        masks.MultiHeadMask([one] * h),
+        block_sizes=splash.BlockSizes(
+            block_q=b, block_kv=b, block_kv_compute=b, block_q_dkv=b,
+            block_kv_dkv=b, block_kv_dkv_compute=b, block_q_dq=b,
+            block_kv_dq=b),
+        interpret=interpret)
+    with jax.named_scope(scopes.SEQ_ATTN_KERNEL):
+        out = jax.vmap(kernel)(*(x.transpose(0, 2, 1, 3)
+                                 for x in (q, k, v)))
+    return out.transpose(0, 2, 1, 3)
+
+
+def _softmax_pv(s: jax.Array, mask: jax.Array, v: jax.Array,
+                spec: str) -> jax.Array:
+    """float32 softmax of masked scores, then the product with
+    ``v`` in ``v``'s type."""
+    s = jnp.where(mask, s, NEG)
+    p = jnp.exp(s - s.max(axis=-1, keepdims=True))
+    p = p / p.sum(axis=-1, keepdims=True)
+    return jnp.einsum(spec, p.astype(v.dtype), v)
+
+
+def _prefix_block(q, k, v, q0: int):
+    """Causal attention of one query block ``[R, bq, d]`` at offset
+    ``q0`` over its key prefix ``[L, d]``."""
+    s = jnp.einsum("rqd,kd->rqk", q, k,
+                   preferred_element_type=jnp.float32)
+    qpos = q0 + jnp.arange(q.shape[1])
+    mask = qpos[:, None] >= jnp.arange(k.shape[0])[None, :]
+    return _softmax_pv(s, mask[None], v, "rqk,kd->rqd")
+
+
+def _group_full(q, k, v):
+    """One key/value group, causal: ``q [R, S, d]``, ``k, v [S, d]``.
+    Query blocks over their key prefixes — about half the products of
+    the square — each recomputed in the backward pass, so that one
+    block's scores are all that ever exists."""
+    s_len = q.shape[1]
+    bq = min(ATTENTION_BLOCK, s_len)
+    if s_len % bq:
+        raise ValueError(f"sequence {s_len} is not whole blocks of {bq}")
+    outs = []
+    for i in range(s_len // bq):
+        hi = (i + 1) * bq
+        outs.append(jax.checkpoint(
+            functools.partial(_prefix_block, q0=i * bq))(
+                q[:, i * bq:hi], k[:hi], v[:hi]))
+    return jnp.concatenate(outs, axis=1)
+
+
+def _group_window(q, k, v, window: int):
+    """One key/value group, causal within ``window`` (``i − j <
+    window``): every query block of ``window`` positions against
+    itself and the block before it, as one banded product."""
+    r, s_len, d = q.shape
+    nb = s_len // window
+    if s_len % window:
+        raise ValueError(
+            f"sequence {s_len} is not whole windows of {window}")
+    qb = q.reshape(r, nb, window, d)
+
+    def with_previous(x):
+        xb = x.reshape(nb, window, d)
+        prev = jnp.concatenate([jnp.zeros_like(xb[:1]), xb[:-1]])
+        return jnp.concatenate([prev, xb], axis=1)     # [nb, 2w, d]
+
+    k2, v2 = with_previous(k), with_previous(v)
+    s = jnp.einsum("rnqd,nkd->rnqk", qb, k2,
+                   preferred_element_type=jnp.float32)
+    qq = jnp.arange(window)[:, None]
+    kk = jnp.arange(2 * window)[None, :]
+    # key kk of the pair is position kk − window relative to the
+    # block: causal kk − window ≤ qq, in the window kk > qq, and the
+    # first block has no predecessor
+    band = (kk <= qq + window) & (kk > qq)
+    real = (jnp.arange(nb)[:, None, None] > 0) | (kk >= window)[None]
+    out = _softmax_pv(s, (band[None] & real)[None], v2,
+                      "rnqk,nkd->rnqd")
+    return out.reshape(r, s_len, d)
+
+
+def grouped_attention(q, k, v, window: int):
+    """Causal grouped-query attention without an ``S × S`` array, in
+    plain XLA: ``q [B, S, H, d]``, ``k, v [B, S, G, d]`` →
+    ``[B, S, H, d]``. ``window`` 0 (or one that covers the sequence)
+    is full attention."""
+    b, s_len, h, d = q.shape
+    g = k.shape[2]
+    r = h // g
+    qg = q.reshape(b, s_len, g, r, d).transpose(0, 2, 3, 1, 4)
+    qg = qg.reshape(b * g, r, s_len, d)
+    kg = k.transpose(0, 2, 1, 3).reshape(b * g, s_len, d)
+    vg = v.transpose(0, 2, 1, 3).reshape(b * g, s_len, d)
+    if window and window < s_len:
+        one = functools.partial(_group_window, window=window)
+    else:
+        one = _group_full
+    # a group's scores are recomputed in the backward pass, not kept
+    # for every group at once
+    out = jax.lax.map(jax.checkpoint(lambda a: one(*a)), (qg, kg, vg))
+    out = out.reshape(b, g, r, s_len, d).transpose(0, 3, 1, 2, 4)
+    return out.reshape(b, s_len, h, d)
+
+
+# -------------------------------------------------------------- modules
+
+def _weight(module, name: str, shape: tuple, dtype):
+    """A float32 parameter, in the compute type."""
+    return module.param(name, nn.initializers.normal(0.02), shape,
+                        jnp.float32).astype(dtype)
+
+
+class RMSNorm(nn.Module):
+    eps: float = 1e-6
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        scale = self.param("scale", nn.initializers.ones,
+                           (x.shape[-1],), jnp.float32)
+        xf = x.astype(jnp.float32)
+        var = jnp.mean(xf * xf, axis=-1, keepdims=True)
+        return xf * jax.lax.rsqrt(var + self.eps) * scale
+
+
+def _swiglu(x, w_gate, w_up, w_down):
+    g = jnp.dot(x, w_gate).astype(jnp.float32)
+    u = jnp.dot(x, w_up).astype(jnp.float32)
+    return jnp.dot((jax.nn.silu(g) * u).astype(x.dtype), w_down)
+
+
+class SwiGLU(nn.Module):
+    width: int
+    dtype: jnp.dtype = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        d = x.shape[-1]
+        return _swiglu(
+            x, _weight(self, "gate_proj", (d, self.width), self.dtype),
+            _weight(self, "up_proj", (d, self.width), self.dtype),
+            _weight(self, "down_proj", (self.width, d), self.dtype))
+
+
+class GatedAttention(nn.Module):
+    spec: LayerSpec
+    kv_heads: int
+    head_dim: int
+    dtype: jnp.dtype = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        spec, hd = self.spec, self.head_dim
+        b, s_len, d = x.shape
+        h, g = spec.heads, self.kv_heads
+        q = jnp.dot(x, _weight(self, "q_proj", (d, h * hd), self.dtype))
+        k = jnp.dot(x, _weight(self, "k_proj", (d, g * hd), self.dtype))
+        v = jnp.dot(x, _weight(self, "v_proj", (d, g * hd), self.dtype))
+        gate = jax.nn.sigmoid(jnp.dot(
+            x, _weight(self, "gate_proj", (d, h), self.dtype)
+        ).astype(jnp.float32))
+        q = apply_rope(q.reshape(b, s_len, h, hd), spec.rope,
+                       1.0 / math.sqrt(hd))
+        k = apply_rope(k.reshape(b, s_len, g, hd), spec.rope)
+        v = v.reshape(b, s_len, g, hd)
+        if use_kernel(s_len, hd):
+            a = kernel_attention(q, k, v, spec.window)
+        else:
+            a = grouped_attention(q, k, v, spec.window)
+        a = (a * gate[..., None]).astype(self.dtype)
+        return jnp.dot(a.reshape(b, s_len, h * hd),
+                       _weight(self, "o_proj", (h * hd, d), self.dtype))
+
+
+@jax.custom_vjp
+def _dispatch(x, order, inverse, held):
+    """Rows of ``x [T, D]`` in pair order: row ``i`` is the token of
+    sorted pair ``order[i]`` (``order`` may be a leading part of the
+    sort: the buffer's rows)."""
+    return x[order // held.shape[1]]
+
+
+def _dispatch_fwd(x, order, inverse, held):
+    return _dispatch(x, order, inverse, held), (inverse, held)
+
+
+def _from_rows(rows, inverse, held):
+    """``[T, K, D]``: each pair's row of the buffer ``rows [R, D]``,
+    zero for a pair that was not sent here or whose place in the sort
+    lies past the buffer."""
+    t, k = held.shape
+    r = rows.shape[0]
+    inside = held & (inverse.reshape(t, k) < r)
+    at = jnp.minimum(inverse, r - 1)
+    return jnp.where(inside[..., None], rows[at].reshape(t, k, -1), 0)
+
+
+def _dispatch_bwd(res, g):
+    # a gather by the inverse order instead of a scatter-add
+    inverse, held = res
+    back = _from_rows(g, inverse, held).astype(jnp.float32)
+    return back.sum(axis=1).astype(g.dtype), None, None, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _combine(y, order, inverse, held):
+    """The pairs' results ``y [R, D]`` back in token order
+    ``[T, K, D]``, zero where a pair was not sent here."""
+    return _from_rows(y, inverse, held)
+
+
+def _combine_fwd(y, order, inverse, held):
+    return _combine(y, order, inverse, held), (order, held)
+
+
+def _combine_bwd(res, g):
+    order, held = res
+    g = jnp.where(held[..., None], g, 0)
+    return g.reshape(-1, g.shape[-1])[order], None, None, None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+#: tokens the expert layer takes at a time: its buffers are this many
+#: times ``top_k`` rows long (fewer tokens are one chunk)
+EXPERT_CHUNK = 4096
+
+
+def held_experts(x, local, weight, w_gate, w_up, w_down,
+                 rows: int | None = None):
+    """What the held experts add for one chunk of tokens.
+
+    ``x [T, D]``; ``local [T, K]`` the index of each chosen expert
+    among the ``E`` held ones (anything outside ``0..E-1``: not held
+    here); ``weight [T, K]`` float32; the held experts' matrices
+    ``[E, D, F]``, ``[E, D, F]``, ``[E, F, D]``. Returns the weighted
+    sum ``[T, D]`` float32, the pairs per held expert ``[E]`` and the
+    pairs sent here that the buffer left out.
+
+    The pairs sent here are sorted to the front of a buffer of
+    ``rows`` rows — all ``T·K`` unless a caller says otherwise, so
+    that every pair fits however the router sends them. The count of
+    pairs left out is what arrived less what the products were given:
+    a buffer cut below what arrives shows in it."""
+    t, k = local.shape
+    rows = t * k if rows is None else rows
+    e = w_gate.shape[0]
+    held = (local >= 0) & (local < e)
+    key = jnp.where(held, local, e).reshape(-1)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    inverse = jnp.zeros_like(order).at[order].set(
+        jnp.arange(t * k, dtype=jnp.int32), unique_indices=True)
+    sizes = (key[:, None] == jnp.arange(e)[None, :]).sum(
+        axis=0, dtype=jnp.int32)
+    mine = order[:rows]
+    # each expert's pairs that lie inside the buffer
+    ends = jnp.minimum(jnp.cumsum(sizes), rows)
+    computed = jnp.diff(ends, prepend=0)
+    row_held = (jnp.arange(rows) < ends[-1])[:, None]
+    xs = _dispatch(x, mine, inverse, held)
+
+    def product(a, w):
+        # rows past the held pairs belong to no group: the product
+        # skips them, and what it leaves there is never read
+        return jnp.where(row_held,
+                         jax.lax.ragged_dot(a, w, computed), 0)
+
+    g = product(xs, w_gate).astype(jnp.float32)
+    u = product(xs, w_up).astype(jnp.float32)
+    y = product((jax.nn.silu(g) * u).astype(x.dtype), w_down)
+    out = _combine(y, mine, inverse, held).astype(jnp.float32)
+    dropped = sizes.sum() - computed.sum()
+    return (out * weight[..., None]).sum(axis=1), sizes, dropped
+
+
+class SparseFFN(nn.Module):
+    """Router over all ``num_experts``, the held experts' part of the
+    routed result, and the shared expert."""
+
+    num_experts: int
+    top_k: int
+    width: int
+    shared_width: int
+    experts_held: int
+    expert_offset: int
+    norm_topk: bool
+    routed_scale: float
+    dtype: jnp.dtype = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x: jax.Array):
+        """``x`` is the normed input in float32; returns the layer's
+        output and its routing counts."""
+        b, s_len, d = x.shape
+        t = b * s_len
+        flat = x.reshape(t, d)
+        with jax.named_scope(scopes.SEQ_ROUTER):
+            w_r = self.param("router", nn.initializers.normal(0.02),
+                             (d, self.num_experts), jnp.float32)
+            p = jax.nn.softmax(jnp.dot(
+                flat, w_r, precision=jax.lax.Precision.HIGHEST))
+            weight, chosen = jax.lax.top_k(p, self.top_k)
+            if self.norm_topk:
+                weight = weight / weight.sum(axis=-1, keepdims=True)
+            local = chosen - self.expert_offset
+            # for a comparison of choices (``chipbench``): kept only
+            # by a caller that makes ``intermediates`` mutable
+            self.sow("intermediates", "chosen", chosen)
+        xb = flat.astype(self.dtype)
+        with jax.named_scope(scopes.SEQ_EXPERTS):
+            e, f = self.experts_held, self.width
+            mats = (_weight(self, "experts_gate", (e, d, f), self.dtype),
+                    _weight(self, "experts_up", (e, d, f), self.dtype),
+                    _weight(self, "experts_down", (e, f, d), self.dtype))
+            chunk = min(EXPERT_CHUNK, t)
+            if t % chunk:
+                raise ValueError(
+                    f"{t} tokens are not whole chunks of {chunk}")
+            n = t // chunk
+            routed, sizes, dropped = jax.lax.map(
+                jax.checkpoint(lambda a: held_experts(*a, *mats)),
+                (xb.reshape(n, chunk, d),
+                 local.reshape(n, chunk, self.top_k),
+                 weight.reshape(n, chunk, self.top_k)))
+            routed = routed.reshape(t, d) * self.routed_scale
+            sizes = sizes.sum(axis=0)
+        with jax.named_scope(scopes.SEQ_SHARED):
+            shared = SwiGLU(self.shared_width, self.dtype,
+                            name="shared")(xb)
+        out = (routed + shared.astype(jnp.float32)).astype(self.dtype)
+        stats = {"moe_routed": jnp.int32(t * self.top_k),
+                 "moe_held": sizes.sum(),
+                 "moe_dropped": dropped.sum(),
+                 "moe_load_max": sizes.max()}
+        return out.reshape(b, s_len, d), stats
+
+
+class DecoderLayer(nn.Module):
+    spec: LayerSpec
+    kv_heads: int
+    head_dim: int
+    dense_width: int
+    ffn: tuple              # SparseFFN's fields, as sorted items
+    eps: float
+    dtype: jnp.dtype = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x: jax.Array):
+        spec = self.spec
+        with (jax.named_scope(scopes.SEQ_ATTN_WINDOW) if spec.window
+              else jax.named_scope(scopes.SEQ_ATTN_FULL)):
+            n = RMSNorm(self.eps, name="input_norm")(x)
+            h = x + GatedAttention(
+                spec, self.kv_heads, self.head_dim, self.dtype,
+                name="attn")(n.astype(self.dtype))
+        norm = RMSNorm(self.eps, name="post_attn_norm")
+        if spec.sparse:
+            with jax.named_scope(scopes.SEQ_ROUTER):
+                n = norm(h)
+            f, stats = SparseFFN(dtype=self.dtype, name="ffn",
+                                 **dict(self.ffn))(n)
+        else:
+            with jax.named_scope(scopes.SEQ_DENSE_FFN):
+                f = SwiGLU(self.dense_width, self.dtype, name="ffn")(
+                    norm(h).astype(self.dtype))
+            stats = None
+        return h + f, stats
+
+
+class SeqPolicyNet(nn.Module):
+    """ids ``[B, S]`` → (float32 logits ``[B, S, vocab_held]``, the
+    step's routing counts)."""
+
+    layers: tuple           # of LayerSpec
+    hidden: int
+    vocab_held: int
+    kv_heads: int
+    head_dim: int
+    dense_width: int
+    ffn: tuple              # SparseFFN's fields, as sorted items
+    eps: float = 1e-6
+    dtype: jnp.dtype = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, ids: jax.Array):
+        with jax.named_scope(scopes.SEQ_EMBED):
+            table = self.param("embed", nn.initializers.normal(0.02),
+                               (self.vocab_held, self.hidden),
+                               jnp.float32)
+            x = jnp.take(table, ids, axis=0).astype(self.dtype)
+        totals = dict.fromkeys(MOE_STATS, jnp.int32(0))
+        layer = nn.remat(DecoderLayer)
+        for i, spec in enumerate(self.layers):
+            x, stats = layer(
+                spec, self.kv_heads, self.head_dim, self.dense_width,
+                self.ffn, self.eps, self.dtype, name=f"layer{i}")(x)
+            if stats is not None:
+                for name in MOE_STATS:
+                    merge = (jnp.maximum if name == "moe_load_max"
+                             else jnp.add)
+                    totals[name] = merge(totals[name], stats[name])
+        with jax.named_scope(scopes.SEQ_HEAD):
+            n = RMSNorm(self.eps, name="norm")(x).astype(self.dtype)
+            logits = jnp.dot(
+                n, _weight(self, "head", (self.hidden, self.vocab_held),
+                           self.dtype),
+                preferred_element_type=jnp.float32)
+        return logits, totals
+
+
+def chosen_experts(kept: dict) -> dict:
+    """``{"layerN": chosen [T, top_k]}`` of every sparse layer, from
+    what ``module.apply(..., mutable=["intermediates"])`` kept."""
+    return {name: layer["ffn"]["chosen"][0]
+            for name, layer in kept["intermediates"].items()
+            if "chosen" in layer.get("ffn", {})}
+
+
+# ------------------------------------------------------------ the class
+
+def layer_specs(kw: dict) -> tuple:
+    """The held layers' ``LayerSpec``s from the published keys."""
+    hd = kw["head_dim"]
+    ropes = {}
+    for kind, r in kw["rope_parameters"].items():
+        ropes[kind] = Rope(
+            kind=r.get("rope_type", "default"),
+            theta=float(r["rope_theta"]),
+            dims=int(hd * r.get("partial_rotary_factor", 1)),
+            factor=float(r.get("factor", 1.0)),
+            original=int(r.get("original_max_position_embeddings", 0)),
+            beta_fast=float(r.get("beta_fast", 32)),
+            beta_slow=float(r.get("beta_slow", 1)),
+            attention_factor=float(r.get("attention_factor", 1.0)))
+    return tuple(
+        LayerSpec(
+            heads=int(kw["num_attention_heads_per_layer"][i]),
+            window=(int(kw["sliding_window"])
+                    if kw["layer_types"][i] == "sliding_attention"
+                    else 0),
+            rope=ropes[kw["layer_types"][i]],
+            sparse=kw["mlp_layer_types"][i] == "sparse")
+        for i in range(int(kw["layers_held"])))
+
+
+#: what this decoder computes one way only; a spec that says
+#: otherwise is refused rather than run as something else
+FIXED = {"attention_bias": False, "gating": "per-head",
+         "moe_apply_router_weight_on_input": False,
+         "moe_router_logit_softcapping": 0,
+         "tie_word_embeddings": False}
+
+
+@neuralnet
+class SeqPolicy(NeuralNetBase):
+    """The move-sequence policy as a spec-built network: the kwargs
+    are the published config's keys plus the held share
+    (``layers_held``, ``vocab_held``, ``experts_held``,
+    ``expert_offset``). It has no feature planes: its input is id
+    rows (``data/convert.py --sequence``)."""
+
+    #: ranks of a training batch's (inputs, labels) — the trainer
+    #: shards both over the data axis
+    batch_ranks = (2, 2)
+
+    def __init__(self, feature_list=(), *, board: int = 19,
+                 init_weights: bool = True, seed: int = 0, **kwargs):
+        for key, want in FIXED.items():
+            if kwargs.get(key, want) != want:
+                raise ValueError(
+                    f"SeqPolicy computes {key}={want!r} only; the "
+                    f"spec says {kwargs[key]!r}")
+        if board * board + 2 > int(kwargs["vocab_held"]):
+            raise ValueError(
+                f"a {board}x{board} record needs {board * board + 2} "
+                f"ids; the spec holds {kwargs['vocab_held']}")
+        self.feature_list = tuple(feature_list)
+        self.board = board
+        self.preprocess = None
+        self.spec_kwargs = dict(kwargs)
+        self.module = self.create_network(**kwargs)
+        self.params = None
+        if init_weights:
+            dummy = jnp.zeros((1, 1), jnp.int32)
+            self.params = jax.jit(self.module.init)(
+                jax.random.key(seed), dummy)
+        self._apply = jax.jit(self.module.apply)
+
+    @property
+    def input_planes(self) -> int:
+        return 0
+
+    @property
+    def num_outputs(self) -> int:
+        return int(self.spec_kwargs["vocab_held"])
+
+    def forward(self, ids: jax.Array) -> jax.Array:
+        """Logits ``[B, S, vocab_held]`` for id rows ``[B, S]``."""
+        return self._apply(self.params, ids)[0]
+
+    @staticmethod
+    def create_network(**kw) -> SeqPolicyNet:
+        ffn = {"num_experts": int(kw["num_experts"]),
+               "top_k": int(kw["num_experts_per_tok"]),
+               "width": int(kw["moe_intermediate_size"]),
+               "shared_width": int(
+                   kw["shared_expert_intermediate_size"]),
+               "experts_held": int(kw["experts_held"]),
+               "expert_offset": int(kw["expert_offset"]),
+               "norm_topk": bool(kw["norm_topk_prob"]),
+               "routed_scale": float(kw["moe_routed_scaling_factor"])}
+        return SeqPolicyNet(
+            layers=layer_specs(kw), hidden=int(kw["hidden_size"]),
+            vocab_held=int(kw["vocab_held"]),
+            kv_heads=int(kw["num_key_value_heads"]),
+            head_dim=int(kw["head_dim"]),
+            dense_width=int(kw["intermediate_size"]),
+            ffn=tuple(sorted(ffn.items())),
+            eps=float(kw["rms_norm_eps"]))

@@ -7,11 +7,20 @@ small CLI makes that a one-liner:
     python -m rocalphago_tpu.models.specs policy --out models/policy.json
     python -m rocalphago_tpu.models.specs value --out models/value.json
     python -m rocalphago_tpu.models.specs rollout --out models/rollout.json
+    python -m rocalphago_tpu.models.specs seq --config cfg.json --out seq.json
+
+``seq`` is the move-sequence policy (``models/seqpolicy.py``). Its
+``--config`` is a JSON object of the published decoder config's keys
+under their own names (``hidden_size``, ``layer_types``,
+``num_attention_heads_per_layer``, ``rope_parameters``,
+``num_experts``, …) plus the share of the model held here:
+``layers_held``, ``vocab_held``, ``experts_held``, ``expert_offset``.
 """
 
 from __future__ import annotations
 
 import argparse
+import json
 import sys
 
 from rocalphago_tpu.features import (
@@ -22,13 +31,17 @@ from rocalphago_tpu.features import (
 )
 from rocalphago_tpu.models.policy import CNNPolicy
 from rocalphago_tpu.models.rollout import ROLLOUT_FEATURES, CNNRollout
+from rocalphago_tpu.models.seqpolicy import SeqPolicy
 from rocalphago_tpu.models.value import CNNValue
 
 
 def main(argv=None):
     ap = argparse.ArgumentParser(
         description="Write a model JSON spec with fresh weights")
-    ap.add_argument("kind", choices=("policy", "value", "rollout"))
+    ap.add_argument("kind", choices=("policy", "value", "rollout", "seq"))
+    ap.add_argument("--config", default=None,
+                    help="seq only: JSON file of the decoder's "
+                         "published keys plus the held share")
     ap.add_argument("--out", required=True, help="spec path (.json)")
     ap.add_argument("--board", type=int, default=19)
     ap.add_argument("--layers", type=int, default=12,
@@ -63,6 +76,16 @@ def main(argv=None):
                          "handcrafted planes")
     a = ap.parse_args(argv)
 
+    if a.kind == "seq":
+        if not a.config:
+            ap.error("seq needs --config")
+        with open(a.config) as f:
+            net = SeqPolicy(board=a.board, seed=a.seed, **json.load(f))
+        net.save_model(a.out)
+        print(f"wrote {a.out} (SeqPolicy, board={a.board}, "
+              f"{net.spec_kwargs['layers_held']} layers, "
+              f"{net.num_outputs} ids)")
+        return net
     if a.kind == "policy":
         features = tuple(a.features) if a.features else default_features()
         net = CNNPolicy(features, board=a.board, layers=a.layers,

@@ -51,6 +51,20 @@ COUNT_EDGES = (1.0, 2.0, 5.0, 10.0, 20.0, 50.0, 100.0, 200.0, 350.0,
                500.0, 1000.0)
 
 
+# ---- the names of the sequence policy's routing metrics, added on
+# the host from a train step's returned counts
+# (``training/sl.py::record_routing``; docs/OBSERVABILITY.md)
+#: token–expert pairs the router made, over ALL experts
+MOE_TOKENS_ROUTED = "moe_tokens_routed_total"
+#: pairs that landed on experts held here (and were computed)
+MOE_TOKENS_HELD = "moe_tokens_held_total"
+#: held pairs left uncomputed — the expert layer has no capacity
+#: limit, so anything but 0 is a fault
+MOE_TOKENS_DROPPED = "moe_tokens_dropped_total"
+#: gauge: the busiest held expert's pairs in the latest step
+MOE_EXPERT_LOAD_MAX = "moe_expert_load_max"
+
+
 def _fmt(x) -> str:
     """Short stable float rendering for bucket keys ('0.01', '1')."""
     return format(float(x), "g")

@@ -24,6 +24,34 @@ TRAIN_LOSS = "train.loss"
 #: the optimizer: ``tx.update`` + ``apply_updates``
 TRAIN_UPDATE = "train.update"
 
+# ---- the move-sequence policy (models/seqpolicy.py). Forward under
+# ``jvp(…)``, backward under ``transpose(jvp(…))``; every layer is
+# recomputed in the backward pass (``checkpoint``/``rematted_
+# computation`` in the path), and that second forward runs under the
+# backward's wrapper
+#: the embedding lookup (backward: the scatter of its gradient)
+SEQ_EMBED = "seq.embed"
+#: a full-attention layer's norm, projections, rotary, gate,
+#: attention and output projection
+SEQ_ATTN_FULL = "seq.attn.full"
+#: the same of a sliding-window layer
+SEQ_ATTN_WINDOW = "seq.attn.window"
+#: inside either of the two: the attention kernel alone (Pallas
+#: ``splash_attention``, forward and backward kernels) where it runs
+SEQ_ATTN_KERNEL = "seq.attn.kernel"
+#: the norm before a sparse layer, the float32 router, top-k and
+#: weights
+SEQ_ROUTER = "seq.router"
+#: sorting the pairs, the dispatch, the grouped products over the held
+#: experts and the weighted combine
+SEQ_EXPERTS = "seq.experts"
+#: the shared expert
+SEQ_SHARED = "seq.shared"
+#: the dense MLP of a layer without experts, with its norm
+SEQ_DENSE_FFN = "seq.dense_ffn"
+#: the final norm and the output head
+SEQ_HEAD = "seq.head"
+
 # ---- one self-play ply (search/selfplay.py::_make_ply)
 #: the shared group analysis (``vgroup_data``)
 PLY_GROUPS = "ply.groups"

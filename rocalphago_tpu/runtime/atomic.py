@@ -19,6 +19,7 @@ power loss, not just a process kill.
 
 from __future__ import annotations
 
+import contextlib
 import json
 import os
 import tempfile
@@ -38,10 +39,12 @@ def _fsync_dir(path: str) -> None:
         os.close(fd)
 
 
-def atomic_write_bytes(path: str, data: bytes,
-                       makedirs: bool = True) -> None:
-    """Write ``data`` to ``path`` so a crash at ANY point leaves
-    either the previous complete file or the new complete file."""
+@contextlib.contextmanager
+def atomic_writer(path: str, makedirs: bool = True):
+    """A binary file object whose content becomes ``path`` when the
+    block ends — so a crash at ANY point leaves either the previous
+    complete file or the new complete file — for payloads too large
+    to build in memory first (a weights file written leaf by leaf)."""
     parent = os.path.dirname(path)
     if makedirs and parent:
         os.makedirs(parent, exist_ok=True)
@@ -50,7 +53,7 @@ def atomic_write_bytes(path: str, data: bytes,
         suffix=".tmp")
     try:
         with os.fdopen(fd, "wb") as f:
-            f.write(data)
+            yield f
             f.flush()
             os.fsync(f.fileno())
         os.replace(tmp, path)
@@ -64,6 +67,14 @@ def atomic_write_bytes(path: str, data: bytes,
             pass
         raise
     _fsync_dir(parent)
+
+
+def atomic_write_bytes(path: str, data: bytes,
+                       makedirs: bool = True) -> None:
+    """Write ``data`` to ``path`` so a crash at ANY point leaves
+    either the previous complete file or the new complete file."""
+    with atomic_writer(path, makedirs) as f:
+        f.write(data)
 
 
 def atomic_write_text(path: str, text: str,

@@ -21,7 +21,6 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
-import functools
 import os
 import sys
 import time
@@ -91,8 +90,23 @@ def make_optimizer(cfg: SLConfig) -> optax.GradientTransformation:
     return optax.sgd(sched, momentum=cfg.momentum or None)
 
 
+def _per_row(weights, like):
+    """One weight per row, broadcast over ``like``'s other axes."""
+    return weights.reshape(weights.shape + (1,) * (like.ndim - 1))
+
+
 def policy_loss_fn(apply_fn, params, planes, actions, weights=None):
-    logits = apply_fn(params, planes)
+    """(loss, accuracy) of ``[B, N]`` logits against ``[B]`` moves —
+    or of a sequence policy's ``[B, S, V]`` against ``[B, S]`` next
+    ids: the mean is over every position either way."""
+    return _policy_loss(apply_fn, params, planes, actions, weights)[:2]
+
+
+def _policy_loss(apply_fn, params, planes, actions, weights=None):
+    """``policy_loss_fn`` plus whatever the network returns beside its
+    logits (a sequence policy's routing counts; else ``{}``)."""
+    out = apply_fn(params, planes)
+    logits, extras = out if isinstance(out, tuple) else (out, {})
     with jax.named_scope(scopes.TRAIN_LOSS):
         # pass actions (== N, present when a corpus was converted with
         # include_passes) are outside the policy's board-point output
@@ -100,48 +114,73 @@ def policy_loss_fn(apply_fn, params, planes, actions, weights=None):
         # clamp them onto the last board point
         valid = (actions < logits.shape[-1]).astype(jnp.float32)
         if weights is not None:
-            valid = valid * weights
+            valid = valid * _per_row(weights, valid)
         denom = jnp.maximum(valid.sum(), 1.0)
         xent = optax.softmax_cross_entropy_with_integer_labels(
             logits, jnp.minimum(actions, logits.shape[-1] - 1))
         loss = (xent * valid).sum() / denom
         acc = (((logits.argmax(axis=-1) == actions) * valid).sum()
                / denom)
-    return loss, acc
+    return loss, acc, extras
 
 
 def make_train_step(apply_fn, tx, size: int, symmetries: bool):
-    """Pure (state, planes, actions) → (state, metrics) step fn."""
+    """Pure (state, planes, actions) → (state, metrics) step fn.
+    ``planes`` are feature planes ``[B, s, s, F]`` with moves ``[B]``,
+    or a sequence policy's id rows ``[B, S]`` with next ids
+    ``[B, S]``."""
+
+    def loss_fn(params, planes, actions):
+        loss, acc, extras = _policy_loss(apply_fn, params, planes,
+                                         actions)
+        return loss, (acc, extras)
 
     def train_step(state: SLState, planes, actions):
         key = unpack_rng(state.rng)
         key, sub = jax.random.split(key)
         with jax.named_scope(scopes.TRAIN_AUGMENT):
-            planes = planes.astype(jnp.float32)
+            if planes.ndim == 4:        # id rows stay integers
+                planes = planes.astype(jnp.float32)
             if symmetries:
                 planes, actions = random_transform_batch(
                     sub, planes, actions, size)
-        (loss, acc), grads = jax.value_and_grad(
-            functools.partial(policy_loss_fn, apply_fn), has_aux=True)(
-                state.params, planes, actions)
+        (loss, (acc, extras)), grads = jax.value_and_grad(
+            loss_fn, has_aux=True)(state.params, planes, actions)
         with jax.named_scope(scopes.TRAIN_UPDATE):
             updates, opt_state = tx.update(
                 grads, state.opt_state, state.params)
             params = optax.apply_updates(state.params, updates)
         new = SLState(params, opt_state, state.step + 1, pack_rng(key))
-        return new, {"loss": loss, "accuracy": acc}
+        return new, {"loss": loss, "accuracy": acc, **extras}
 
     return train_step
 
 
+def record_routing(metrics: list) -> None:
+    """Add the routing counts of some steps' returned metrics (host
+    values; a sequence policy's — a step without them adds nothing)
+    to the process's counters, once per block of steps."""
+    if not metrics or "moe_routed" not in metrics[0]:
+        return
+    for key, name in (("moe_routed", obs_registry.MOE_TOKENS_ROUTED),
+                      ("moe_held", obs_registry.MOE_TOKENS_HELD),
+                      ("moe_dropped", obs_registry.MOE_TOKENS_DROPPED)):
+        obs_registry.counter(name).inc(
+            sum(int(m[key]) for m in metrics))
+    obs_registry.gauge(obs_registry.MOE_EXPERT_LOAD_MAX).set(
+        int(metrics[-1]["moe_load_max"]))
+
+
 def make_eval_step(apply_fn, num_points: int):
     def eval_step(params, planes, actions, weights):
-        loss, acc = policy_loss_fn(
-            apply_fn, params, planes.astype(jnp.float32), actions,
-            weights)
+        if planes.ndim == 4:            # id rows stay integers
+            planes = planes.astype(jnp.float32)
+        loss, acc = policy_loss_fn(apply_fn, params, planes, actions,
+                                   weights)
         # effective sample count = the loss denominator (real rows
         # whose action is a board point)
-        count = ((actions < num_points) * weights).sum()
+        count = ((actions < num_points)
+                 * _per_row(weights, actions)).sum()
         return {"loss": loss, "accuracy": acc, "count": count}
     return eval_step
 
@@ -175,10 +214,11 @@ class SLTrainer:
         self.net = net or NeuralNetBase.load_model(cfg.model_json)
         self.mesh = meshlib.make_mesh(cfg.num_devices)
         self.dataset = ShardedDataset(cfg.train_data)
-        if self.dataset.planes != self.net.preprocess.output_dim:
+        if self.dataset.planes != self.net.input_planes:
             raise ValueError(
-                f"dataset has {self.dataset.planes} planes but the model's "
-                f"feature list needs {self.net.preprocess.output_dim}")
+                f"dataset has {self.dataset.planes} planes but the model "
+                f"needs {self.net.input_planes} (0: a sequence policy's "
+                "id rows, data/convert.py --sequence)")
         os.makedirs(cfg.out_dir, exist_ok=True)
 
         dwidth = self.mesh.shape[meshlib.DATA_AXIS]
@@ -190,8 +230,10 @@ class SLTrainer:
         tx = make_optimizer(cfg)
         size = self.net.board
         opt_state0 = tx.init(self.net.params)
-        batch_sh = meshlib.data_sharding(self.mesh, rank=4)
-        act_sh = meshlib.data_sharding(self.mesh, rank=1)
+        in_rank, target_rank = self.net.batch_ranks
+        batch_sh = meshlib.data_sharding(self.mesh, rank=in_rank)
+        act_sh = meshlib.data_sharding(self.mesh, rank=target_rank)
+        weight_sh = meshlib.data_sharding(self.mesh, rank=1)
         rep = meshlib.replicated(self.mesh)
         state_sh = SLState(
             params=jax.tree.map(lambda _: rep, self.net.params),
@@ -206,8 +248,8 @@ class SLTrainer:
             out_shardings=(state_sh, rep),
             donate_argnums=(0,)))
         self._eval_step = jaxobs.track("sl.eval_step", jax.jit(
-            make_eval_step(self.net.module.apply, size * size),
-            in_shardings=(state_sh.params, batch_sh, act_sh, act_sh),
+            make_eval_step(self.net.module.apply, self.net.num_outputs),
+            in_shardings=(state_sh.params, batch_sh, act_sh, weight_sh),
             out_shardings=rep))
 
         self.tx = tx
@@ -289,7 +331,7 @@ class SLTrainer:
             it = (meshlib.shard_batch(self.mesh, b)
                   for b in it)
             t0 = time.time()
-            losses, accs = [], []
+            losses, accs, routing = [], [], []
             with trace.span("sl.train"):
               for i, (planes, actions) in enumerate(obs_registry.timed(
                       device_prefetch(it, size=2), data_wait,
@@ -303,6 +345,8 @@ class SLTrainer:
                         self.state, planes, actions)
                 losses.append(m["loss"])
                 accs.append(m["accuracy"])
+                if "moe_routed" in m:       # a sequence policy's counts
+                    routing.append(m)
                 if cfg.save_every:
                     gstep = epoch * steps_per_epoch + skip + len(losses)
                     if gstep % cfg.save_every == 0:
@@ -313,6 +357,7 @@ class SLTrainer:
                     f"train split ({len(self.train_idx)} positions) "
                     f"yields no full minibatch of {cfg.minibatch}; "
                     "convert more games or shrink the minibatch")
+            record_routing(jax.device_get(routing))
             train_loss = float(jnp.mean(jnp.stack(losses)))
             train_acc = float(jnp.mean(jnp.stack(accs)))
             dt = time.time() - t0

@@ -76,8 +76,15 @@ def inverse_transform_planes(x: jax.Array, t: jax.Array) -> jax.Array:
 def random_transform_batch(rng: jax.Array, planes: jax.Array,
                            actions: jax.Array, size: int):
     """Random per-sample symmetry for a training batch
-    (``planes [B,s,s,F]``, ``actions [B]``)."""
+    (``planes [B,s,s,F]``, ``actions [B]``) — or, for a sequence
+    policy, one transform per row of move ids (``planes [B,S]``,
+    ``actions [B,S]``): every board point of a packed record moves
+    with its board, and pass, separators and any id past them stay
+    (``transform_action`` leaves ids ≥ ``size²`` alone)."""
     t = jax.random.randint(rng, (planes.shape[0],), 0, 8)
+    if planes.ndim == 2:
+        return (transform_action(planes, t[:, None], size),
+                transform_action(actions, t[:, None], size))
     planes = jax.vmap(transform_planes)(planes, t)
     actions = transform_action(actions, t, size)
     return planes, actions

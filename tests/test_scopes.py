@@ -98,6 +98,38 @@ def value_ops():
 
 
 @pytest.fixture(scope="module")
+def seq_ops():
+    """The COMPILED supervised train step of the move-sequence policy
+    at a toy size (one full and one sliding layer, a dense and a
+    sparse MLP)."""
+    from rocalphago_tpu.io.checkpoint import pack_rng
+    from rocalphago_tpu.models.seqpolicy import SeqPolicy
+    from rocalphago_tpu.training import sl
+
+    rope = {"rope_theta": 10000, "partial_rotary_factor": 1}
+    net = SeqPolicy(
+        board=SIZE, vocab_size=32, vocab_held=32, hidden_size=8,
+        intermediate_size=8, num_hidden_layers=2, layers_held=2,
+        num_attention_heads_per_layer=[2, 4], num_key_value_heads=2,
+        head_dim=4, sliding_window=4,
+        layer_types=["full_attention", "sliding_attention"],
+        mlp_layer_types=["dense", "sparse"],
+        rope_parameters={"full_attention": rope,
+                         "sliding_attention": rope},
+        num_experts=4, num_experts_per_tok=2, moe_intermediate_size=4,
+        shared_expert_intermediate_size=4, norm_topk_prob=True,
+        moe_routed_scaling_factor=2.5, experts_held=2, expert_offset=0,
+        rms_norm_eps=1e-6)
+    tx = sl.make_optimizer(sl.SLConfig())
+    step = sl.make_train_step(net.module.apply, tx, SIZE, True)
+    state = sl.SLState(net.params, tx.init(net.params), jnp.int32(0),
+                       pack_rng(jax.random.key(0)))
+    ids = jnp.zeros((2, 8), jnp.int32)
+    return op_names(jax.jit(step).lower(
+        state, ids, ids).compile().as_text())
+
+
+@pytest.fixture(scope="module")
 def ply_ops():
     from rocalphago_tpu.search.selfplay import _make_ply
 
@@ -150,17 +182,36 @@ ENCODE = [scopes.ENCODE_CANDIDATES, scopes.ENCODE_LADDER,
 EVAL = [scopes.EVAL_GROUPS, scopes.EVAL_ENCODE, scopes.EVAL_POLICY,
         scopes.EVAL_VALUE]
 MCTS = [scopes.MCTS_SELECT, scopes.MCTS_EXPAND, scopes.MCTS_BACKUP]
+SEQ = [scopes.SEQ_EMBED, scopes.SEQ_ATTN_FULL, scopes.SEQ_ATTN_WINDOW,
+       scopes.SEQ_ROUTER, scopes.SEQ_EXPERTS, scopes.SEQ_SHARED,
+       scopes.SEQ_DENSE_FFN, scopes.SEQ_HEAD]
 
 
 def test_every_constant_has_a_case():
-    assert sorted(TRAIN + PLY + ENCODE + EVAL + MCTS) == sorted(
-        scopes.ALL)
+    # the attention kernel's scope exists only in a program traced
+    # for a TPU: tests/test_seqpolicy.py lowers it there
+    assert sorted(TRAIN + PLY + ENCODE + EVAL + MCTS + SEQ
+                  + [scopes.SEQ_ATTN_KERNEL]) == sorted(scopes.ALL)
     assert len(set(scopes.ALL)) == len(scopes.ALL)
 
 
 @pytest.mark.parametrize("name", TRAIN)
 def test_train_step_scope_survives_the_compile(train_ops, name):
     assert has(train_ops, name), sorted(set(train_ops))[:40]
+
+
+@pytest.mark.parametrize("name", TRAIN + SEQ)
+def test_sequence_step_scope_survives_the_compile(seq_ops, name):
+    assert has(seq_ops, name), sorted(set(seq_ops))[:40]
+
+
+@pytest.mark.parametrize("name", SEQ)
+def test_sequence_scopes_name_the_backward_pass_too(seq_ops, name):
+    """Forward under ``jvp(SeqPolicyNet)``, backward — and each
+    layer's recomputed forward — under ``transpose(jvp(…))``."""
+    mine = [op for op in seq_ops if name in op.split("/")]
+    assert any("transpose(jvp(SeqPolicyNet))" in op for op in mine)
+    assert any("transpose(" not in op for op in mine)
 
 
 def test_train_loss_is_scoped_forward_and_backward(train_ops):
