@@ -54,9 +54,15 @@ router, softmax and loss):
   ragged split. The pairs sent here are sorted to the front of a
   buffer that holds EVERY pair a chunk of tokens could send
   (``chunk × top_k`` rows), so no pair is dropped however unbalanced
-  the routing; rows past the held pairs are skipped by the product.
-  What the buffer left out is counted from the buffer (``moe_dropped``),
-  not assumed;
+  the routing; rows past the held pairs are skipped by the product,
+  and every other pass over the buffer's rows — the dispatch gather,
+  SwiGLU's inside between the products, and their mirrors in the
+  backward pass — runs in row blocks and only on the blocks that
+  hold a pair (:func:`_held_blocks`): the work follows what arrives,
+  three blocks of forty on balanced traffic and all forty when every
+  pair lands here, one body run more or fewer times and no second
+  path. What the buffer left out is counted from the buffer
+  (``moe_dropped``), not assumed, and so are the blocks that ran;
 * every layer is recomputed in the backward pass (``nn.remat``): what
   a step saves is one ``[B, S, hidden]`` input per layer.
 """
@@ -78,7 +84,8 @@ from rocalphago_tpu.obs import scopes
 #: masked scores: far below any real one, and finite
 NEG = -1e30
 #: the parts of a step's returned metrics that count routing
-MOE_STATS = ("moe_routed", "moe_held", "moe_dropped", "moe_load_max")
+MOE_STATS = ("moe_routed", "moe_held", "moe_dropped", "moe_load_max",
+             "moe_row_blocks_run", "moe_row_blocks")
 
 
 class Rope(NamedTuple):
@@ -307,10 +314,14 @@ class RMSNorm(nn.Module):
         return xf * jax.lax.rsqrt(var + self.eps) * scale
 
 
+def _gated(g, u):
+    """The inside of SwiGLU, in float32."""
+    return (jax.nn.silu(g.astype(jnp.float32))
+            * u.astype(jnp.float32)).astype(g.dtype)
+
+
 def _swiglu(x, w_gate, w_up, w_down):
-    g = jnp.dot(x, w_gate).astype(jnp.float32)
-    u = jnp.dot(x, w_up).astype(jnp.float32)
-    return jnp.dot((jax.nn.silu(g) * u).astype(x.dtype), w_down)
+    return jnp.dot(_gated(jnp.dot(x, w_gate), jnp.dot(x, w_up)), w_down)
 
 
 class SwiGLU(nn.Module):
@@ -356,22 +367,56 @@ class GatedAttention(nn.Module):
                        _weight(self, "o_proj", (h * hd, d), self.dtype))
 
 
-@jax.custom_vjp
-def _dispatch(x, order, inverse, held):
-    """Rows of ``x [T, D]`` in pair order: row ``i`` is the token of
-    sorted pair ``order[i]`` (``order`` may be a leading part of the
-    sort: the buffer's rows)."""
-    return x[order // held.shape[1]]
+#: tokens the expert layer takes at a time: its buffers are this many
+#: times ``top_k`` rows long (fewer tokens are one chunk)
+EXPERT_CHUNK = 4096
+#: rows of the expert buffer that its dense passes take at a time
+#: (a buffer this does not divide, or a shorter one, is one block).
+#: Read off the v5e: 512 and 1,024 alike, 2,048 and 4,096 a little
+#: slower on 2,540 pairs a chunk (PERF.md, PR 27)
+EXPERT_ROW_BLOCK = 1024
 
 
-def _dispatch_fwd(x, order, inverse, held):
-    return _dispatch(x, order, inverse, held), (inverse, held)
+def _row_block(rows: int) -> int:
+    """Height of the row blocks of a buffer of ``rows`` rows."""
+    return rows if rows % EXPERT_ROW_BLOCK else EXPERT_ROW_BLOCK
+
+
+def _held_blocks(n_held, bufs: tuple, block_fn):
+    """``bufs`` (zeros ``[R, …]`` each) with the row blocks that hold
+    one of the leading ``n_held`` rows — the rows with a pair —
+    replaced by ``block_fn(at)``, where ``at(a)`` is the block's rows
+    of an ``a [R, …]``. Its rows past ``n_held`` stay zero whatever
+    ``block_fn`` gives them (a select: a product's leavings do not
+    spread).
+
+    The loop is as long as the blocks that hold a pair, so a block
+    past them costs its share of the zero fill and nothing else. It
+    is never differentiated: every caller is a ``custom_vjp`` rule.
+    What the body reads should be there already: XLA sinks a
+    producer that only the loop uses into its body, once a turn, and
+    copies a product's output before a loop may write to it (PERF.md,
+    PR 27)."""
+    block = _row_block(bufs[0].shape[0])
+
+    def body(i, bufs):
+        start = i * block
+        live = (start + jnp.arange(block) < n_held)[:, None]
+        new = block_fn(lambda a: jax.lax.dynamic_slice_in_dim(
+            a, start, block, 0))
+        return tuple(
+            jax.lax.dynamic_update_slice_in_dim(
+                buf, jnp.where(live, n, 0).astype(buf.dtype), start, 0)
+            for buf, n in zip(bufs, new))
+
+    return jax.lax.fori_loop(0, -(-n_held // block), body, bufs)
 
 
 def _from_rows(rows, inverse, held):
     """``[T, K, D]``: each pair's row of the buffer ``rows [R, D]``,
     zero for a pair that was not sent here or whose place in the sort
-    lies past the buffer."""
+    lies past the buffer. Rows that hold no pair are never read: a
+    product may leave anything there."""
     t, k = held.shape
     r = rows.shape[0]
     inside = held & (inverse.reshape(t, k) < r)
@@ -379,39 +424,92 @@ def _from_rows(rows, inverse, held):
     return jnp.where(inside[..., None], rows[at].reshape(t, k, -1), 0)
 
 
+@jax.custom_vjp
+def _dispatch(x, order, inverse, held, n_held):
+    """Rows of ``x [T, D]`` in pair order, once for each of the two
+    products that read them (one buffer): row ``i`` is the token of
+    sorted pair ``order[i]`` (``order`` may be a leading part of the
+    sort: the buffer's rows), zero past the ``n_held`` rows that hold
+    a pair."""
+    k = held.shape[1]
+    xs, = _held_blocks(
+        n_held, (jnp.zeros((order.shape[0], x.shape[1]), x.dtype),),
+        lambda at: (x[at(order) // k],))
+    return xs, xs
+
+
+def _dispatch_fwd(x, order, inverse, held, n_held):
+    return (_dispatch(x, order, inverse, held, n_held),
+            (inverse, held, n_held))
+
+
 def _dispatch_bwd(res, g):
+    inverse, held, n_held = res
+    # the two products' cotangents, summed where a pair is:
+    # ``_from_rows`` reads no other row
+    both, = _held_blocks(n_held, (jnp.zeros_like(g[0]),),
+                         lambda at: (at(g[0]) + at(g[1]),))
     # a gather by the inverse order instead of a scatter-add
-    inverse, held = res
-    back = _from_rows(g, inverse, held).astype(jnp.float32)
-    return back.sum(axis=1).astype(g.dtype), None, None, None
+    back = _from_rows(both, inverse, held).astype(jnp.float32)
+    return back.sum(axis=1).astype(both.dtype), None, None, None, None
 
 
 _dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
 @jax.custom_vjp
-def _combine(y, order, inverse, held):
-    """The pairs' results ``y [R, D]`` back in token order
-    ``[T, K, D]``, zero where a pair was not sent here."""
-    return _from_rows(y, inverse, held)
+def _act(g, u, n_held):
+    """``silu(g) * u`` on the ``n_held`` rows of the buffer that hold
+    a pair, zero on the others whatever the products left there;
+    ``g, u [R, F]``."""
+    return _held_blocks(n_held, (jnp.zeros_like(g),),
+                        lambda at: (_gated(at(g), at(u)),))[0]
 
 
-def _combine_fwd(y, order, inverse, held):
-    return _combine(y, order, inverse, held), (order, held)
+def _act_fwd(g, u, n_held):
+    return _act(g, u, n_held), (g, u, n_held)
 
 
-def _combine_bwd(res, g):
-    order, held = res
-    g = jnp.where(held[..., None], g, 0)
-    return g.reshape(-1, g.shape[-1])[order], None, None, None
+def _act_bwd(res, dh):
+    g, u, n_held = res
+    return *_held_blocks(
+        n_held, (jnp.zeros_like(g), jnp.zeros_like(u)),
+        lambda at: jax.vjp(_gated, at(g), at(u))[1](at(dh))), None
+
+
+_act.defvjp(_act_fwd, _act_bwd)
+
+
+@jax.custom_vjp
+def _combine(y, weight, order, inverse, held, n_held):
+    """The pairs' results ``y [R, D]`` back in token order and summed
+    by their float32 ``weight [T, K]``: ``[T, D]`` float32; a pair
+    that was not sent here adds nothing."""
+    out = _from_rows(y, inverse, held).astype(jnp.float32)
+    return (out * weight[..., None]).sum(axis=1)
+
+
+def _combine_fwd(y, weight, order, inverse, held, n_held):
+    return (_combine(y, weight, order, inverse, held, n_held),
+            (y, weight, order, inverse, held, n_held))
+
+
+def _combine_bwd(res, d):
+    y, weight, order, inverse, held, n_held = res
+    k, flat = held.shape[1], weight.reshape(-1)
+    # a row's cotangent is its token's, weighted: taken from ``d
+    # [T, D]`` block by block, never laid out ``[T, K, D]``
+    def block_bwd(at):
+        pairs = at(order)
+        return (d[pairs // k] * flat[pairs][:, None],)
+
+    dy, = _held_blocks(n_held, (jnp.zeros_like(y),), block_bwd)
+    out = _from_rows(y, inverse, held).astype(jnp.float32)
+    return (dy, (d[:, None, :] * out).sum(axis=-1),
+            None, None, None, None)
 
 
 _combine.defvjp(_combine_fwd, _combine_bwd)
-
-
-#: tokens the expert layer takes at a time: its buffers are this many
-#: times ``top_k`` rows long (fewer tokens are one chunk)
-EXPERT_CHUNK = 4096
 
 
 def held_experts(x, local, weight, w_gate, w_up, w_down,
@@ -422,43 +520,59 @@ def held_experts(x, local, weight, w_gate, w_up, w_down,
     among the ``E`` held ones (anything outside ``0..E-1``: not held
     here); ``weight [T, K]`` float32; the held experts' matrices
     ``[E, D, F]``, ``[E, D, F]``, ``[E, F, D]``. Returns the weighted
-    sum ``[T, D]`` float32, the pairs per held expert ``[E]`` and the
-    pairs sent here that the buffer left out.
+    sum ``[T, D]`` float32, the pairs per held expert ``[E]``, the
+    pairs sent here that the buffer left out, and the buffer's row
+    blocks ``[2]``: those that held a pair, and all.
 
     The pairs sent here are sorted to the front of a buffer of
     ``rows`` rows — all ``T·K`` unless a caller says otherwise, so
     that every pair fits however the router sends them. The count of
     pairs left out is what arrived less what the products were given:
-    a buffer cut below what arrives shows in it."""
+    a buffer cut below what arrives shows in it.
+
+    Each grouped product is one ``ragged_dot`` over the whole buffer
+    and skips the rows past its groups. Everything else that touches
+    the buffer's rows runs in blocks of :data:`EXPERT_ROW_BLOCK` and
+    only on the blocks that hold a pair (:func:`_held_blocks`), so
+    the work follows the pairs that arrived — all of the blocks when
+    every pair lands here, three of forty on balanced traffic — and
+    not the buffer's length."""
     t, k = local.shape
     rows = t * k if rows is None else rows
     e = w_gate.shape[0]
-    held = (local >= 0) & (local < e)
-    key = jnp.where(held, local, e).reshape(-1)
-    order = jnp.argsort(key, stable=True).astype(jnp.int32)
-    inverse = jnp.zeros_like(order).at[order].set(
-        jnp.arange(t * k, dtype=jnp.int32), unique_indices=True)
-    sizes = (key[:, None] == jnp.arange(e)[None, :]).sum(
-        axis=0, dtype=jnp.int32)
-    mine = order[:rows]
-    # each expert's pairs that lie inside the buffer
-    ends = jnp.minimum(jnp.cumsum(sizes), rows)
-    computed = jnp.diff(ends, prepend=0)
-    row_held = (jnp.arange(rows) < ends[-1])[:, None]
-    xs = _dispatch(x, mine, inverse, held)
+    with jax.named_scope(scopes.SEQ_EXPERTS_SORT):
+        held = (local >= 0) & (local < e)
+        key = jnp.where(held, local, e).reshape(-1)
+        order = jnp.argsort(key, stable=True).astype(jnp.int32)
+        inverse = jnp.zeros_like(order).at[order].set(
+            jnp.arange(t * k, dtype=jnp.int32), unique_indices=True)
+        sizes = (key[:, None] == jnp.arange(e)[None, :]).sum(
+            axis=0, dtype=jnp.int32)
+        mine = order[:rows]
+        # each expert's pairs that lie inside the buffer
+        ends = jnp.minimum(jnp.cumsum(sizes), rows)
+        computed = jnp.diff(ends, prepend=0)
+        n_held = ends[-1]
+        block = _row_block(rows)
+        blocks = jnp.stack([-(-n_held // block),
+                            jnp.int32(rows // block)])
 
     def product(a, w):
         # rows past the held pairs belong to no group: the product
         # skips them, and what it leaves there is never read
-        return jnp.where(row_held,
-                         jax.lax.ragged_dot(a, w, computed), 0)
+        return jax.lax.ragged_dot(a, w, computed)
 
-    g = product(xs, w_gate).astype(jnp.float32)
-    u = product(xs, w_up).astype(jnp.float32)
-    y = product((jax.nn.silu(g) * u).astype(x.dtype), w_down)
-    out = _combine(y, mine, inverse, held).astype(jnp.float32)
+    # a rule's backward pass runs under the scope of its call
+    with jax.named_scope(scopes.SEQ_EXPERTS_DISPATCH):
+        xs_gate, xs_up = _dispatch(x, mine, inverse, held, n_held)
+    g, u = product(xs_gate, w_gate), product(xs_up, w_up)
+    with jax.named_scope(scopes.SEQ_EXPERTS_ACT):
+        h = _act(g, u, n_held)
+    y = product(h, w_down)
+    with jax.named_scope(scopes.SEQ_EXPERTS_COMBINE):
+        out = _combine(y, weight, mine, inverse, held, n_held)
     dropped = sizes.sum() - computed.sum()
-    return (out * weight[..., None]).sum(axis=1), sizes, dropped
+    return out, sizes, dropped, blocks
 
 
 class SparseFFN(nn.Module):
@@ -505,13 +619,14 @@ class SparseFFN(nn.Module):
                 raise ValueError(
                     f"{t} tokens are not whole chunks of {chunk}")
             n = t // chunk
-            routed, sizes, dropped = jax.lax.map(
+            routed, sizes, dropped, blocks = jax.lax.map(
                 jax.checkpoint(lambda a: held_experts(*a, *mats)),
                 (xb.reshape(n, chunk, d),
                  local.reshape(n, chunk, self.top_k),
                  weight.reshape(n, chunk, self.top_k)))
             routed = routed.reshape(t, d) * self.routed_scale
             sizes = sizes.sum(axis=0)
+            blocks = blocks.sum(axis=0)
         with jax.named_scope(scopes.SEQ_SHARED):
             shared = SwiGLU(self.shared_width, self.dtype,
                             name="shared")(xb)
@@ -519,7 +634,9 @@ class SparseFFN(nn.Module):
         stats = {"moe_routed": jnp.int32(t * self.top_k),
                  "moe_held": sizes.sum(),
                  "moe_dropped": dropped.sum(),
-                 "moe_load_max": sizes.max()}
+                 "moe_load_max": sizes.max(),
+                 "moe_row_blocks_run": blocks[0],
+                 "moe_row_blocks": blocks[1]}
         return out.reshape(b, s_len, d), stats
 
 

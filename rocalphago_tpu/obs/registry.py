@@ -61,6 +61,11 @@ MOE_TOKENS_HELD = "moe_tokens_held_total"
 #: held pairs left uncomputed — the expert layer has no capacity
 #: limit, so anything but 0 is a fault
 MOE_TOKENS_DROPPED = "moe_tokens_dropped_total"
+#: row blocks of the expert buffers that held a pair and were
+#: computed, and all row blocks (forward pass, per chunk and layer):
+#: their ratio is the share of the buffer the dense passes touch
+MOE_ROW_BLOCKS_RUN = "moe_row_blocks_run_total"
+MOE_ROW_BLOCKS = "moe_row_blocks_total"
 #: gauge: the busiest held expert's pairs in the latest step
 MOE_EXPERT_LOAD_MAX = "moe_expert_load_max"
 

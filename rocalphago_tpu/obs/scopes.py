@@ -42,9 +42,22 @@ SEQ_ATTN_KERNEL = "seq.attn.kernel"
 #: the norm before a sparse layer, the float32 router, top-k and
 #: weights
 SEQ_ROUTER = "seq.router"
-#: sorting the pairs, the dispatch, the grouped products over the held
-#: experts and the weighted combine
+#: the expert layer: the four parts below, the held experts'
+#: matrices cast to the compute type, and the grouped products
+#: (which XLA renames: ``chipbench/seq_readers.py``)
 SEQ_EXPERTS = "seq.experts"
+#: inside it (forward, recomputed forward and the backward rules
+#: alike): sorting the pairs by held expert, their counts and offsets
+SEQ_EXPERTS_SORT = "seq.experts.sort"
+#: tokens' rows into the buffer, in row blocks that hold a pair;
+#: backward: the sum of the two products' cotangents on those blocks,
+#: then the token-major gather back and its sum over ``top_k``
+SEQ_EXPERTS_DISPATCH = "seq.experts.dispatch"
+#: between the products, in the same blocks: float32 SwiGLU inside
+SEQ_EXPERTS_ACT = "seq.experts.act"
+#: the token-major gather of the pairs' results and their weighted
+#: sum; backward: the cotangent's rows into the buffer, in blocks
+SEQ_EXPERTS_COMBINE = "seq.experts.combine"
 #: the shared expert
 SEQ_SHARED = "seq.shared"
 #: the dense MLP of a layer without experts, with its norm

@@ -164,7 +164,10 @@ def record_routing(metrics: list) -> None:
         return
     for key, name in (("moe_routed", obs_registry.MOE_TOKENS_ROUTED),
                       ("moe_held", obs_registry.MOE_TOKENS_HELD),
-                      ("moe_dropped", obs_registry.MOE_TOKENS_DROPPED)):
+                      ("moe_dropped", obs_registry.MOE_TOKENS_DROPPED),
+                      ("moe_row_blocks_run",
+                       obs_registry.MOE_ROW_BLOCKS_RUN),
+                      ("moe_row_blocks", obs_registry.MOE_ROW_BLOCKS)):
         obs_registry.counter(name).inc(
             sum(int(m[key]) for m in metrics))
     obs_registry.gauge(obs_registry.MOE_EXPERT_LOAD_MAX).set(

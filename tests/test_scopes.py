@@ -184,7 +184,9 @@ EVAL = [scopes.EVAL_GROUPS, scopes.EVAL_ENCODE, scopes.EVAL_POLICY,
 MCTS = [scopes.MCTS_SELECT, scopes.MCTS_EXPAND, scopes.MCTS_BACKUP]
 SEQ = [scopes.SEQ_EMBED, scopes.SEQ_ATTN_FULL, scopes.SEQ_ATTN_WINDOW,
        scopes.SEQ_ROUTER, scopes.SEQ_EXPERTS, scopes.SEQ_SHARED,
-       scopes.SEQ_DENSE_FFN, scopes.SEQ_HEAD]
+       scopes.SEQ_DENSE_FFN, scopes.SEQ_HEAD,
+       scopes.SEQ_EXPERTS_SORT, scopes.SEQ_EXPERTS_DISPATCH,
+       scopes.SEQ_EXPERTS_ACT, scopes.SEQ_EXPERTS_COMBINE]
 
 
 def test_every_constant_has_a_case():

@@ -189,6 +189,35 @@ def whole_layer():
     return module.init(jax.random.key(6), x), x
 
 
+def middle_share(params) -> dict:
+    """The layer's parameters with experts 4..7 of the 16 alone."""
+    p = params["params"]
+    return dict(p, **{n: p[n][4:8] for n in
+                      ("experts_gate", "experts_up", "experts_down")})
+
+
+MIDDLE = dict(TOY, experts_held=4, expert_offset=4)
+
+
+def share_and_grads(mine, x):
+    """(value, routing counts), gradients — of a probe's dot with
+    what ``SparseFFN`` holding experts 4..7 returns — and the same of
+    the reference."""
+    probe = jax.random.normal(jax.random.key(11), x.shape)
+
+    def program(mine, x):
+        out, stats = ffn_module(4, 4).apply({"params": mine}, x)
+        return (out * probe).sum(), stats
+
+    def plain(mine, x):
+        out = jnp.stack([reference.sparse_ffn(mine, row, MIDDLE)
+                         for row in x])
+        return (out * probe).sum()
+
+    return (jax.value_and_grad(program, (0, 1), has_aux=True)(mine, x),
+            jax.value_and_grad(plain, (0, 1))(mine, x))
+
+
 def test_the_shares_add_up_to_the_uncut_layer(whole_layer):
     """Four chips' routed parts plus the shared expert, which every
     chip computes alike, counted once = the uncut reference."""
@@ -221,35 +250,42 @@ def buffers(request, monkeypatch):
     return request.param
 
 
+#: heights of the buffers' row blocks, against buffers of 48 and 192
+#: rows of which a quarter hold a pair (30 of 48 in ``one_chunk``):
+#: the module's own (one block), 4 (most blocks hold nothing; the
+#: last that runs is cut mid-way), 16 (48 rows: three blocks, of
+#: which ``one_chunk`` runs two), 48 (one block of 48, four of 192)
+#: and 5, which divides neither buffer (one block; six whole blocks
+#: of ``one_chunk``'s 30-row buffer)
+ROW_BLOCKS = [None, 4, 16, 48, 5]
+
+
+@pytest.fixture(params=ROW_BLOCKS,
+                ids=[f"blocks of {b or 'all'}" for b in ROW_BLOCKS])
+def row_block(request, monkeypatch):
+    if request.param:
+        monkeypatch.setattr(seqpolicy, "EXPERT_ROW_BLOCK",
+                            request.param)
+    return request.param
+
+
 def test_the_held_share_and_its_gradients_with_either_buffer(
-        whole_layer, buffers):
+        whole_layer, buffers, row_block):
     params, x = whole_layer
-    p = params["params"]
-    mine = dict(p, **{n: p[n][4:8] for n in
-                      ("experts_gate", "experts_up", "experts_down")})
-    kw = dict(TOY, experts_held=4, expert_offset=4)
-    probe = jax.random.normal(jax.random.key(11), x.shape)
-
-    def program(mine, x):
-        out, stats = ffn_module(4, 4).apply({"params": mine}, x)
-        return (out * probe).sum(), stats
-
-    def plain(mine, x):
-        out = jnp.stack([reference.sparse_ffn(mine, row, kw)
-                         for row in x])
-        return (out * probe).sum()
-
-    (got, stats), g = jax.value_and_grad(program, (0, 1),
-                                         has_aux=True)(mine, x)
-    want, w = jax.value_and_grad(plain, (0, 1))(mine, x)
+    ((got, stats), g), (want, w) = share_and_grads(
+        middle_share(params), x)
     assert abs(float(got) - float(want)) < 1e-4
     assert int(stats["moe_dropped"]) == 0
+    if row_block == 4:          # a quarter of the pairs are held
+        assert int(stats["moe_row_blocks_run"]) \
+            < int(stats["moe_row_blocks"]) // 2
     for a, b in zip(jax.tree.leaves(g), jax.tree.leaves(w)):
+        assert np.isfinite(np.asarray(a)).all()
         assert reference.relative_error(a, b) < 1e-5
 
 
 def test_no_pair_is_dropped_when_every_token_picks_one_expert(
-        whole_layer, buffers):
+        whole_layer, buffers, row_block):
     """The worst imbalance: the router sends every token to the same
     three experts, all held here."""
     params, x = whole_layer
@@ -257,22 +293,23 @@ def test_no_pair_is_dropped_when_every_token_picks_one_expert(
     p["router"] = jnp.zeros_like(p["router"]).at[:, 4:7].set(
         jnp.array([3.0, 2.0, 1.0]))
     x = jnp.abs(x)                      # so the logits keep their order
-    mine = dict(p, **{n: p[n][4:8] for n in
-                      ("experts_gate", "experts_up", "experts_down")})
+    mine = middle_share({"params": p})
     out, stats = ffn_module(4, 4).apply({"params": mine}, x)
     assert int(stats["moe_held"]) == int(stats["moe_routed"]) \
         == 2 * SEQ * 3
     assert int(stats["moe_dropped"]) == 0
     assert int(stats["moe_load_max"]) == 2 * SEQ
-    kw = dict(TOY, experts_held=4, expert_offset=4)
-    want = jnp.stack([reference.sparse_ffn(mine, row, kw)
+    assert int(stats["moe_row_blocks_run"]) \
+        == int(stats["moe_row_blocks"]) \
+        == 2 * SEQ * 3 // seqpolicy._row_block(buffers * 3)
+    want = jnp.stack([reference.sparse_ffn(mine, row, MIDDLE)
                       for row in x])
     assert reference.relative_error(out, want) < 1e-5
 
 
-def one_chunk(rows):
-    """48 pairs, 30 of them for the 4 held experts (sizes 12, 9, 6,
-    3), through a buffer of ``rows`` rows."""
+def chunk_inputs():
+    """16 tokens' 48 pairs, 30 of them for the 4 held experts (sizes
+    12, 9, 6, 3): ``x``, ``local``, ``weight`` and the matrices."""
     keys = jax.random.split(jax.random.key(13), 5)
     x = jax.random.normal(keys[0], (16, 32))
     local = jnp.array([0] * 12 + [1] * 9 + [2] * 6 + [3] * 3
@@ -280,15 +317,26 @@ def one_chunk(rows):
     weight = jax.random.uniform(keys[1], (16, 3))
     mats = [jax.random.normal(k, shape) for k, shape in zip(
         keys[2:], [(4, 32, 16), (4, 32, 16), (4, 16, 32)])]
-    out, sizes, dropped = seqpolicy.held_experts(
+    return x, local, weight, mats
+
+
+def one_chunk(rows):
+    """``chunk_inputs`` through a buffer of ``rows`` rows."""
+    x, local, weight, mats = chunk_inputs()
+    out, sizes, dropped, blocks = seqpolicy.held_experts(
         x, local, weight, *mats, rows=rows)
+    # the row blocks follow the pairs the buffer holds
+    block = seqpolicy._row_block(rows)
+    assert blocks.tolist() == [-(-min(30, rows) // block),
+                               rows // block]
     each = jnp.stack([reference._mlp(x, *(m[e] for m in mats))
                       for e in range(4)], axis=1)       # [T, E, D]
     return out, sizes, int(dropped), each, local, weight
 
 
 @pytest.mark.parametrize("rows", [48, 32, 30])
-def test_a_buffer_that_holds_what_arrives_drops_nothing(rows):
+def test_a_buffer_that_holds_what_arrives_drops_nothing(rows,
+                                                        row_block):
     out, sizes, dropped, each, local, weight = one_chunk(rows)
     assert sizes.tolist() == [12, 9, 6, 3] and dropped == 0
     want = sum(jnp.where((local == e)[..., None], weight[..., None]
@@ -298,7 +346,7 @@ def test_a_buffer_that_holds_what_arrives_drops_nothing(rows):
 
 @pytest.mark.parametrize("rows,lost", [(24, 6), (16, 14), (8, 22)])
 def test_a_buffer_cut_below_what_arrives_counts_what_it_left_out(
-        rows, lost):
+        rows, lost, row_block):
     """The count is taken from the buffer, not assumed: pairs past
     its last row are counted and add nothing; the others are whole."""
     out, sizes, dropped, each, local, weight = one_chunk(rows)
@@ -314,6 +362,84 @@ def test_a_buffer_cut_below_what_arrives_counts_what_it_left_out(
                          weight[..., None] * each[:, e, None], 0)
                for e in range(4))
     assert reference.relative_error(out, want.sum(axis=1)) < 1e-5
+
+
+@pytest.mark.parametrize("block,run,of", [
+    (4, 8, 12), (16, 2, 3), (48, 1, 1), (5, 1, 1), (2048, 1, 1)])
+def test_the_row_blocks_that_run_are_those_that_hold_a_pair(
+        monkeypatch, block, run, of):
+    """30 held pairs in a buffer of 48 rows."""
+    monkeypatch.setattr(seqpolicy, "EXPERT_ROW_BLOCK", block)
+    x, local, weight, mats = chunk_inputs()
+    blocks = seqpolicy.held_experts(x, local, weight, *mats)[3]
+    assert blocks.tolist() == [run, of]
+
+
+def spoiled_ragged_dot(real):
+    """``ragged_dot`` at its least forgiving: the rows past its
+    groups — of the product, and of the cotangent it hands back for
+    its left side — hold NaN, and if anything but zero was FED to it
+    in such a row, everything it returns is NaN."""
+    def spoil(out, sizes, *fed):
+        past = (jnp.arange(fed[0].shape[0]) >= sizes.sum())[:, None]
+        dirty = jnp.any(jnp.stack(
+            [((x != 0) & past).any() for x in fed]))
+        if out.shape[0] == past.shape[0] and out.ndim == 2:
+            dirty = dirty | past
+        return jnp.where(dirty, jnp.nan, out)
+
+    @jax.custom_vjp
+    def dot(a, w, sizes):
+        return spoil(real(a, w, sizes), sizes, a)
+
+    def fwd(a, w, sizes):
+        return dot(a, w, sizes), (a, w, sizes)
+
+    def bwd(res, g):
+        a, w, sizes = res
+        da, dw = jax.vjp(lambda a, w: real(a, w, sizes), a, w)[1](g)
+        return spoil(da, sizes, a, g), spoil(dw, sizes, a, g), None
+
+    dot.defvjp(fwd, bwd)
+    return dot
+
+
+@pytest.mark.parametrize("block", [4, 16, 2048])
+def test_what_a_product_leaves_past_its_groups_reaches_nothing(
+        whole_layer, monkeypatch, block):
+    """The guard against ``0 × garbage``: with NaN in every row a
+    product does not own, the held share and all its gradients are
+    finite and the reference's, most blocks skipped or none."""
+    monkeypatch.setattr(seqpolicy, "EXPERT_ROW_BLOCK", block)
+    monkeypatch.setattr(seqpolicy, "EXPERT_CHUNK", 64)
+    params, x = whole_layer
+    # the reference multiplies densely: no product of its own
+    monkeypatch.setattr(jax.lax, "ragged_dot",
+                        spoiled_ragged_dot(jax.lax.ragged_dot))
+    ((got, stats), g), (want, w) = share_and_grads(
+        middle_share(params), x)
+    assert int(stats["moe_held"]) < 2 * SEQ * 3 // 2    # rows are left
+    assert abs(float(got) - float(want)) < 1e-4
+    for a, b in zip(jax.tree.leaves(g), jax.tree.leaves(w)):
+        assert np.isfinite(np.asarray(a)).all()
+        assert reference.relative_error(a, b) < 1e-5
+
+
+@pytest.mark.parametrize("block", [4, 16, 48])
+def test_skipping_blocks_changes_no_row_that_holds_a_pair(
+        whole_layer, monkeypatch, block):
+    """The layer with most row blocks skipped against the layer at
+    one block: float32 round-off apart, and no further."""
+    params, x = whole_layer
+    mine = middle_share(params)
+    monkeypatch.setattr(seqpolicy, "EXPERT_CHUNK", 64)
+    whole, _ = ffn_module(4, 4).apply({"params": mine}, x)
+    monkeypatch.setattr(seqpolicy, "EXPERT_ROW_BLOCK", block)
+    out, stats = ffn_module(4, 4).apply({"params": mine}, x)
+    assert int(stats["moe_row_blocks"]) == 192 // block
+    assert int(stats["moe_row_blocks_run"]) \
+        == -(-int(stats["moe_held"]) // block)
+    assert reference.relative_error(out, whole) < 1e-6
 
 
 def test_the_routers_choices_are_kept_for_who_asks(net, batch):
@@ -493,6 +619,10 @@ def test_the_train_step_learns_and_returns_the_routing_counts(net):
         == int(m["moe_held"])
     assert after["gauges"][registry.MOE_EXPERT_LOAD_MAX] \
         == int(m["moe_load_max"])
+    # 2 rows of 32 tokens in chunks of 16 tokens, 4 sparse layers
+    assert int(m["moe_row_blocks_run"]) == int(m["moe_row_blocks"]) \
+        == after["counters"][registry.MOE_ROW_BLOCKS] == 4 * 4
+    assert after["counters"][registry.MOE_ROW_BLOCKS_RUN] == 4 * 4
     sl.record_routing([{"loss": 1.0}])      # a conv step: nothing
 
 
