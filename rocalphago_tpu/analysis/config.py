@@ -23,8 +23,7 @@ import dataclasses
 import os
 import re
 
-DEFAULT_INCLUDE = ("rocalphago_tpu", "scripts", "benchmarks", "tests",
-                   "bench.py")
+DEFAULT_INCLUDE = ("rocalphago_tpu", "scripts", "tests")
 DEFAULT_EXCLUDE = ()
 
 

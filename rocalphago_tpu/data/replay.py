@@ -102,7 +102,7 @@ class ZeroGames(NamedTuple):
     - ``full``: ``[T, B]`` bool — ply ran a FULL search (playout-cap
       randomization; only these plies carry policy targets)
     - ``ownership``: ``[B, N]`` int8 terminal ownership labels
-      (black-positive; :func:`rocalphago_tpu.ops.labels
+      (black-positive; :func:`rocalphago_tpu.engine.jaxgo
       .terminal_labels`)
     - ``score``: ``[B]`` float32 terminal score margins (black −
       white, komi included)

@@ -172,11 +172,11 @@ def _dense_engine() -> bool:
     On TPU, scatter-adds with colliding indices and `[N,4]` index
     gathers serialize, while broadcast compares, 2-D grid shifts and
     small matmuls run at full vector/MXU width — the one on-chip A/B
-    on record (`benchmarks/results.jsonl`, 2026-08-01, batch 1024,
-    19x19): dense 17,762 steps/s vs scatter 10,558, so dense is the
-    TPU default. On CPU the scatter path wins (1444 cheap serial
-    updates beat 131k-cell dense compares), so the default follows
-    the backend platform; entry points report which one ran
+    on record (ROADMAP.md's north star: 2026-08-01, before PR 1, a
+    12x128 net's games at batch 1024, 19x19): dense 17,762 steps/s vs
+    scatter 10,558, so dense is the TPU default. On CPU the scatter
+    path wins (1444 cheap serial updates beat 131k-cell dense
+    compares), so the default follows the backend platform; entry points report which one ran
     (:func:`engine_formulation`, the ``device`` event).
 
     Read once per process (trace-time; cached): override with
@@ -768,13 +768,12 @@ def _step_place(cfg: GoConfig, state: GoState, action,
 # --------------------------------------------------------------------------
 
 
-def area_scores(cfg: GoConfig, state: GoState) -> tuple[jax.Array, jax.Array]:
-    """Area (Chinese) scores ``(black, white_plus_komi)`` — empty regions
-    bordering exactly one color count for it. Same flood-fill machinery
-    as group labels, run on the empty graph."""
+def _territory(cfg: GoConfig, board: jax.Array):
+    """bool ``[N]`` masks ``(black's, white's)`` of the empty points in
+    regions bordering exactly one color. Same flood-fill machinery as
+    group labels, run on the empty graph."""
     n = cfg.num_points
     nbrs = neighbors_for(cfg.size)
-    board = state.board
     empty = board == 0
 
     # label empty regions: treat empty as the "color"
@@ -785,11 +784,17 @@ def area_scores(cfg: GoConfig, state: GoState) -> tuple[jax.Array, jax.Array]:
     touches_w_pt = empty & (nbr_color == WHITE).any(axis=1)
     touches_b = jnp.zeros((n + 1,), jnp.bool_).at[region].max(touches_b_pt)
     touches_w = jnp.zeros((n + 1,), jnp.bool_).at[region].max(touches_w_pt)
+    return (empty & touches_b[region] & ~touches_w[region],
+            empty & touches_w[region] & ~touches_b[region])
 
-    terr_b = (empty & touches_b[region] & ~touches_w[region]).sum()
-    terr_w = (empty & touches_w[region] & ~touches_b[region]).sum()
-    black = (board == BLACK).sum() + terr_b
-    white = (board == WHITE).sum() + terr_w
+
+def area_scores(cfg: GoConfig, state: GoState) -> tuple[jax.Array, jax.Array]:
+    """Area (Chinese) scores ``(black, white_plus_komi)`` — empty regions
+    bordering exactly one color count for it."""
+    board = state.board
+    terr_b, terr_w = _territory(cfg, board)
+    black = (board == BLACK).sum() + terr_b.sum()
+    white = (board == WHITE).sum() + terr_w.sum()
     return black.astype(jnp.float32), white.astype(jnp.float32) + cfg.komi
 
 
@@ -797,6 +802,29 @@ def winner(cfg: GoConfig, state: GoState) -> jax.Array:
     """+1 black wins, -1 white wins, 0 draw."""
     b, w = area_scores(cfg, state)
     return jnp.sign(b - w).astype(jnp.int32)
+
+
+def terminal_labels(cfg: GoConfig, state: GoState):
+    """Auxiliary training targets from one TERMINAL position:
+    ``(ownership int8 [N], score float32)``, black-positive.
+
+    Ownership is the area-scoring verdict per point: a stone's own
+    color, and for empty points the color of the single-color region
+    they sit in (+1 black, -1 white, 0 contested/neutral — dame and
+    seki-shared regions). Score is ``black − white`` with the komi
+    inside white, so ``sign(score) == winner`` by construction — the
+    parity the tests pin. One game's labels (vmap over a batch at the
+    call site, e.g. the zero loop's game-end labelling).
+    """
+    board = state.board
+    terr_b, terr_w = _territory(cfg, board)
+    ownership = (board.astype(jnp.int8)
+                 + terr_b.astype(jnp.int8) - terr_w.astype(jnp.int8))
+    black = (board == BLACK).sum() + terr_b.sum()
+    white = (board == WHITE).sum() + terr_w.sum()
+    score = (black.astype(jnp.float32)
+             - white.astype(jnp.float32) - cfg.komi)
+    return ownership, score
 
 
 # --------------------------------------------------------------------------

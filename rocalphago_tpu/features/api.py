@@ -117,8 +117,8 @@ class Preprocess:
       ``tests/test_features.py``), but dense whole-board ladder
       problems can — raise this (e.g. to 16) when encoding such
       positions; cost is roughly linear in the chase loop's width.
-      MEASURED DEFAULT 6: the CPU A/B (``benchmarks/bench_encode.py``,
-      dense 19×19, shared/phase1=2) ran ~85 pos/s at 4 slots, ~74 at
+      MEASURED DEFAULT 6: the CPU A/B (dense 19×19,
+      shared/phase1=2) ran ~85 pos/s at 4 slots, ~74 at
       6, ~69 at 8 — 6 trades ~13% against the fastest setting to keep
       the POOLED capacity near the pre-overhaul per-plane total
       (4 + 4) and dense-board truncation well inside the 1% oracle

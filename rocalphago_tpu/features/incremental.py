@@ -123,7 +123,7 @@ VERDICT_SLOTS = 128
 #: depends on the compaction. Segregated by kind so each opening
 #: algebra runs once at its own width instead of both running over
 #: one mixed set. MEASURED DEFAULT (8, 4): the 19×19 random-tail A/B
-#: (``bench_encode.py --trajectory``) ran ~2350 µs/pos at (8, 4) vs
+#: on CPU (CHANGES.md PR 6) ran ~2350 µs/pos at (8, 4) vs
 #: ~2600 at (12, 6) and ~2500 at (4, 2) — wide enough that full-width
 #: fallbacks stay rare (13 in a 100-ply dense tail), narrow enough
 #: that the per-ply record/expansion work stops paying for idle lanes.

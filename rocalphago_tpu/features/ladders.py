@@ -59,8 +59,8 @@ def _phase1_depth() -> int:
     reads all slots to this many rungs lockstep; still-live lanes
     then finish one at a time at 1/slots the loop width. Most lanes
     settle within a few rungs. MEASURED DEFAULT 2 (the
-    ``jaxgo._dense_engine`` discipline): ``benchmarks/bench_encode.py``
-    CPU A/B on dense 19×19 mid-games, batch 16, shared gating —
+    ``jaxgo._dense_engine`` discipline): a CPU A/B (CHANGES.md PR 5)
+    on dense 19×19 mid-games, batch 16, shared gating —
     depth 2 won both slot sweeps (91.0 pos/s vs 77.1 @ 1 / 81.4 @ 4
     at 4 slots; 73.9 vs 73.3 / 71.3 at the default 6 — within the
     run-to-run ~10% noise there), 8 pays extra lockstep rungs
@@ -80,10 +80,10 @@ def _ladder_gating() -> str:
     into ONE compacted slot set and ONE lockstep rung loop;
     ``"split"`` keeps the legacy per-plane chases (two loops of
     ``chase_slots`` each — the pre-overhaul formulation, kept as the
-    A/B baseline). MEASURED DEFAULT: shared wins the CPU A/B
-    (``benchmarks/bench_encode.py``; the two planes' rung loops merge,
-    so a deep chase pays its trips once instead of once per plane —
-    CHANGES.md PR 5). Read from
+    A/B baseline). MEASURED DEFAULT: shared wins the CPU A/B (the
+    two planes' rung loops merge, so a deep chase pays its trips
+    once instead of once per plane — CHANGES.md PR 5; on the chip:
+    not measured). Read from
     ``$ROCALPHAGO_LADDER_GATE`` at trace time."""
     v = os.environ.get("ROCALPHAGO_LADDER_GATE", "shared")
     return "split" if v in ("split", "0", "off") else "shared"
@@ -321,8 +321,7 @@ def _foot_mode() -> str:
     only costs reuse. Read from ``$ROCALPHAGO_LADDER_FOOT`` at trace
     time (same policy as the other ladder knobs). MEASURED: tight cuts
     the footprint-churn re-chase cascade that capped incremental
-    encode at ~2.1–2.3× on CPU — CHANGES.md PR 19 and the
-    ``encode_cascade`` row of ``bench_encode.py``."""
+    encode at ~2.1–2.3× on CPU — CHANGES.md PR 19."""
     v = os.environ.get("ROCALPHAGO_LADDER_FOOT", "tight")
     return "wide" if v in ("wide", "0", "off") else "tight"
 
@@ -839,7 +838,7 @@ def ladder_planes(cfg: GoConfig, state: GoState, gd: GroupData,
 
     ``$ROCALPHAGO_LADDER_GATE=split`` traces the legacy per-plane
     formulation instead (two independent ``chase_slots``-wide chases)
-    — the measured A/B baseline (``benchmarks/bench_encode.py``).
+    — the A/B baseline and the reference of ``TestSharedGating``.
     """
     n = cfg.num_points
     analysis = neighbor_analysis(cfg, state.board, gd.labels)

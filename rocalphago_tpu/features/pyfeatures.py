@@ -53,8 +53,9 @@ DEFAULT_FEATURES = (
 # the value net's 49-plane input: the 48 policy planes + player color
 VALUE_FEATURES = DEFAULT_FEATURES + ("color",)
 
-#: the two handcrafted ladder plane groups — ~88% of encode cost
-#: (bench_encode.py no-ladder row), the target of the ladder-free
+#: the two handcrafted ladder plane groups — ~88% of encode cost on
+#: CPU, 94% of a self-play ply on the chip (PERF.md §5, by hand) —
+#: the target of the ladder-free
 #: self-play configuration (docs/PERFORMANCE.md "Ladder-free encode")
 LADDER_FEATURES = ("ladder_capture", "ladder_escape")
 

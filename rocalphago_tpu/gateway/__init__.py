@@ -17,11 +17,9 @@ Python APIs. The gateway turns the pool into an actual service:
   JSON plus a ``"gateway"`` block) and ``/metrics`` (the obs
   registry's Prometheus rendering);
 * :mod:`~rocalphago_tpu.gateway.client` — the client handle + load
-  generator driving ``benchmarks/bench_gateway.py`` and
-  ``scripts/gateway_soak.py``.
+  generator driving ``scripts/gateway_soak.py``.
 
-Wire format, probe schema, drain semantics, measured numbers:
-docs/GATEWAY.md.
+Wire format, probe schema, drain semantics: docs/GATEWAY.md.
 """
 
 from rocalphago_tpu.gateway.protocol import PROTO_VERSION  # noqa: F401

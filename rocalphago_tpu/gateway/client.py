@@ -2,7 +2,7 @@
 
 :class:`GatewayClient` is the blocking request/response handle every
 consumer shares — the GTP bridge (``interface/gtp.py --connect``),
-``benchmarks/bench_gateway.py`` and ``scripts/gateway_soak.py``. A
+``scripts/gateway_soak.py`` and ``chip_smoke.py``'s gateway leg. A
 structured refusal (``overload``/``draining``) surfaces as
 :class:`GatewayRefused` carrying the server's ``retry_after_s`` so
 callers back off instead of spinning; a dropped connection is
@@ -17,8 +17,7 @@ sleep — or spinning.
 
 :func:`run_load` drives N concurrent synthetic games (one
 connection each, barrier-started) and returns per-genmove latencies
-plus shed/disconnect counts — the measurement half of the wire-tax
-A/B and the soak's traffic source.
+plus shed/disconnect counts — the soaks' traffic source.
 """
 
 from __future__ import annotations
@@ -348,11 +347,9 @@ def run_load(host: str, port: int, conns: int, moves: int,
              timeout: float = 120.0) -> dict:
     """N concurrent synthetic games against a gateway.
 
-    Barrier-started so every connection ramps together (the same
-    idiom as ``benchmarks/bench_serve.py``). Returns moves/sheds/
-    disconnect/error counts, the elapsed wall time and every
-    per-genmove latency — :func:`summarize` turns that into the
-    bench row.
+    Barrier-started so every connection ramps together. Returns
+    moves/sheds/disconnect/error counts, the elapsed wall time and
+    every per-genmove latency.
     """
     start = threading.Barrier(conns + 1)
     lock = threading.Lock()

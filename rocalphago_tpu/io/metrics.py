@@ -3,9 +3,8 @@
 Parity: the reference's observability is the Keras progress bar plus
 ``metadata.json`` (SURVEY.md §5 "Metrics / logging"). The rebuild logs
 one JSON object per event to ``metrics.jsonl`` (step, loss, accuracy,
-games/min, …) — greppable, plottable, and the format ``bench.py``
-reuses. TensorBoard is intentionally not a dependency; the JSONL is
-trivially convertible.
+games/min, …) — greppable and plottable. TensorBoard is
+intentionally not a dependency; the JSONL is trivially convertible.
 
 The same stream carries the observability subsystem's records
 (``span``/``compile``/``registry`` events — see

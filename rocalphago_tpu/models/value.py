@@ -23,7 +23,7 @@ Auxiliary heads (``aux_heads=("ownership", "score")``, KataGo's
 "Accelerating Self-Play Learning in Go"): extra prediction heads
 sharing the trunk — per-point terminal ownership (tanh ``[B, N]``)
 and final score margin (scalar) — trained against the engine's
-terminal labels (:func:`rocalphago_tpu.ops.labels.terminal_labels`)
+terminal labels (:func:`rocalphago_tpu.engine.jaxgo.terminal_labels`)
 as regularizers that feed territory signal back into the shared
 trunk. Default ``()``: the param tree, the value output, and every
 compiled program are unchanged. With heads on, the main ``__call__``

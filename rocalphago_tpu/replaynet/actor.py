@@ -16,10 +16,10 @@ Two game sources:
   dedup window. That determinism is what lets the chaos soak
   (``scripts/replay_soak.py``) assert exact produced-vs-ingested
   set equality through kill storms.
-* ``--mode selfplay`` — real self-play from the tiny bench model
-  (same flags as ``benchmarks/bench_zero_scale.py``), for the
-  ``--wire`` scaling sweep. Params stay at version 0 (parameter
-  distribution is out of scope for this rig).
+* ``--mode selfplay`` — real self-play from a tiny fixed model
+  (1 layer × 4 filters), for driving the wire with games a search
+  made. Params stay at version 0 (parameter distribution is out of
+  scope for this rig).
 
 Resume protocol: on start the actor counts its durably produced
 games (``acked ∪ spooled`` — :meth:`ReplayClient.produced_ids`) and
@@ -90,7 +90,7 @@ def _run_synthetic(a, client: ReplayClient) -> int:
 
 
 def _run_selfplay(a, client: ReplayClient) -> int:
-    """Real self-play on the tiny bench model (one process, own
+    """Real self-play on a tiny fixed model (one process, own
     mesh); ships one batch per produced game index."""
     import jax
     import optax
@@ -121,7 +121,7 @@ def _run_selfplay(a, client: ReplayClient) -> int:
     done = len(client.produced_ids())
     # selfplay content is NOT restart-deterministic (the rng chain
     # isn't checkpointed) — the count-based resume still never
-    # under- or over-produces, which is all the bench needs
+    # under- or over-produces, which is all this rig needs
     for _ in range(done, a.games):
         key, game_key = jax.random.split(key)
         games = jax.device_get(

@@ -1,8 +1,8 @@
 """Where JAX's persistent compilation cache lives — one rule, one place.
 
 Every entry point (trainers, self-play CLI, GTP engine, gateway,
-``bench.py``, the ``benchmarks/`` harness, ``chip_smoke.py``'s legs and
-the test suite's conftest) calls :func:`enable_compile_cache` before its
+``chipbench/run.py``, ``chip_smoke.py``'s legs and the test suite's
+conftest) calls :func:`enable_compile_cache` before its
 first compile, so a re-launch of the SAME program loads its executables
 instead of compiling them again.
 

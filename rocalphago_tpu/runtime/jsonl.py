@@ -5,8 +5,8 @@ in a line-buffered JSONL stream (``io.metrics.MetricsLogger`` emits
 whole lines through a ``buffering=1`` handle; ``tests/
 test_runtime.py`` pins the at-most-one-torn-line invariant), so a
 reader that skips undecodable lines loses at most the final
-in-flight record instead of crashing. ``bench.py`` and
-``scripts/zero_curve.py`` read crash-prone logs through this.
+in-flight record instead of crashing. ``scripts/zero_curve.py``
+and ``scripts/obs_report.py`` read crash-prone logs through this.
 """
 
 from __future__ import annotations

@@ -7,7 +7,7 @@ programs driven from a host loop — but the loops then paid a full
 host sync per chunk (``jax.block_until_ready`` for the deadline
 check, a blocking ``device_get`` for the self-play done-poll), so the
 device idled in every gap, on exactly the sims/sec and games/min
-paths the benchmarks headline. This module takes the host back out of
+paths. This module takes the host back out of
 the steady state: a :class:`ChunkPipeline` lets the loop dispatch
 chunk N+1 while the host inspects chunk N's already-materialized
 scalars, so deadline checks, done-polls and per-chunk observability
@@ -57,8 +57,9 @@ Observability (``obs.registry``): every pipeline records the
 the device had NOTHING in flight — the idle the sync path pays per
 chunk), a ``device_occupancy{runner=...}`` gauge (1 − gap/wall over
 the pipeline's active windows) and ``dispatch_chunks_total``;
-``scripts/obs_report.py`` renders them and the benches publish
-``host_gap_frac`` for the pipelined-vs-sync A/B. The same three
+``scripts/obs_report.py`` renders them and the chip benchmark's
+self-play driver reads ``host_gap_frac`` (``chipbench/layers/
+dispatch_gap_pct.selfplay.py``). The same three
 intervals go on the profiler's clock (``obs.trace.annotation``):
 ``pipeline.dispatch`` (a push's bookkeeping), ``pipeline.wait`` (a
 retire blocked on the device) and ``pipeline.host_work`` (the gap the

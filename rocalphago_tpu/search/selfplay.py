@@ -61,20 +61,19 @@ def incremental_default() -> bool:
     """Whether the batched self-play ply loop carries the incremental
     encode cache (``features/incremental.py``) — env knob
     ``ROCALPHAGO_ENCODE_INCR``, read at TRACE time like the ladder
-    knobs so benchmarks can A/B it per traced program.
+    knobs so a run can A/B it per traced program.
 
     MEASURED DEFAULT off for the BATCHED loop: under ``vmap`` the
     delta path's gating conds lower to selects that execute both
     branches, so its win is confined to cached ladder verdicts
     shortening the batch-lockstep rung loop, against the footprint
-    bookkeeping it adds every ply (``bench_encode.py --trajectory
-    --traj-batch`` records the A/B on CPU, CHANGES.md PR 6; on the
-    chip: not measured). The SEQUENTIAL single-state paths
-    (``Preprocess.advance``, the ``DeviceMCTSPlayer`` root advance,
-    ``bench_encode --trajectory``) default ON instead — there the
-    host-branch gating really skips the opening/chase blocks and
-    measures ~2× µs/pos on dense 19×19 random tails. Results are
-    bit-identical either way (``tests/test_incremental.py``)."""
+    bookkeeping it adds every ply (a CPU A/B, CHANGES.md PR 6; on
+    the chip: not measured). The SEQUENTIAL single-state paths
+    (``Preprocess.advance``, the ``DeviceMCTSPlayer`` root advance)
+    default ON instead — there the host-branch gating really skips
+    the opening/chase blocks (~2× µs/pos on dense 19×19 random tails
+    on CPU; on the chip: not measured). Results are bit-identical
+    either way (``tests/test_incremental.py``)."""
     from rocalphago_tpu.features import incremental as _incr
 
     return _incr.enabled(default=False)
@@ -547,11 +546,8 @@ def host_winners(cfg: GoConfig, boards: np.ndarray) -> np.ndarray:
 
 def make_device_rollout(cfg: GoConfig, features: tuple, apply_fn: Callable,
                         rollout_limit: int = 500,
-                        temperature: float = 1.0,
-                        with_steps: bool = False):
-    """Jitted ``(params, states, rng) -> winners`` rollout-to-terminal
-    (``with_steps=True``: ``-> (winners, executed_plies)`` — benchmarks
-    must not assume the early-exit loop ran to ``rollout_limit``).
+                        temperature: float = 1.0):
+    """Jitted ``(params, states, rng) -> winners`` rollout-to-terminal.
 
     The MCTS λ-mix's rollout leg, fully on device (SURVEY.md §3.3
     rebuild note): play a *batched* :class:`GoState` — e.g. a wave of
@@ -597,11 +593,8 @@ def make_device_rollout(cfg: GoConfig, features: tuple, apply_fn: Callable,
             states, _, t = carry
             return ~states.done.all() & (t < rollout_limit)
 
-        final, _, t = lax.while_loop(cond, ply,
+        final, _, _ = lax.while_loop(cond, ply,
                                      (states, rng, jnp.int32(0)))
-        winners = jax.vmap(functools.partial(winner, cfg))(final)
-        # with_steps: also report the executed ply count (benchmarks
-        # must not assume the loop ran to rollout_limit)
-        return (winners, t) if with_steps else winners
+        return jax.vmap(functools.partial(winner, cfg))(final)
 
     return run

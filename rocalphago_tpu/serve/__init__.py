@@ -25,8 +25,9 @@ PAPERS.md). The subsystem fuses pieces that already exist:
   fallback) and the :class:`~rocalphago_tpu.runtime.deadline.
   Deadline` SLO guarantees an anytime answer.
 
-Architecture, dispatch policy, knobs and measured numbers:
-docs/SERVING.md. Benchmark: ``benchmarks/bench_serve.py``.
+Architecture, dispatch policy and knobs: docs/SERVING.md. On the chip
+the pool has been driven by hand only (``chipbench/drivers/
+serve_closed.py``, PERF.md §5); no benchmark cell times it yet.
 """
 
 from rocalphago_tpu.serve.admission import (  # noqa: F401

@@ -245,7 +245,6 @@ class SelfplayActor:
         self._poll_s = default_poll_s() if poll_s is None else poll_s
         self._metrics = metrics
         self._on_progress = on_progress   # supervisor heartbeat
-        self._inject: BaseException | None = None
         self.games_played = 0
         self.error: BaseException | None = None
         self._stop = threading.Event()
@@ -265,15 +264,6 @@ class SelfplayActor:
 
     def alive(self) -> bool:
         return self._thread.is_alive()
-
-    def inject_fault(self, exc: BaseException | None = None) -> None:
-        """Arm a one-shot fault raised at this actor's next game
-        boundary (default :class:`~..runtime.faults.InjectedKill`) —
-        the deterministic per-actor kill the recovery bench
-        (``bench_zero_scale.py --kill-actor-at``) uses; randomized
-        schedules go through ``ROCALPHAGO_FAULT_PLAN`` instead."""
-        self._inject = exc if exc is not None else faults.InjectedKill(
-            f"injected kill of {self.name} (inject_fault)")
 
     # ------------------------------------------------------ producer
 
@@ -309,9 +299,6 @@ class SelfplayActor:
 
             try:
                 faults.barrier("actor.game", iteration=index)
-                if self._inject is not None:
-                    exc, self._inject = self._inject, None
-                    raise exc
                 with trace.span("actor.play", actor=self.name,
                                 game=index):
                     host = (self._gang.run(_play_synced)

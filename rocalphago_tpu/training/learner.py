@@ -18,8 +18,7 @@ Metrics: ``learner_steps_total`` counter, ``learner_wait_seconds``
 histogram (time blocked on the buffer per step), and the headline
 ``learner_idle_frac`` gauge — cumulative wait over wall time, THE
 number the actor/learner split exists to push down (the synchronous
-loop's equivalent is its self-play phase fraction;
-``benchmarks/bench_zero_scale.py`` measures both).
+loop's equivalent is its self-play phase fraction).
 """
 
 from __future__ import annotations

@@ -50,7 +50,6 @@ from rocalphago_tpu.features.planes import batched_encoder, needs_member
 from rocalphago_tpu.features.pyfeatures import output_planes
 from rocalphago_tpu.io.checkpoint import pack_rng, unpack_rng
 from rocalphago_tpu.obs import jaxobs, trace
-from rocalphago_tpu.ops.labels import terminal_labels
 from rocalphago_tpu.parallel import mesh as meshlib
 from rocalphago_tpu.runtime.pipeline import ChunkPipeline
 from rocalphago_tpu.search.device_mcts import make_mcts_selfplay
@@ -151,7 +150,7 @@ def make_zero_iteration(cfg: jaxgo.GoConfig, policy_features: tuple,
         mesh=mesh, cap_p=cap_p, cap_cheap=cheap,
         cap_per_row=cap_per_row, forced_k=forced_k)
     vlabels = jax.jit(jax.vmap(
-        functools.partial(terminal_labels, cfg))) if aux else None
+        functools.partial(jaxgo.terminal_labels, cfg))) if aux else None
 
     n_policy_planes = output_planes(policy_features)
     vgd = jax.vmap(lambda s: jaxgo.group_data(

@@ -10,7 +10,7 @@ set -eu
 cd "$(dirname "$0")/.."
 OUT=${1:-results/elo_demo}
 GAMES=${2:-6}
-SPECS=benchmarks/tpu_extra_r3
+SPECS=results/elo_demo/specs
 mkdir -p "$OUT"
 
 run_pair() {

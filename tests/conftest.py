@@ -10,7 +10,10 @@ first ``jax.devices()`` call), so they are made at conftest-import
 time.
 """
 
+import functools
 import os
+
+import pytest
 
 _flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in _flags:
@@ -32,3 +35,33 @@ from rocalphago_tpu.runtime.compilecache import (  # noqa: E402
 )
 
 enable_compile_cache()
+
+
+@pytest.fixture
+def random_game_states():
+    """``(cfg, batch, moves, key) -> GoState``: batched positions after
+    ``moves`` uniform random legal plies, played under one jit."""
+    import jax.numpy as jnp
+
+    from rocalphago_tpu.engine import jaxgo
+
+    def play(cfg, batch, moves, key):
+        vstep = jax.vmap(functools.partial(jaxgo.step, cfg))
+        vlegal = jax.vmap(functools.partial(jaxgo.legal_mask, cfg))
+
+        def ply(carry, _):
+            states, key = carry
+            key, sub = jax.random.split(key)
+            legal = vlegal(states)[:, :-1]
+            action = jnp.where(
+                legal.any(-1),
+                jax.random.categorical(
+                    sub, jnp.where(legal, 0.0, -1e30), axis=-1),
+                cfg.num_points).astype(jnp.int32)
+            return (vstep(states, action), key), None
+
+        return jax.jit(lambda key: jax.lax.scan(
+            ply, (jaxgo.new_states(cfg, batch), key),
+            length=moves)[0][0])(key)
+
+    return play

@@ -207,29 +207,29 @@ def test_selfplay_forced_k_records_pruned_distribution():
 # ------------------------------------------------ terminal labels
 
 
-def test_terminal_labels_parity_with_engine_scoring():
-    """ops.labels.terminal_labels must agree with the engine's area
+@pytest.mark.parametrize("size,moves", [(9, 70), (19, 320)])
+def test_terminal_labels_parity_with_engine_scoring(
+        size, moves, random_game_states):
+    """jaxgo.terminal_labels must agree with the engine's area
     scoring exactly: score == black − white_plus_komi, sign(score) ==
     jaxgo.winner, and the per-point ownership counts reproduce the
     score (ownership IS the area verdict per point)."""
-    from benchmarks._harness import random_game_states
-    from rocalphago_tpu.ops.labels import terminal_labels
-
-    states = random_game_states(CFG, 8, 40, jax.random.key(2))
+    cfg = GoConfig(size=size)
+    states = random_game_states(cfg, 8, moves, jax.random.key(2))
     own, score = jax.device_get(
-        jax.vmap(lambda s: terminal_labels(CFG, s))(states))
+        jax.vmap(lambda s: jaxgo.terminal_labels(cfg, s))(states))
     b, w = jax.device_get(
-        jax.vmap(lambda s: jaxgo.area_scores(CFG, s))(states))
+        jax.vmap(lambda s: jaxgo.area_scores(cfg, s))(states))
     np.testing.assert_allclose(
         score, np.asarray(b, np.float32) - np.asarray(w, np.float32))
     winners = jax.device_get(
-        jax.vmap(lambda s: jaxgo.winner(CFG, s))(states))
+        jax.vmap(lambda s: jaxgo.winner(cfg, s))(states))
     np.testing.assert_array_equal(
         np.sign(score).astype(np.int32), winners)
     assert own.dtype == np.int8
     assert set(np.unique(own)) <= {-1, 0, 1}
     np.testing.assert_allclose(
-        (own == 1).sum(axis=-1) - (own == -1).sum(axis=-1) - CFG.komi,
+        (own == 1).sum(axis=-1) - (own == -1).sum(axis=-1) - cfg.komi,
         score)
 
 

@@ -79,8 +79,11 @@ def pool(nets):
 @pytest.fixture(scope="module")
 def server(pool):
     """One long-lived gateway for the happy-path / refusal tests.
-    Shedding and drain tests build their own (drain is one-way)."""
-    srv = GatewayServer(pool, max_conns=4, slo_ms=2000.0)
+    Shedding and drain tests build their own (drain is one-way).
+    The SLO is armed but far off: the first genmove at a custom komi
+    compiles the komi program (``pool.warm()`` does not), which under
+    six test workers has taken over 2 s."""
+    srv = GatewayServer(pool, max_conns=4, slo_ms=60000.0)
     srv.start()
     yield srv
     srv.close()
@@ -197,7 +200,7 @@ def test_happy_path_conversation(server, pool):
         reply = client.genmove("b")
         assert reply["type"] == "move"
         assert reply["elapsed_ms"] >= 0.0
-        assert reply["slo_hit"] is False    # 2s SLO, 6-sim search
+        assert reply["slo_hit"] is False    # 60s SLO, 6-sim search
         assert "rung" in reply
         vertex = reply["move"]
         assert vertex == "pass" or vertex[0].isalpha()
@@ -218,7 +221,7 @@ def test_happy_path_conversation(server, pool):
     assert after["requests"]["unhandled"] \
         == before["requests"]["unhandled"]
     assert after["wire_ms"]["p50"] is not None
-    assert after["slo_ms"] == 2000.0
+    assert after["slo_ms"] == 60000.0
     assert after["boards"] == [SIZE]
 
 

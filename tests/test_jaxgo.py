@@ -139,6 +139,106 @@ def test_dense_engine_parity_differential(monkeypatch):
         jaxgo._dense_engine.cache_clear()  # monkeypatch restored the env
 
 
+def random_boards(size, batch, moves, seed):
+    rng = np.random.default_rng(seed)
+    out = np.zeros((batch, size * size), np.int8)
+    for i in range(batch):
+        st = pygo.GameState(size=size, komi=5.5)
+        for _ in range(moves):
+            legal = st.get_legal_moves(include_eyes=False)
+            if not legal or st.is_end_of_game:
+                break
+            st.do_move(legal[rng.integers(len(legal))])
+        out[i] = py_board_flat(st)
+    return out
+
+
+def single_file_snake(size: int):
+    """A 1-wide boustrophedon snake: even rows full, odd rows a single
+    connector stone at alternating ends — ONE group whose label must
+    propagate along the whole path (the longest chain constructible on
+    a board), the stress case for the labeller's propagation bound."""
+    b = np.zeros((size, size), np.int8)
+    for x in range(size):
+        if x % 2 == 0:
+            b[x, :] = 1
+        else:
+            b[x, size - 1 if (x // 2) % 2 == 0 else 0] = 1
+    return b.reshape(-1)
+
+
+def worst_case_boards(size):
+    """The snake (on 19×19 a chain of ~190 stones), a solid board and
+    the snake in the other color."""
+    solid = np.ones((size * size,), np.int8)
+    return np.stack([single_file_snake(size), solid,
+                     -single_file_snake(size)]).astype(np.int8)
+
+
+def host_groups(board, size):
+    """Host flood fill: ``(labels, sizes, libs)`` — a point's label is
+    the min flat index of its group (N for empty); sizes and distinct
+    liberties are indexed by that root."""
+    n = size * size
+    labels = np.full(n, n, np.int32)
+    sizes, libs = np.zeros(n + 1, np.int32), np.zeros(n + 1, np.int32)
+    for p in range(n):      # ascending: p is the min of a new group
+        if board[p] == 0 or labels[p] != n:
+            continue
+        group, frontier, lib = {p}, [p], set()
+        while frontier:
+            x, y = divmod(frontier.pop(), size)
+            for nx, ny in ((x + 1, y), (x - 1, y), (x, y + 1), (x, y - 1)):
+                if not (0 <= nx < size and 0 <= ny < size):
+                    continue
+                r = nx * size + ny
+                if board[r] == 0:
+                    lib.add(r)
+                elif board[r] == board[p] and r not in group:
+                    group.add(r)
+                    frontier.append(r)
+        labels[list(group)] = p
+        sizes[p], libs[p] = len(group), len(lib)
+    return labels, sizes, libs
+
+
+@pytest.mark.parametrize("size,moves", [
+    (9, 0), (9, 10), (9, 30), (9, 60), (9, None), (19, None)],
+    ids=lambda v: "snake" if v is None else str(v))
+def test_labels_and_group_data_match_host_flood_fill(
+        size, moves, monkeypatch):
+    """The labeller that runs on the chip against a host oracle, on
+    whole boards: sparse and dense random positions, and the longest
+    chains a board can hold — the input that stresses
+    ``compute_labels``' propagation (hooks + pointer jumps to a fixed
+    point). ``group_data``'s sizes and liberty counts must equal the
+    host's under BOTH formulations (dense: the TPU's; scatter: the
+    CPU's)."""
+    import jax
+
+    boards = (worst_case_boards(size) if moves is None
+              else random_boards(size, 6, moves, seed=moves))
+    cfg = GoConfig(size=size)
+    want = [host_groups(b, size) for b in boards]
+    got = np.asarray(jax.vmap(lambda b: compute_labels(cfg, b))(boards))
+    for row, (labels, _, _) in zip(got, want):
+        np.testing.assert_array_equal(row, labels)
+    try:
+        for dense in ("1", "0"):
+            monkeypatch.setenv("ROCALPHAGO_ENGINE_DENSE", dense)
+            jaxgo._dense_engine.cache_clear()
+            gd = jax.jit(jax.vmap(     # a fresh trace per formulation
+                lambda b: jaxgo.group_data(cfg, b)))(boards)
+            for i, (_, sizes, libs) in enumerate(want):
+                np.testing.assert_array_equal(
+                    np.asarray(gd.sizes[i]), sizes, f"dense={dense}")
+                np.testing.assert_array_equal(
+                    np.asarray(gd.lib_counts[i]), libs, f"dense={dense}")
+    finally:
+        monkeypatch.undo()
+        jaxgo._dense_engine.cache_clear()
+
+
 class TestUnit:
     def setup_method(self):
         self.cfg = GoConfig(size=5, komi=0.0)

@@ -386,9 +386,8 @@ def test_compile_cache_rule_has_one_home():
     assert jax.config.jax_compilation_cache_dir == cache_dir()
     home = os.path.join("rocalphago_tpu", "runtime", "compilecache.py")
     setters, old_knob = [], []
-    for top in ("rocalphago_tpu", "benchmarks", "scripts", "tests",
-                "docs", "bench.py", "chip_smoke.py",
-                "__graft_entry__.py"):
+    for top in ("rocalphago_tpu", "scripts", "tests", "docs",
+                "chip_smoke.py", "__graft_entry__.py"):
         path = os.path.join(REPO, top)
         files = [path] if os.path.isfile(path) else [
             os.path.join(d, f) for d, _, fs in os.walk(path)

@@ -171,3 +171,25 @@ def test_value_player_picks_legal_move():
     st = pygo.GameState(size=SIZE)
     player = ValuePlayer(value)
     assert player.get_move(st) in st.get_legal_moves(include_eyes=False)
+
+
+def test_warmup_compiles_exactly_the_timed_programs():
+    """run.warmup must leave a subsequent full rep with ZERO segment
+    compiles — the exact-program warmup discipline: a timed rep
+    after it pays no compile."""
+    cfg = GoConfig(size=5)
+    net = CNNPolicy(("board", "ones"), board=5, layers=1,
+                    filters_per_layer=2)
+    # chunk deliberately not a divisor: the remainder segment is its
+    # own compile and warmup must cover it too
+    run = make_selfplay_chunked(
+        cfg, net.feature_list, net.module.apply, net.module.apply,
+        batch=4, max_moves=10, chunk=4, score_on_device=False)
+    seg_s = run.warmup(net.params, net.params)
+    assert seg_s is not None and seg_s > 0
+    n0 = run.segment._cache_size()
+    assert n0 == 2          # chunk-length + remainder programs
+    res = run(net.params, net.params, jax.random.key(1),
+              stop_when_done=True)
+    jax.device_get(res.actions)
+    assert run.segment._cache_size() == n0   # zero compile growth
