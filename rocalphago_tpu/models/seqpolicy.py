@@ -55,14 +55,18 @@ router, softmax and loss):
   buffer that holds EVERY pair a chunk of tokens could send
   (``chunk × top_k`` rows), so no pair is dropped however unbalanced
   the routing; rows past the held pairs are skipped by the product,
-  and every other pass over the buffer's rows — the dispatch gather,
-  SwiGLU's inside between the products, and their mirrors in the
-  backward pass — runs in row blocks and only on the blocks that
-  hold a pair (:func:`_held_blocks`): the work follows what arrives,
-  three blocks of forty on balanced traffic and all forty when every
-  pair lands here, one body run more or fewer times and no second
-  path. What the buffer left out is counted from the buffer
-  (``moe_dropped``), not assumed, and so are the blocks that ran;
+  and every other pass of the layer — the dispatch gather, SwiGLU's
+  inside between the products, the weighted sum of the results by
+  token, and their mirrors in the backward pass — walks the buffer
+  in row blocks and only the blocks that hold a pair
+  (:func:`_held_blocks`); what belongs to a token is added to the
+  token's row from the block that holds the pair, so nothing is
+  ever gathered or laid out a row for every pair a chunk could
+  send. The work follows what arrives, three blocks of forty on
+  balanced traffic and all forty when every pair lands here, one
+  body run more or fewer times and no second path. What the buffer
+  left out is counted from the buffer (``moe_dropped``), not
+  assumed, and so are the blocks that ran;
 * every layer is recomputed in the backward pass (``nn.remat``): what
   a step saves is one ``[B, S, hidden]`` input per layer.
 """
@@ -382,13 +386,18 @@ def _row_block(rows: int) -> int:
     return rows if rows % EXPERT_ROW_BLOCK else EXPERT_ROW_BLOCK
 
 
-def _held_blocks(n_held, bufs: tuple, block_fn):
-    """``bufs`` (zeros ``[R, …]`` each) with the row blocks that hold
-    one of the leading ``n_held`` rows — the rows with a pair —
-    replaced by ``block_fn(at)``, where ``at(a)`` is the block's rows
-    of an ``a [R, …]``. Its rows past ``n_held`` stay zero whatever
-    ``block_fn`` gives them (a select: a product's leavings do not
-    spread).
+def _held_blocks(n_held, rows: int, bufs: tuple, block_fn):
+    """``bufs`` (zeros each) with what the row blocks that hold one
+    of the leading ``n_held`` of a buffer's ``rows`` rows — the rows
+    with a pair — give them. ``block_fn(at)``, where ``at(a)`` is the
+    block's rows of an ``a [R, …]``, returns one entry per buffer:
+    the block's rows of a buffer ``[R, …]``, written in their place;
+    or ``(index, values)`` for a buffer that is not row-major (a
+    token's sum ``[T, D]``, a pair's scalar ``[T·K]``): the block's
+    ``values`` ADDED at its ``index [block]``, which may repeat — a
+    token holding several pairs of one block. A row past ``n_held``
+    writes and adds zero whatever ``block_fn`` gives it (a select: a
+    product's leavings do not spread).
 
     The loop is as long as the blocks that hold a pair, so a block
     past them costs its share of the zero fill and nothing else. It
@@ -396,62 +405,57 @@ def _held_blocks(n_held, bufs: tuple, block_fn):
     What the body reads should be there already: XLA sinks a
     producer that only the loop uses into its body, once a turn, and
     copies a product's output before a loop may write to it (PERF.md,
-    PR 27)."""
-    block = _row_block(bufs[0].shape[0])
+    PR 27) — so every buffer is a fresh zero array."""
+    block = _row_block(rows)
 
     def body(i, bufs):
         start = i * block
-        live = (start + jnp.arange(block) < n_held)[:, None]
-        new = block_fn(lambda a: jax.lax.dynamic_slice_in_dim(
-            a, start, block, 0))
-        return tuple(
-            jax.lax.dynamic_update_slice_in_dim(
-                buf, jnp.where(live, n, 0).astype(buf.dtype), start, 0)
-            for buf, n in zip(bufs, new))
+        live = start + jnp.arange(block) < n_held
+
+        def put(buf, new):
+            index, new = new if isinstance(new, tuple) else (None, new)
+            new = jnp.where(live.reshape((-1,) + (1,) * (new.ndim - 1)),
+                            new, 0).astype(buf.dtype)
+            if index is None:
+                return jax.lax.dynamic_update_slice_in_dim(
+                    buf, new, start, 0)
+            return buf.at[index].add(new)
+
+        return tuple(map(put, bufs, block_fn(
+            lambda a: jax.lax.dynamic_slice_in_dim(a, start, block, 0))))
 
     return jax.lax.fori_loop(0, -(-n_held // block), body, bufs)
 
 
-def _from_rows(rows, inverse, held):
-    """``[T, K, D]``: each pair's row of the buffer ``rows [R, D]``,
-    zero for a pair that was not sent here or whose place in the sort
-    lies past the buffer. Rows that hold no pair are never read: a
-    product may leave anything there."""
-    t, k = held.shape
-    r = rows.shape[0]
-    inside = held & (inverse.reshape(t, k) < r)
-    at = jnp.minimum(inverse, r - 1)
-    return jnp.where(inside[..., None], rows[at].reshape(t, k, -1), 0)
-
-
 @jax.custom_vjp
-def _dispatch(x, order, inverse, held, n_held):
+def _dispatch(x, tok, n_held):
     """Rows of ``x [T, D]`` in pair order, once for each of the two
-    products that read them (one buffer): row ``i`` is the token of
-    sorted pair ``order[i]`` (``order`` may be a leading part of the
-    sort: the buffer's rows), zero past the ``n_held`` rows that hold
-    a pair."""
-    k = held.shape[1]
+    products that read them (one buffer): row ``i`` is token
+    ``tok[i]``, the token of the sorted pair the buffer's row ``i``
+    holds, zero past the ``n_held`` rows that hold a pair. Per block:
+    a gather of the block's tokens."""
     xs, = _held_blocks(
-        n_held, (jnp.zeros((order.shape[0], x.shape[1]), x.dtype),),
-        lambda at: (x[at(order) // k],))
+        n_held, tok.shape[0],
+        (jnp.zeros((tok.shape[0], x.shape[1]), x.dtype),),
+        lambda at: (x[at(tok)],))
     return xs, xs
 
 
-def _dispatch_fwd(x, order, inverse, held, n_held):
-    return (_dispatch(x, order, inverse, held, n_held),
-            (inverse, held, n_held))
+def _dispatch_fwd(x, tok, n_held):
+    # ``x`` for its shape and type alone: nothing of it is kept
+    return _dispatch(x, tok, n_held), (x, tok, n_held)
 
 
 def _dispatch_bwd(res, g):
-    inverse, held, n_held = res
-    # the two products' cotangents, summed where a pair is:
-    # ``_from_rows`` reads no other row
-    both, = _held_blocks(n_held, (jnp.zeros_like(g[0]),),
-                         lambda at: (at(g[0]) + at(g[1]),))
-    # a gather by the inverse order instead of a scatter-add
-    back = _from_rows(both, inverse, held).astype(jnp.float32)
-    return back.sum(axis=1).astype(both.dtype), None, None, None, None
+    """Per block: the two products' cotangents summed in float32 and
+    added to their tokens' rows of a float32 ``[T, D]``; no pass is
+    longer than the rows that hold a pair."""
+    x, tok, n_held = res
+    back, = _held_blocks(
+        n_held, tok.shape[0], (jnp.zeros(x.shape, jnp.float32),),
+        lambda at: ((at(tok), at(g[0]).astype(jnp.float32)
+                     + at(g[1]).astype(jnp.float32)),))
+    return back.astype(x.dtype), None, None
 
 
 _dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
@@ -462,7 +466,7 @@ def _act(g, u, n_held):
     """``silu(g) * u`` on the ``n_held`` rows of the buffer that hold
     a pair, zero on the others whatever the products left there;
     ``g, u [R, F]``."""
-    return _held_blocks(n_held, (jnp.zeros_like(g),),
+    return _held_blocks(n_held, g.shape[0], (jnp.zeros_like(g),),
                         lambda at: (_gated(at(g), at(u)),))[0]
 
 
@@ -473,7 +477,7 @@ def _act_fwd(g, u, n_held):
 def _act_bwd(res, dh):
     g, u, n_held = res
     return *_held_blocks(
-        n_held, (jnp.zeros_like(g), jnp.zeros_like(u)),
+        n_held, g.shape[0], (jnp.zeros_like(g), jnp.zeros_like(u)),
         lambda at: jax.vjp(_gated, at(g), at(u))[1](at(dh))), None
 
 
@@ -481,32 +485,44 @@ _act.defvjp(_act_fwd, _act_bwd)
 
 
 @jax.custom_vjp
-def _combine(y, weight, order, inverse, held, n_held):
-    """The pairs' results ``y [R, D]`` back in token order and summed
-    by their float32 ``weight [T, K]``: ``[T, D]`` float32; a pair
-    that was not sent here adds nothing."""
-    out = _from_rows(y, inverse, held).astype(jnp.float32)
-    return (out * weight[..., None]).sum(axis=1)
+def _combine(y, weight, pairs, tok, n_held):
+    """The pairs' results ``y [R, D]`` summed by token under their
+    float32 ``weight [T, K]``: ``[T, D]`` float32. Row ``i`` of the
+    buffer holds pair ``pairs[i]`` of token ``tok[i]``; a pair that
+    was not sent here, or that the buffer left out, adds nothing.
+    Per block: the block's rows times their pairs' weights, added to
+    their tokens' rows of the sum."""
+    flat = weight.reshape(-1)
+    out, = _held_blocks(
+        n_held, y.shape[0],
+        (jnp.zeros((weight.shape[0], y.shape[1]), jnp.float32),),
+        lambda at: ((at(tok), at(y).astype(jnp.float32)
+                     * flat[at(pairs)][:, None]),))
+    return out
 
 
-def _combine_fwd(y, weight, order, inverse, held, n_held):
-    return (_combine(y, weight, order, inverse, held, n_held),
-            (y, weight, order, inverse, held, n_held))
+def _combine_fwd(y, weight, pairs, tok, n_held):
+    return (_combine(y, weight, pairs, tok, n_held),
+            (y, weight, pairs, tok, n_held))
 
 
 def _combine_bwd(res, d):
-    y, weight, order, inverse, held, n_held = res
-    k, flat = held.shape[1], weight.reshape(-1)
-    # a row's cotangent is its token's, weighted: taken from ``d
-    # [T, D]`` block by block, never laid out ``[T, K, D]``
-    def block_bwd(at):
-        pairs = at(order)
-        return (d[pairs // k] * flat[pairs][:, None],)
+    """Per block, from one gather of the block's tokens' rows of ``d
+    [T, D]``: a row's cotangent is its token's times the pair's
+    weight, and a pair's weight's is its row's dot with its token's —
+    a scalar placed at the pair. Nothing is laid out ``[T, K, D]``."""
+    y, weight, pairs, tok, n_held = res
+    flat = weight.reshape(-1)
 
-    dy, = _held_blocks(n_held, (jnp.zeros_like(y),), block_bwd)
-    out = _from_rows(y, inverse, held).astype(jnp.float32)
-    return (dy, (d[:, None, :] * out).sum(axis=-1),
-            None, None, None, None)
+    def block_bwd(at):
+        at_pairs, rows = at(pairs), d[at(tok)]
+        return (rows * flat[at_pairs][:, None],
+                (at_pairs, (rows * at(y).astype(jnp.float32)).sum(-1)))
+
+    dy, dw = _held_blocks(
+        n_held, y.shape[0],
+        (jnp.zeros_like(y), jnp.zeros_like(flat)), block_bwd)
+    return dy, dw.reshape(weight.shape), None, None, None
 
 
 _combine.defvjp(_combine_fwd, _combine_bwd)
@@ -531,12 +547,14 @@ def held_experts(x, local, weight, w_gate, w_up, w_down,
     a buffer cut below what arrives shows in it.
 
     Each grouped product is one ``ragged_dot`` over the whole buffer
-    and skips the rows past its groups. Everything else that touches
-    the buffer's rows runs in blocks of :data:`EXPERT_ROW_BLOCK` and
-    only on the blocks that hold a pair (:func:`_held_blocks`), so
-    the work follows the pairs that arrived — all of the blocks when
-    every pair lands here, three of forty on balanced traffic — and
-    not the buffer's length."""
+    and skips the rows past its groups. Everything else — tokens'
+    rows into the buffer, SwiGLU's inside, the results summed by
+    token, and each one's backward rule — runs in blocks of
+    :data:`EXPERT_ROW_BLOCK` rows of the buffer and only on the
+    blocks that hold a pair (:func:`_held_blocks`), so the work
+    follows the pairs that arrived — all of the blocks when every
+    pair lands here, three of forty on balanced traffic — and
+    neither the buffer's length nor the ``T·K`` pairs of the chunk."""
     t, k = local.shape
     rows = t * k if rows is None else rows
     e = w_gate.shape[0]
@@ -544,11 +562,11 @@ def held_experts(x, local, weight, w_gate, w_up, w_down,
         held = (local >= 0) & (local < e)
         key = jnp.where(held, local, e).reshape(-1)
         order = jnp.argsort(key, stable=True).astype(jnp.int32)
-        inverse = jnp.zeros_like(order).at[order].set(
-            jnp.arange(t * k, dtype=jnp.int32), unique_indices=True)
         sizes = (key[:, None] == jnp.arange(e)[None, :]).sum(
             axis=0, dtype=jnp.int32)
+        # the buffer's rows: the pair each holds, and its token
         mine = order[:rows]
+        tok = mine // k
         # each expert's pairs that lie inside the buffer
         ends = jnp.minimum(jnp.cumsum(sizes), rows)
         computed = jnp.diff(ends, prepend=0)
@@ -564,13 +582,13 @@ def held_experts(x, local, weight, w_gate, w_up, w_down,
 
     # a rule's backward pass runs under the scope of its call
     with jax.named_scope(scopes.SEQ_EXPERTS_DISPATCH):
-        xs_gate, xs_up = _dispatch(x, mine, inverse, held, n_held)
+        xs_gate, xs_up = _dispatch(x, tok, n_held)
     g, u = product(xs_gate, w_gate), product(xs_up, w_up)
     with jax.named_scope(scopes.SEQ_EXPERTS_ACT):
         h = _act(g, u, n_held)
     y = product(h, w_down)
     with jax.named_scope(scopes.SEQ_EXPERTS_COMBINE):
-        out = _combine(y, weight, mine, inverse, held, n_held)
+        out = _combine(y, weight, mine, tok, n_held)
     dropped = sizes.sum() - computed.sum()
     return out, sizes, dropped, blocks
 

@@ -50,13 +50,14 @@ SEQ_EXPERTS = "seq.experts"
 #: alike): sorting the pairs by held expert, their counts and offsets
 SEQ_EXPERTS_SORT = "seq.experts.sort"
 #: tokens' rows into the buffer, in row blocks that hold a pair;
-#: backward: the sum of the two products' cotangents on those blocks,
-#: then the token-major gather back and its sum over ``top_k``
+#: backward: on those blocks, the two products' cotangents summed and
+#: added to their tokens' rows
 SEQ_EXPERTS_DISPATCH = "seq.experts.dispatch"
 #: between the products, in the same blocks: float32 SwiGLU inside
 SEQ_EXPERTS_ACT = "seq.experts.act"
-#: the token-major gather of the pairs' results and their weighted
-#: sum; backward: the cotangent's rows into the buffer, in blocks
+#: on the same blocks: the pairs' results times their weights, added
+#: to their tokens' rows; backward: the cotangent's rows into the
+#: buffer and each pair's weight's gradient, from one gather a block
 SEQ_EXPERTS_COMBINE = "seq.experts.combine"
 #: the shared expert
 SEQ_SHARED = "seq.shared"

@@ -442,6 +442,165 @@ def test_skipping_blocks_changes_no_row_that_holds_a_pair(
     assert reference.relative_error(out, whole) < 1e-6
 
 
+# The token-major forms that the rules of ``held_experts`` had before
+# they walked the buffer's row blocks (PR 29), kept here as the oracle:
+# every pair's row gathered by the inverse of the sort, ``[T, K, D]``.
+
+def from_rows(rows, inverse, held):
+    t, k = held.shape
+    r = rows.shape[0]
+    inside = held & (inverse.reshape(t, k) < r)
+    at = jnp.minimum(inverse, r - 1)
+    return jnp.where(inside[..., None], rows[at].reshape(t, k, -1), 0)
+
+
+def token_major_combine(y, weight, inverse, held):
+    return (from_rows(y, inverse, held) * weight[..., None]).sum(axis=1)
+
+
+def token_major_dispatch_bwd(g, inverse, held, n_held):
+    live = (jnp.arange(g[0].shape[0]) < n_held)[:, None]
+    return from_rows(jnp.where(live, g[0] + g[1], 0), inverse,
+                     held).sum(axis=1)
+
+
+def sorted_pairs(local, rows):
+    """``held_experts``' sort of a chunk's pairs by held expert (4
+    held): the buffer's pairs and tokens, the inverse of the sort,
+    which pairs are held, and the rows that hold one."""
+    t, k = local.shape
+    held = (local >= 0) & (local < 4)
+    order = jnp.argsort(jnp.where(held, local, 4).reshape(-1),
+                        stable=True).astype(jnp.int32)
+    inverse = jnp.zeros_like(order).at[order].set(
+        jnp.arange(t * k, dtype=jnp.int32))
+    mine = order[:rows]
+    return mine, mine // k, inverse, held, jnp.minimum(held.sum(), rows)
+
+
+#: 16 tokens' three choices among 4 held experts (others: 7)
+ROUTINGS = {
+    # chunk_inputs' own: 30 of 48 pairs held, sizes 12, 9, 6, 3
+    "balanced": [0] * 12 + [1] * 9 + [2] * 6 + [3] * 3 + [7] * 18,
+    # every pair lands here, every token on the same three experts
+    "every token on one expert": [2, 0, 1] * 16,
+    # a token's pairs lie rows apart in the sort and, with three
+    # tokens all told, several times inside any block
+    "a token holds several": ([0, 1, 2] * 3 + [1, 1, 1, 7, 3, 3]
+                              + [7] * 33),
+    "no pair held": [7] * 48,
+}
+#: (routing, rows of the buffer, height of its row blocks, NaN past
+#: the rows that hold a pair)
+PASSES = (
+    [(name, 48, block, False) for name in ROUTINGS
+     for block in (4, 16, 2048)]
+    + [("balanced", rows, block, False) for rows in (24, 16, 8)
+       for block in (4, 16)]
+    + [("balanced", 48, block, True) for block in (4, 16, 2048)]
+    + [("a token holds several", 48, 4, True), ("no pair held", 48, 16,
+                                               True)])
+
+
+@pytest.fixture(params=PASSES, ids=[
+    f"{name}, {rows} rows in blocks of {block}"
+    + (", NaN past the pairs" if spoiled else "")
+    for name, rows, block, spoiled in PASSES])
+def a_pass(request, monkeypatch):
+    """A chunk's sort and a buffer ``[rows, 32]`` of it, twice."""
+    name, rows, block, spoiled = request.param
+    monkeypatch.setattr(seqpolicy, "EXPERT_ROW_BLOCK", block)
+    local = jnp.array(ROUTINGS[name]).reshape(16, 3)
+    mine, tok, inverse, held, n_held = sorted_pairs(local, rows)
+    keys = jax.random.split(jax.random.key(17), 5)
+    bufs = [jax.random.normal(k, (rows, 32)) for k in keys[:2]]
+    if spoiled:
+        past = (jnp.arange(rows) >= n_held)[:, None]
+        bufs = [jnp.where(past, jnp.nan, b) for b in bufs]
+    weight = jax.random.uniform(keys[2], (16, 3))
+    probe = jax.random.normal(keys[3], (16, 32))
+    x = jax.random.normal(keys[4], (16, 32))
+    return (mine, tok, inverse, held, n_held), bufs, weight, probe, x
+
+
+def test_combine_by_row_blocks_is_the_token_major_sum(a_pass):
+    """The weighted sum and both its gradients — the rows' and the
+    weights' — against a gather of every pair's row."""
+    (mine, tok, inverse, held, n_held), (y, _), weight, probe, _ = a_pass
+
+    def rows_major(y, weight):
+        return seqpolicy._combine(y, weight, mine, tok, n_held)
+
+    def token_major(y, weight):
+        return token_major_combine(y, weight, inverse, held)
+
+    got, want = rows_major(y, weight), token_major(y, weight)
+    assert got.dtype == jnp.float32 and got.shape == (16, 32)
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+    g, w = (jax.grad(lambda *a: (f(*a) * probe).sum(), (0, 1))(y, weight)
+            for f in (rows_major, token_major))
+    for a, b in zip(g, w):
+        assert np.isfinite(np.asarray(a)).all()
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
+    # a row that holds no pair is owed nothing
+    assert not np.asarray(g[0])[int(n_held):].any()
+
+
+def test_dispatch_backward_by_row_blocks_is_the_token_major_sum(a_pass):
+    """The buffer's rows are their tokens', zero past the pairs, and
+    the two products' cotangents come back summed by token — against
+    the gather of every pair's row and its sum over ``top_k``."""
+    (mine, tok, inverse, held, n_held), g, _, probe, x = a_pass
+    (xs, again), back = jax.vjp(
+        lambda x: seqpolicy._dispatch(x, tok, n_held), x)
+    live = (jnp.arange(mine.shape[0]) < n_held)[:, None]
+    np.testing.assert_array_equal(xs, jnp.where(live, x[tok], 0))
+    np.testing.assert_array_equal(xs, again)
+    got, = back(tuple(g))
+    assert got.dtype == x.dtype and np.isfinite(np.asarray(got)).all()
+    np.testing.assert_allclose(
+        got, token_major_dispatch_bwd(g, inverse, held, n_held),
+        rtol=1e-5, atol=1e-6)
+    # and through ``jax.grad``, both outputs read
+    dx = jax.grad(lambda x: sum(
+        (o[:n_held] * probe[0]).sum()
+        for o in seqpolicy._dispatch(x, tok, n_held)))(x)
+    want = jnp.zeros_like(x).at[tok[:n_held]].add(2 * probe[0])
+    np.testing.assert_allclose(dx, want, rtol=1e-5, atol=1e-6)
+
+
+def test_no_pass_of_the_expert_layer_is_token_major(monkeypatch):
+    """Forward and backward of a chunk hold no ``[T, K, D]`` array
+    and gather no ``T·K`` rows of width ``D``: what goes through the
+    buffer goes by row blocks (48 rows in blocks of 16 here)."""
+    monkeypatch.setattr(seqpolicy, "EXPERT_ROW_BLOCK", 16)
+    x, local, weight, mats = chunk_inputs()
+
+    def share(x, weight, *mats):
+        return seqpolicy.held_experts(x, local, weight, *mats)[0].sum()
+
+    jaxpr = jax.make_jaxpr(jax.value_and_grad(share, (0, 1, 2, 3, 4)))(
+        x, weight, *mats)
+
+    def eqns(jaxpr):
+        for eqn in jaxpr.eqns:
+            yield eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from eqns(sub)
+
+    found = list(eqns(jaxpr.jaxpr))
+    shapes = {v.aval.shape for eqn in found for v in eqn.outvars}
+    assert (48, 32) in shapes           # the buffer itself
+    assert (16, 3, 32) not in shapes
+    gathers = [eqn.outvars[0].aval.shape for eqn in found
+               if eqn.primitive.name == "gather"]
+    assert (16, 32) in gathers          # a block of the buffer's rows
+    assert not [s for s in gathers if s[0] == 48 and s[-1] == 32]
+    loops = [eqn for eqn in found if eqn.primitive.name == "while"]
+    assert len(loops) >= 6              # every rule's, and no other
+
+
 def test_the_routers_choices_are_kept_for_who_asks(net, batch):
     """``chipbench`` compares the program's top-k choices with the
     reference's: a caller that makes ``intermediates`` mutable gets
