@@ -24,6 +24,33 @@ The decoder is the published ``laguna`` block (poolside Laguna-S-2.1,
   (``norm_topk_prob``), scaled (``moe_routed_scaling_factor``), plus
   one shared expert.
 
+A second published block is read from its own keys beside it —
+``xing4_0`` (XingChen-AGI Xing4.0-29B-A4B, ``config.json``; the
+DeepSeek-V3 family's names) — and shares every line it can with the
+first. A spec chooses by the keys it carries, nothing else does:
+
+* ``kv_lora_rank``: **latent attention** (:class:`LatentAttention`):
+  low-rank query and key/value paths with their own norms, per head a
+  non-rotary part of ``qk_nope_head_dim`` and a rotary part of
+  ``qk_rope_head_dim`` that every head's key shares, values of
+  ``v_head_dim`` — query/key heads of 192 and value heads of 128 as
+  published, run as so many heads with a key each (training: no
+  absorbed form);
+* ``scoring_func: sigmoid``: the router scores by a sigmoid, chooses
+  by the score PLUS a bias that carries no gradient
+  (``topk_method: noaux_tc``) and weighs by the scores without it;
+* ``hc_mult``: **hyper-connections** in place of ``x + f(x)``
+  (:class:`HyperConnection`; Zhu et al., arXiv:2409.19606, with the
+  manifold constraint of arXiv:2512.24880): the residual state is
+  ``hc_mult`` streams, each sublayer reads a learned per-token mix
+  of them and writes back through a doubly-stochastic matrix made by
+  ``hc_sinkhorn_iters`` Sinkhorn iterations;
+* ``num_nextn_predict_layers: 1``: a **multi-token-prediction
+  module** (DeepSeek-V3 report §2.2) — one more block on the trunk's
+  output joined with the next id's embedding, predicting the id after
+  next through the shared embedding and head — whenever the next ids
+  are given (training; a forward pass without them has no use for it).
+
 **The held share.** A spec also says what part of the model lives
 here: ``layers_held`` leading layers, ``vocab_held`` leading rows of
 the vocabulary (embedding, head, logits and loss are over the slice),
@@ -106,11 +133,32 @@ class Rope(NamedTuple):
     attention_factor: float = 1.0
 
 
+class Latent(NamedTuple):
+    """Latent attention's sizes, as the config names them."""
+
+    q_rank: int             # q_lora_rank
+    kv_rank: int            # kv_lora_rank
+    nope: int               # qk_nope_head_dim
+    rope: int               # qk_rope_head_dim
+    value: int              # v_head_dim
+    scale: float            # of the scores: 1/sqrt(nope + rope) x mscale^2
+
+
+class Hyper(NamedTuple):
+    """The hyper-connections' constants (``hc_*``, ``mhc_*``)."""
+
+    streams: int            # hc_mult
+    iters: int              # hc_sinkhorn_iters
+    eps: float              # hc_eps: Sinkhorn's denominator guard
+    clamp: tuple            # (mhc_h_res_clamp_min, _max)
+
+
 class LayerSpec(NamedTuple):
     heads: int              # query heads of this layer
     window: int             # 0 = full attention
     rope: Rope
     sparse: bool            # routed experts (else the dense MLP)
+    latent: Latent | None = None    # latent attention (else gated GQA)
 
 
 def rope_inv_freq(rope: Rope) -> np.ndarray:
@@ -174,15 +222,20 @@ def kernel_platform() -> str:
     return jax.default_backend()
 
 
-def use_kernel(s_len: int, d: int) -> bool:
-    return (kernel_platform() == "tpu" and d % 128 == 0
-            and s_len % KERNEL_BLOCK == 0)
+def use_kernel(s_len: int, *head_dims: int) -> bool:
+    """Whether the kernel takes these heads: whole 128-lane tiles, or
+    whole tiles and a half (latent attention's 192: Mosaic compiles
+    it for the v5e and the chip agrees with the reference, PERF.md,
+    PR 30)."""
+    return (kernel_platform() == "tpu" and s_len % KERNEL_BLOCK == 0
+            and all(d >= 128 and d % 64 == 0 for d in head_dims))
 
 
 def kernel_attention(q, k, v, window: int, interpret: bool = False):
     """Causal grouped-query attention by the splash kernel:
-    ``q [B, S, H, d]``, ``k, v [B, S, G, d]`` → ``[B, S, H, d]``.
-    ``window`` 0 is full attention, else ``i − j < window``."""
+    ``q [B, S, H, d]``, ``k [B, S, G, d]``, ``v [B, S, G, dv]`` →
+    ``[B, S, H, dv]``. ``window`` 0 is full attention, else
+    ``i − j < window``."""
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as splash,
         splash_attention_mask as masks,
@@ -256,7 +309,7 @@ def _group_window(q, k, v, window: int):
     qb = q.reshape(r, nb, window, d)
 
     def with_previous(x):
-        xb = x.reshape(nb, window, d)
+        xb = x.reshape(nb, window, x.shape[-1])
         prev = jnp.concatenate([jnp.zeros_like(xb[:1]), xb[:-1]])
         return jnp.concatenate([prev, xb], axis=1)     # [nb, 2w, d]
 
@@ -272,21 +325,21 @@ def _group_window(q, k, v, window: int):
     real = (jnp.arange(nb)[:, None, None] > 0) | (kk >= window)[None]
     out = _softmax_pv(s, (band[None] & real)[None], v2,
                       "rnqk,nkd->rnqd")
-    return out.reshape(r, s_len, d)
+    return out.reshape(r, s_len, v.shape[-1])
 
 
 def grouped_attention(q, k, v, window: int):
     """Causal grouped-query attention without an ``S × S`` array, in
-    plain XLA: ``q [B, S, H, d]``, ``k, v [B, S, G, d]`` →
-    ``[B, S, H, d]``. ``window`` 0 (or one that covers the sequence)
-    is full attention."""
+    plain XLA: ``q [B, S, H, d]``, ``k [B, S, G, d]``, ``v [B, S, G,
+    dv]`` → ``[B, S, H, dv]``. ``window`` 0 (or one that covers the
+    sequence) is full attention."""
     b, s_len, h, d = q.shape
-    g = k.shape[2]
+    g, dv = k.shape[2], v.shape[3]
     r = h // g
     qg = q.reshape(b, s_len, g, r, d).transpose(0, 2, 3, 1, 4)
     qg = qg.reshape(b * g, r, s_len, d)
     kg = k.transpose(0, 2, 1, 3).reshape(b * g, s_len, d)
-    vg = v.transpose(0, 2, 1, 3).reshape(b * g, s_len, d)
+    vg = v.transpose(0, 2, 1, 3).reshape(b * g, s_len, dv)
     if window and window < s_len:
         one = functools.partial(_group_window, window=window)
     else:
@@ -294,8 +347,15 @@ def grouped_attention(q, k, v, window: int):
     # a group's scores are recomputed in the backward pass, not kept
     # for every group at once
     out = jax.lax.map(jax.checkpoint(lambda a: one(*a)), (qg, kg, vg))
-    out = out.reshape(b, g, r, s_len, d).transpose(0, 3, 1, 2, 4)
-    return out.reshape(b, s_len, h, d)
+    out = out.reshape(b, g, r, s_len, dv).transpose(0, 3, 1, 2, 4)
+    return out.reshape(b, s_len, h, dv)
+
+
+def _attention(q, k, v, window: int):
+    """The kernel where its tiles fit, else the XLA form."""
+    if use_kernel(q.shape[1], q.shape[3], v.shape[3]):
+        return kernel_attention(q, k, v, window)
+    return grouped_attention(q, k, v, window)
 
 
 # -------------------------------------------------------------- modules
@@ -362,13 +422,176 @@ class GatedAttention(nn.Module):
                        1.0 / math.sqrt(hd))
         k = apply_rope(k.reshape(b, s_len, g, hd), spec.rope)
         v = v.reshape(b, s_len, g, hd)
-        if use_kernel(s_len, hd):
-            a = kernel_attention(q, k, v, spec.window)
-        else:
-            a = grouped_attention(q, k, v, spec.window)
+        a = _attention(q, k, v, spec.window)
         a = (a * gate[..., None]).astype(self.dtype)
         return jnp.dot(a.reshape(b, s_len, h * hd),
                        _weight(self, "o_proj", (h * hd, d), self.dtype))
+
+
+class LatentAttention(nn.Module):
+    """Multi-head latent attention as it trains: queries and
+    keys/values through low-rank paths with a norm each, per head a
+    non-rotary part and a rotary part whose key all heads share, so
+    many heads with a key and a value each (``spec.latent``)."""
+
+    spec: LayerSpec
+    eps: float
+    dtype: jnp.dtype = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        spec, lat = self.spec, self.spec.latent
+        b, s_len, d = x.shape
+        h, qk = spec.heads, lat.nope + lat.rope
+        c_q = RMSNorm(self.eps, name="q_a_norm")(jnp.dot(
+            x, _weight(self, "q_a_proj", (d, lat.q_rank), self.dtype)))
+        q = jnp.dot(
+            c_q.astype(self.dtype),
+            _weight(self, "q_b_proj", (lat.q_rank, h * qk), self.dtype)
+        ).reshape(b, s_len, h, qk)
+        kv_a = jnp.dot(x, _weight(
+            self, "kv_a_proj", (d, lat.kv_rank + lat.rope), self.dtype))
+        c_kv = RMSNorm(self.eps, name="kv_a_norm")(
+            kv_a[..., :lat.kv_rank])
+        kv = jnp.dot(
+            c_kv.astype(self.dtype),
+            _weight(self, "kv_b_proj",
+                    (lat.kv_rank, h * (lat.nope + lat.value)),
+                    self.dtype)
+        ).reshape(b, s_len, h, lat.nope + lat.value)
+        # the scores' scale goes on the queries, in float32
+        q_nope = (q[..., :lat.nope].astype(jnp.float32)
+                  * lat.scale).astype(self.dtype)
+        q_pe = apply_rope(q[..., lat.nope:], spec.rope, lat.scale)
+        k_pe = apply_rope(kv_a[:, :, None, lat.kv_rank:], spec.rope)
+        q = jnp.concatenate([q_nope, q_pe], axis=-1)
+        k = jnp.concatenate(
+            [kv[..., :lat.nope],
+             jnp.broadcast_to(k_pe, (b, s_len, h, lat.rope))], axis=-1)
+        a = _attention(q, k, kv[..., lat.nope:], spec.window)
+        return jnp.dot(
+            a.astype(self.dtype).reshape(b, s_len, h * lat.value),
+            _weight(self, "o_proj", (h * lat.value, d), self.dtype))
+
+
+# ----------------------------------------------------- hyper-connections
+#
+# The residual state is ``n`` streams side by side, ``[B, S, n·D]``
+# (stream ``i`` the columns ``i·D .. (i+1)·D``: whole lane tiles, and
+# ``vec(X)`` as it lies). A sublayer F becomes
+#
+#     u = H_pre X;  y = F(RMSNorm(u));  X <- H_res X + H_post^T y
+#
+# with per-token coefficients ``H_pre, H_post [n]`` and ``H_res
+# [n, n]``. The coefficients live with the TOKENS ON THE LANE AXIS
+# (``[n, B·S]``, ``[n, n, B·S]``): a ``[T, 4, 4]`` array would pad
+# every token's sixteen numbers to a tile.
+
+def sinkhorn(m: jax.Array, iters: int, eps: float) -> jax.Array:
+    """``exp(m)`` ``[n, n, …]`` made doubly stochastic: ``iters``
+    times its rows (sums over axis 1), then its columns (over axis
+    0), each divided by its sum plus ``eps``. Differentiated as
+    written, through every iteration (a ``scan``: one body in the
+    program and one in its transpose, not ``iters`` copies of each
+    in every sublayer's forward, recomputation and backward)."""
+    def once(m, _):
+        m = m / (m.sum(axis=1, keepdims=True) + eps)
+        return m / (m.sum(axis=0, keepdims=True) + eps), None
+
+    return jax.lax.scan(once, jnp.exp(m), None, length=iters)[0]
+
+
+def _streams(x: jax.Array, n: int) -> list:
+    d = x.shape[-1] // n
+    return [x[..., i * d:(i + 1) * d].astype(jnp.float32)
+            for i in range(n)]
+
+
+def _per_token(c: jax.Array, like: jax.Array) -> jax.Array:
+    """A coefficient ``[B·S]`` as a column beside ``like [B, S, D]``."""
+    return c.reshape(like.shape[:-1] + (1,))
+
+
+def mix_in(x: jax.Array, pre: jax.Array) -> jax.Array:
+    """``H_pre X``: the streams ``x [B, S, n·D]`` mixed into one
+    float32 ``[B, S, D]`` under ``pre [n, B·S]``."""
+    parts = _streams(x, pre.shape[0])
+    return sum(_per_token(pre[i], p) * p for i, p in enumerate(parts))
+
+
+def mix_out(x: jax.Array, y: jax.Array, res: jax.Array,
+            post: jax.Array) -> jax.Array:
+    """``H_res X + H_post^T y``: ``x [B, S, n·D]``, the sublayer's
+    ``y [B, S, D]``, ``res [n, n, B·S]``, ``post [n, B·S]`` → the
+    streams, in ``x``'s type (the sums in float32)."""
+    n = post.shape[0]
+    parts, yf = _streams(x, n), y.astype(jnp.float32)
+    return jnp.concatenate([
+        (sum(_per_token(res[i, j], p) * p for j, p in enumerate(parts))
+         + _per_token(post[i], yf) * yf).astype(x.dtype)
+        for i in range(n)], axis=-1)
+
+
+def _logit(p: float) -> float:
+    return math.log(p / (1.0 - p))
+
+
+#: ``b_res`` off the diagonal at the start (0 on it): ``H_res`` starts
+#: near the identity, 0.95 on the diagonal
+HYPER_RES_OFF_DIAGONAL = -4.0
+
+
+class HyperConnection(nn.Module):
+    """One sublayer's coefficients from the streams ``[B, S, n·D]``:
+    ``pre [n, T]``, ``post [n, T]`` and ``res [n, n, T]`` (``T =
+    B·S``), all float32. ``x' = RMSNorm(vec(X))`` over all ``n·D``
+    with a learned scale; ``H~ = alpha · (x' phi) + b`` for each of
+    the three; ``pre = sigmoid``, ``post = 2 sigmoid``, ``res =
+    Sinkhorn(clip(·))``.
+
+    The three products are one: the norm's scale is folded into the
+    ``phi``s' rows and its statistic multiplies the ``[T, 2n + n²]``
+    result, so the normed copy of the streams is never laid out."""
+
+    hyper: Hyper
+    eps: float
+
+    @nn.compact
+    def __call__(self, x: jax.Array):
+        hyper, n = self.hyper, self.hyper.streams
+        nd = x.shape[-1]
+        normal = nn.initializers.normal(0.02)
+        with jax.named_scope(scopes.SEQ_MHC_COEFF):
+            scale = self.param("norm", nn.initializers.ones, (nd,),
+                               jnp.float32)
+            phi = jnp.concatenate([
+                self.param("phi_pre", normal, (nd, n), jnp.float32),
+                self.param("phi_post", normal, (nd, n), jnp.float32),
+                self.param("phi_res", normal, (nd, n * n), jnp.float32),
+            ], axis=1) * scale[:, None]
+            alpha = [self.param(f"alpha_{k}", nn.initializers.constant(
+                0.01), (), jnp.float32) for k in ("pre", "post", "res")]
+            b_pre = self.param("b_pre", nn.initializers.constant(
+                _logit(1.0 / n) if n > 1 else 0.0), (n,), jnp.float32)
+            b_post = self.param("b_post", nn.initializers.zeros, (n,),
+                                jnp.float32)
+            b_res = self.param(
+                "b_res", lambda *_: HYPER_RES_OFF_DIAGONAL
+                * (1.0 - jnp.eye(n, dtype=jnp.float32)))
+            flat = x.reshape(-1, nd).astype(jnp.float32)
+            var = jnp.mean(flat * flat, axis=-1)
+            raw = (jnp.dot(flat, phi,
+                           precision=jax.lax.Precision.HIGHEST)
+                   * jax.lax.rsqrt(var + self.eps)[:, None]).T
+            pre = jax.nn.sigmoid(alpha[0] * raw[:n] + b_pre[:, None])
+            post = 2.0 * jax.nn.sigmoid(
+                alpha[1] * raw[n:2 * n] + b_post[:, None])
+            res = (alpha[2] * raw[2 * n:].reshape(n, n, -1)
+                   + b_res[:, :, None])
+        with jax.named_scope(scopes.SEQ_MHC_SINKHORN):
+            res = sinkhorn(jnp.clip(res, *hyper.clamp), hyper.iters,
+                           hyper.eps)
+        return pre, post, res
 
 
 #: tokens the expert layer takes at a time: its buffers are this many
@@ -593,9 +816,44 @@ def held_experts(x, local, weight, w_gate, w_up, w_down,
     return out, sizes, dropped, blocks
 
 
+#: spread of the seeded selection bias: two steps of the published
+#: update rule (±0.001 a step, DeepSeek-V3 report §4.2). Non-zero, so
+#: that selection and weighting can be told apart; small, because the
+#: top 4 of 64 is a tail: a spread of 0.02 alone swings the load of
+#: eight held experts by 4.6 % (std) with the seed (PERF.md, PR 30)
+ROUTER_BIAS_STD = 0.002
+#: the published rule's step (the same report; the config has no key
+#: for it)
+ROUTER_BIAS_RATE = 0.001
+
+
+def bias_step(load: jax.Array) -> jax.Array:
+    """The selection bias's move after a step, by the published rule:
+    up by ``ROUTER_BIAS_RATE`` for an expert that took fewer of the
+    step's tokens than the mean, down for one that took more. ``load
+    [E]`` counts the choices of the tokens HERE over ALL experts (the
+    router is whole on every chip); no gradient is involved."""
+    load = load.astype(jnp.float32)
+    return ROUTER_BIAS_RATE * jnp.sign(load.mean() - load)
+
+
 class SparseFFN(nn.Module):
     """Router over all ``num_experts``, the held experts' part of the
-    routed result, and the shared expert."""
+    routed result, and the shared expert.
+
+    ``scoring`` ``softmax``: the top ``top_k`` of the softmax, by and
+    with its probabilities. ``sigmoid``: scores ``s = sigmoid(x W_r)``;
+    the choice is the top ``top_k`` of ``s + b`` and the weights are
+    the chosen ``s`` — the bias steers the choice alone and carries
+    no gradient. ``b`` (``router_bias``) is a leaf of the parameter
+    tree under a ``stop_gradient``, not a collection of its own: the
+    model JSON, the checkpoints and the trainer's state carry one
+    tree, and the optimizer's update of a zero gradient leaves the
+    leaf as it is. What moves it is the published rule
+    (:func:`bias_step`): the layer returns the move with its counts
+    (``bias_step``) and the train step adds it after the optimizer's
+    update — without it the held experts' load drifts with the
+    router's first steps, and a step's time with the load."""
 
     num_experts: int
     top_k: int
@@ -605,6 +863,7 @@ class SparseFFN(nn.Module):
     expert_offset: int
     norm_topk: bool
     routed_scale: float
+    scoring: str = "softmax"
     dtype: jnp.dtype = jnp.bfloat16
 
     @nn.compact
@@ -617,9 +876,23 @@ class SparseFFN(nn.Module):
         with jax.named_scope(scopes.SEQ_ROUTER):
             w_r = self.param("router", nn.initializers.normal(0.02),
                              (d, self.num_experts), jnp.float32)
-            p = jax.nn.softmax(jnp.dot(
-                flat, w_r, precision=jax.lax.Precision.HIGHEST))
-            weight, chosen = jax.lax.top_k(p, self.top_k)
+            logits = jnp.dot(flat, w_r,
+                             precision=jax.lax.Precision.HIGHEST)
+            if self.scoring == "sigmoid":
+                bias = self.param("router_bias",
+                                  nn.initializers.normal(ROUTER_BIAS_STD),
+                                  (self.num_experts,), jnp.float32)
+                p = jax.nn.sigmoid(logits)
+                _, chosen = jax.lax.top_k(
+                    p + jax.lax.stop_gradient(bias), self.top_k)
+                weight = jnp.take_along_axis(p, chosen, axis=-1)
+                moves = {"bias_step": bias_step((
+                    chosen[..., None] == jnp.arange(self.num_experts)
+                ).sum(axis=(0, 1)))}
+            else:
+                p = jax.nn.softmax(logits)
+                weight, chosen = jax.lax.top_k(p, self.top_k)
+                moves = {}
             if self.norm_topk:
                 weight = weight / weight.sum(axis=-1, keepdims=True)
             local = chosen - self.expert_offset
@@ -654,11 +927,15 @@ class SparseFFN(nn.Module):
                  "moe_dropped": dropped.sum(),
                  "moe_load_max": sizes.max(),
                  "moe_row_blocks_run": blocks[0],
-                 "moe_row_blocks": blocks[1]}
+                 "moe_row_blocks": blocks[1], **moves}
         return out.reshape(b, s_len, d), stats
 
 
 class DecoderLayer(nn.Module):
+    """An attention sublayer and an FFN sublayer, each ``x + F(norm
+    (x))`` — or, with ``hyper``, each a hyper-connected sublayer over
+    the streams ``[B, S, n·D]`` with coefficients of its own."""
+
     spec: LayerSpec
     kv_heads: int
     head_dim: int
@@ -666,33 +943,72 @@ class DecoderLayer(nn.Module):
     ffn: tuple              # SparseFFN's fields, as sorted items
     eps: float
     dtype: jnp.dtype = jnp.bfloat16
+    hyper: Hyper | None = None
+
+    def _mixed(self, name: str, x: jax.Array, f):
+        """A hyper-connected sublayer: ``f`` (its input → its output
+        and counts) on the streams' mix, written back to them."""
+        pre, post, res = HyperConnection(self.hyper, self.eps,
+                                         name=name)(x)
+        with jax.named_scope(scopes.SEQ_MHC_MIX):
+            u = mix_in(x, pre)
+        y, stats = f(u)
+        with jax.named_scope(scopes.SEQ_MHC_MIX):
+            return mix_out(x, y, res, post), stats
 
     @nn.compact
     def __call__(self, x: jax.Array):
         spec = self.spec
-        with (jax.named_scope(scopes.SEQ_ATTN_WINDOW) if spec.window
-              else jax.named_scope(scopes.SEQ_ATTN_FULL)):
-            n = RMSNorm(self.eps, name="input_norm")(x)
-            h = x + GatedAttention(
-                spec, self.kv_heads, self.head_dim, self.dtype,
-                name="attn")(n.astype(self.dtype))
-        norm = RMSNorm(self.eps, name="post_attn_norm")
-        if spec.sparse:
-            with jax.named_scope(scopes.SEQ_ROUTER):
-                n = norm(h)
-            f, stats = SparseFFN(dtype=self.dtype, name="ffn",
+
+        def attn_scope():
+            return (jax.named_scope(scopes.SEQ_ATTN_MLA) if spec.latent
+                    else jax.named_scope(scopes.SEQ_ATTN_WINDOW)
+                    if spec.window
+                    else jax.named_scope(scopes.SEQ_ATTN_FULL))
+
+        def attention(x):
+            n = RMSNorm(self.eps, name="input_norm")(x).astype(
+                self.dtype)
+            if spec.latent:
+                return LatentAttention(spec, self.eps, self.dtype,
+                                       name="attn")(n)
+            return GatedAttention(spec, self.kv_heads, self.head_dim,
+                                  self.dtype, name="attn")(n)
+
+        def ffn(h):
+            norm = RMSNorm(self.eps, name="post_attn_norm")
+            if spec.sparse:
+                with jax.named_scope(scopes.SEQ_ROUTER):
+                    n = norm(h)
+                return SparseFFN(dtype=self.dtype, name="ffn",
                                  **dict(self.ffn))(n)
-        else:
             with jax.named_scope(scopes.SEQ_DENSE_FFN):
-                f = SwiGLU(self.dense_width, self.dtype, name="ffn")(
-                    norm(h).astype(self.dtype))
-            stats = None
-        return h + f, stats
+                return SwiGLU(self.dense_width, self.dtype, name="ffn")(
+                    norm(h).astype(self.dtype)), None
+
+        if self.hyper is None:
+            with attn_scope():
+                h = x + attention(x)
+            f, stats = ffn(h)
+            return h + f, stats
+
+        def scoped_attention(u):
+            with attn_scope():
+                return attention(u), None
+
+        h, _ = self._mixed("attn_hc", x, scoped_attention)
+        return self._mixed("ffn_hc", h, ffn)
 
 
 class SeqPolicyNet(nn.Module):
     """ids ``[B, S]`` → (float32 logits ``[B, S, vocab_held]``, the
-    step's routing counts)."""
+    step's routing counts). Beside the counts, where a router has a
+    selection bias: ``no_grad_updates``, the biases' moves by the
+    published rule as a part of the parameter tree, for the train
+    step to add. With a multi-token-prediction module
+    (``mtp``) and the next ids ``[B, S]`` given, the counts come with
+    ``mtp_logits`` ``[B, S, vocab_held]``: at each position the
+    logits of the id AFTER the next."""
 
     layers: tuple           # of LayerSpec
     hidden: int
@@ -703,32 +1019,85 @@ class SeqPolicyNet(nn.Module):
     ffn: tuple              # SparseFFN's fields, as sorted items
     eps: float = 1e-6
     dtype: jnp.dtype = jnp.bfloat16
+    hyper: Hyper | None = None
+    mtp: int = 0            # multi-token-prediction modules: 0 or 1
 
     @nn.compact
-    def __call__(self, ids: jax.Array):
+    def __call__(self, ids: jax.Array, next_ids: jax.Array | None = None):
         with jax.named_scope(scopes.SEQ_EMBED):
             table = self.param("embed", nn.initializers.normal(0.02),
                                (self.vocab_held, self.hidden),
                                jnp.float32)
             x = jnp.take(table, ids, axis=0).astype(self.dtype)
         totals = dict.fromkeys(MOE_STATS, jnp.int32(0))
+        moves = {}
         layer = nn.remat(DecoderLayer)
-        for i, spec in enumerate(self.layers):
+
+        def block(spec, name, x):
+            """One layer on the state, its counts merged."""
             x, stats = layer(
                 spec, self.kv_heads, self.head_dim, self.dense_width,
-                self.ffn, self.eps, self.dtype, name=f"layer{i}")(x)
+                self.ffn, self.eps, self.dtype, self.hyper,
+                name=name)(x)
             if stats is not None:
-                for name in MOE_STATS:
-                    merge = (jnp.maximum if name == "moe_load_max"
+                for key in MOE_STATS:
+                    merge = (jnp.maximum if key == "moe_load_max"
                              else jnp.add)
-                    totals[name] = merge(totals[name], stats[name])
+                    totals[key] = merge(totals[key], stats[key])
+                if "bias_step" in stats:
+                    moves[name] = {"ffn": {
+                        "router_bias": stats["bias_step"]}}
+            return x
+
+        def extras(**more):
+            if moves:
+                more["no_grad_updates"] = {"params": moves}
+            return dict(totals, **more)
+
+        def enter(x):
+            """The streams start as copies of ``x``."""
+            if not self.hyper:
+                return x
+            return jnp.tile(x, (1, 1, self.hyper.streams))
+
+        def leave(x):
+            """And end as their sum."""
+            if not self.hyper:
+                return x
+            return sum(_streams(x, self.hyper.streams)).astype(
+                self.dtype)
+
+        x = enter(x)
+        for i, spec in enumerate(self.layers):
+            x = block(spec, f"layer{i}", x)
+        with jax.named_scope(scopes.SEQ_MHC_MIX):
+            x = leave(x)
         with jax.named_scope(scopes.SEQ_HEAD):
             n = RMSNorm(self.eps, name="norm")(x).astype(self.dtype)
-            logits = jnp.dot(
-                n, _weight(self, "head", (self.hidden, self.vocab_held),
-                           self.dtype),
-                preferred_element_type=jnp.float32)
-        return logits, totals
+            head_w = _weight(self, "head", (self.hidden, self.vocab_held),
+                             self.dtype)
+            logits = jnp.dot(n, head_w,
+                             preferred_element_type=jnp.float32)
+        if not self.mtp or next_ids is None:
+            return logits, extras()
+        # the module's own parts under its scope; its block under the
+        # layers' (it is the last layer's kind)
+        with jax.named_scope(scopes.SEQ_MTP):
+            ahead = jnp.take(table, next_ids, axis=0).astype(self.dtype)
+            joined = jnp.concatenate([
+                RMSNorm(self.eps, name="mtp_hnorm")(x),
+                RMSNorm(self.eps, name="mtp_enorm")(ahead),
+            ], axis=-1).astype(self.dtype)
+            x = enter(jnp.dot(joined, _weight(
+                self, "mtp_eh_proj", (2 * self.hidden, self.hidden),
+                self.dtype)))
+        x = block(self.layers[-1], "mtp_layer", x)
+        with jax.named_scope(scopes.SEQ_MTP):
+            n = RMSNorm(self.eps, name="mtp_norm")(leave(x)).astype(
+                self.dtype)
+            mtp_logits = jnp.dot(n, head_w,
+                                 preferred_element_type=jnp.float32)
+        return logits, extras(mtp_logits=mtp_logits)
 
 
 def chosen_experts(kept: dict) -> dict:
@@ -766,12 +1135,61 @@ def layer_specs(kw: dict) -> tuple:
         for i in range(int(kw["layers_held"])))
 
 
+def _yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention scale as this family's ``transformers`` code
+    has it."""
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def latent_layer_specs(kw: dict) -> tuple:
+    """The held layers' ``LayerSpec``s from the ``xing4_0`` keys
+    (the DeepSeek-V3 family's names): every layer latent attention
+    over the whole row, ``first_k_dense_replace`` leading layers with
+    the dense MLP. The rotary tables carry the ratio of ``mscale`` to
+    ``mscale_all_dim``'s scale; the scores carry the latter's
+    square."""
+    r = kw.get("rope_scaling") or {"type": "default"}
+    if r["type"] not in ("default", "yarn"):
+        raise ValueError(f"SeqPolicy computes default and yarn rotary "
+                         f"only; the spec says {r['type']!r}")
+    factor = float(r.get("factor", 1.0))
+    over_all = _yarn_mscale(factor, float(r.get("mscale_all_dim", 0)))
+    rope = Rope(
+        kind=r["type"], theta=float(kw["rope_theta"]),
+        dims=int(kw["qk_rope_head_dim"]), factor=factor,
+        original=int(r.get("original_max_position_embeddings", 0)),
+        beta_fast=float(r.get("beta_fast", 32)),
+        beta_slow=float(r.get("beta_slow", 1)),
+        attention_factor=_yarn_mscale(factor, float(r.get("mscale", 0)))
+        / over_all)
+    qk = int(kw["qk_nope_head_dim"]) + int(kw["qk_rope_head_dim"])
+    latent = Latent(
+        q_rank=int(kw["q_lora_rank"]), kv_rank=int(kw["kv_lora_rank"]),
+        nope=int(kw["qk_nope_head_dim"]),
+        rope=int(kw["qk_rope_head_dim"]), value=int(kw["v_head_dim"]),
+        scale=over_all * over_all / math.sqrt(qk))
+    return tuple(
+        LayerSpec(heads=int(kw["num_attention_heads"]), window=0,
+                  rope=rope,
+                  sparse=i >= int(kw["first_k_dense_replace"]),
+                  latent=latent)
+        for i in range(int(kw["layers_held"])))
+
+
 #: what this decoder computes one way only; a spec that says
 #: otherwise is refused rather than run as something else
 FIXED = {"attention_bias": False, "gating": "per-head",
          "moe_apply_router_weight_on_input": False,
          "moe_router_logit_softcapping": 0,
-         "tie_word_embeddings": False}
+         "tie_word_embeddings": False,
+         # the xing4_0 keys: no group-limited routing, an expert
+         # layer wherever the dense ones end, the bias-steered choice
+         "hidden_act": "silu", "n_group": 1, "topk_group": 1,
+         "moe_layer_freq": 1, "topk_method": "noaux_tc"}
+#: and what it computes in more than one way, by the spec's key
+CHOICES = {"model_type": ("laguna", "xing4_0"),
+           "scoring_func": ("softmax", "sigmoid"),
+           "num_nextn_predict_layers": (0, 1)}
 
 
 @neuralnet
@@ -793,6 +1211,11 @@ class SeqPolicy(NeuralNetBase):
                 raise ValueError(
                     f"SeqPolicy computes {key}={want!r} only; the "
                     f"spec says {kwargs[key]!r}")
+        for key, have in CHOICES.items():
+            if kwargs.get(key, have[0]) not in have:
+                raise ValueError(
+                    f"SeqPolicy computes {key} in {have!r} only; the "
+                    f"spec says {kwargs[key]!r}")
         if board * board + 2 > int(kwargs["vocab_held"]):
             raise ValueError(
                 f"a {board}x{board} record needs {board * board + 2} "
@@ -804,9 +1227,11 @@ class SeqPolicy(NeuralNetBase):
         self.module = self.create_network(**kwargs)
         self.params = None
         if init_weights:
+            # with next ids, so that a multi-token-prediction module
+            # gets its weights too
             dummy = jnp.zeros((1, 1), jnp.int32)
             self.params = jax.jit(self.module.init)(
-                jax.random.key(seed), dummy)
+                jax.random.key(seed), dummy, dummy)
         self._apply = jax.jit(self.module.apply)
 
     @property
@@ -823,20 +1248,43 @@ class SeqPolicy(NeuralNetBase):
 
     @staticmethod
     def create_network(**kw) -> SeqPolicyNet:
-        ffn = {"num_experts": int(kw["num_experts"]),
-               "top_k": int(kw["num_experts_per_tok"]),
-               "width": int(kw["moe_intermediate_size"]),
-               "shared_width": int(
-                   kw["shared_expert_intermediate_size"]),
-               "experts_held": int(kw["experts_held"]),
-               "expert_offset": int(kw["expert_offset"]),
-               "norm_topk": bool(kw["norm_topk_prob"]),
-               "routed_scale": float(kw["moe_routed_scaling_factor"])}
+        held = {"experts_held": int(kw["experts_held"]),
+                "expert_offset": int(kw["expert_offset"]),
+                "top_k": int(kw["num_experts_per_tok"]),
+                "width": int(kw["moe_intermediate_size"]),
+                "norm_topk": bool(kw["norm_topk_prob"]),
+                "scoring": kw.get("scoring_func", "softmax")}
+        if kw.get("model_type", "laguna") == "xing4_0":
+            layers = latent_layer_specs(kw)
+            ffn = dict(
+                held, num_experts=int(kw["n_routed_experts"]),
+                shared_width=int(kw["n_shared_experts"])
+                * int(kw["moe_intermediate_size"]),
+                routed_scale=float(kw["routed_scaling_factor"]))
+            # one key for each head of the latent layers; no grouping
+            kv_heads, head_dim = int(kw["num_attention_heads"]), 0
+        else:
+            layers = layer_specs(kw)
+            ffn = dict(
+                held, num_experts=int(kw["num_experts"]),
+                shared_width=int(
+                    kw["shared_expert_intermediate_size"]),
+                routed_scale=float(kw["moe_routed_scaling_factor"]))
+            kv_heads = int(kw["num_key_value_heads"])
+            head_dim = int(kw["head_dim"])
+        hyper = None
+        if "hc_mult" in kw:
+            hyper = Hyper(
+                streams=int(kw["hc_mult"]),
+                iters=int(kw["hc_sinkhorn_iters"]),
+                eps=float(kw["hc_eps"]),
+                clamp=(float(kw["mhc_h_res_clamp_min"]),
+                       float(kw["mhc_h_res_clamp_max"])))
         return SeqPolicyNet(
-            layers=layer_specs(kw), hidden=int(kw["hidden_size"]),
-            vocab_held=int(kw["vocab_held"]),
-            kv_heads=int(kw["num_key_value_heads"]),
-            head_dim=int(kw["head_dim"]),
+            layers=layers, hidden=int(kw["hidden_size"]),
+            vocab_held=int(kw["vocab_held"]), kv_heads=kv_heads,
+            head_dim=head_dim,
             dense_width=int(kw["intermediate_size"]),
             ffn=tuple(sorted(ffn.items())),
-            eps=float(kw["rms_norm_eps"]))
+            eps=float(kw["rms_norm_eps"]), hyper=hyper,
+            mtp=int(kw.get("num_nextn_predict_layers", 0)))

@@ -68,6 +68,9 @@ MOE_ROW_BLOCKS_RUN = "moe_row_blocks_run_total"
 MOE_ROW_BLOCKS = "moe_row_blocks_total"
 #: gauge: the busiest held expert's pairs in the latest step
 MOE_EXPERT_LOAD_MAX = "moe_expert_load_max"
+#: gauge: the multi-token-prediction module's own cross-entropy in
+#: the latest step (a step's ``loss`` already holds its weighted part)
+SEQ_MTP_LOSS = "seq_mtp_loss"
 
 
 def _fmt(x) -> str:

@@ -36,9 +36,26 @@ SEQ_EMBED = "seq.embed"
 SEQ_ATTN_FULL = "seq.attn.full"
 #: the same of a sliding-window layer
 SEQ_ATTN_WINDOW = "seq.attn.window"
-#: inside either of the two: the attention kernel alone (Pallas
+#: a latent-attention layer's norms, low-rank projections, rotary,
+#: attention and output projection
+SEQ_ATTN_MLA = "seq.attn.mla"
+#: inside any of the three: the attention kernel alone (Pallas
 #: ``splash_attention``, forward and backward kernels) where it runs
 SEQ_ATTN_KERNEL = "seq.attn.kernel"
+#: hyper-connections, three scopes side by side (a reader of
+#: ``seq.mhc`` sums them). The coefficients of one sublayer: the
+#: streams' norm statistic, their product with the three ``phi``s,
+#: the sigmoids
+SEQ_MHC_COEFF = "seq.mhc.coeff"
+#: the Sinkhorn iterations that make ``H_res`` doubly stochastic
+SEQ_MHC_SINKHORN = "seq.mhc.sinkhorn"
+#: the passes over the streams: ``H_pre X`` into a sublayer,
+#: ``H_res X + H_post^T y`` out of it, and the streams' sum at the end
+SEQ_MHC_MIX = "seq.mhc.mix"
+#: what the multi-token-prediction module adds outside its block (the
+#: block runs under the layer scopes above): the next ids' embedding,
+#: the two norms, ``eh_proj``, its final norm and its head product
+SEQ_MTP = "seq.mtp"
 #: the norm before a sparse layer, the float32 router, top-k and
 #: weights
 SEQ_ROUTER = "seq.router"

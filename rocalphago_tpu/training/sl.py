@@ -102,26 +102,66 @@ def policy_loss_fn(apply_fn, params, planes, actions, weights=None):
     return _policy_loss(apply_fn, params, planes, actions, weights)[:2]
 
 
+#: weight of a multi-token-prediction module's loss beside the main
+#: one (DeepSeek-V3 report §4.2: 0.3 for most of pre-training). The
+#: trainer's, not the model's: no published config has a key for it
+MTP_LOSS_WEIGHT = 0.3
+
+
+def _masked_xent(logits, actions, weights=None):
+    """Mean cross-entropy and accuracy over the positions whose
+    target is inside the logits' range (and whose row counts)."""
+    # pass actions (== N, present when a corpus was converted with
+    # include_passes) are outside the policy's board-point output
+    # space — mask them out rather than letting the xent gather
+    # clamp them onto the last board point
+    valid = (actions < logits.shape[-1]).astype(jnp.float32)
+    if weights is not None:
+        valid = valid * _per_row(weights, valid)
+    denom = jnp.maximum(valid.sum(), 1.0)
+    xent = optax.softmax_cross_entropy_with_integer_labels(
+        logits, jnp.minimum(actions, logits.shape[-1] - 1))
+    loss = (xent * valid).sum() / denom
+    acc = (((logits.argmax(axis=-1) == actions) * valid).sum()
+           / denom)
+    return loss, acc
+
+
 def _policy_loss(apply_fn, params, planes, actions, weights=None):
     """``policy_loss_fn`` plus whatever the network returns beside its
-    logits (a sequence policy's routing counts; else ``{}``)."""
-    out = apply_fn(params, planes)
+    logits (a sequence policy's routing counts; else ``{}``).
+
+    A sequence policy is given its next ids beside its ids: one with
+    a multi-token-prediction module embeds them and returns
+    ``mtp_logits``, at each position the logits of the id after the
+    next. Their cross-entropy against the next ids one to the left
+    (a row's last position has no target; a game separator is a
+    target like any other: attention crosses it too) is added
+    ``MTP_LOSS_WEIGHT`` times and returned as ``mtp_loss``."""
+    if planes.ndim == 2:
+        out = apply_fn(params, planes, actions)
+    else:
+        out = apply_fn(params, planes)
     logits, extras = out if isinstance(out, tuple) else (out, {})
+    extras = dict(extras)
+    ahead = extras.pop("mtp_logits", None)
     with jax.named_scope(scopes.TRAIN_LOSS):
-        # pass actions (== N, present when a corpus was converted with
-        # include_passes) are outside the policy's board-point output
-        # space — mask them out rather than letting the xent gather
-        # clamp them onto the last board point
-        valid = (actions < logits.shape[-1]).astype(jnp.float32)
-        if weights is not None:
-            valid = valid * _per_row(weights, valid)
-        denom = jnp.maximum(valid.sum(), 1.0)
-        xent = optax.softmax_cross_entropy_with_integer_labels(
-            logits, jnp.minimum(actions, logits.shape[-1] - 1))
-        loss = (xent * valid).sum() / denom
-        acc = (((logits.argmax(axis=-1) == actions) * valid).sum()
-               / denom)
+        loss, acc = _masked_xent(logits, actions, weights)
+        if ahead is not None:
+            beyond = jnp.full_like(actions[:, :1], ahead.shape[-1])
+            extras["mtp_loss"], _ = _masked_xent(
+                ahead, jnp.concatenate([actions[:, 1:], beyond], axis=1),
+                weights)
+            loss = loss + MTP_LOSS_WEIGHT * extras["mtp_loss"]
     return loss, acc, extras
+
+
+def _moved(params, moves):
+    """``params`` with ``moves`` — a part of the same tree — added."""
+    if not isinstance(moves, dict):
+        return params + moves
+    return {k: _moved(v, moves[k]) if k in moves else v
+            for k, v in params.items()}
 
 
 def make_train_step(apply_fn, tx, size: int, symmetries: bool):
@@ -146,10 +186,15 @@ def make_train_step(apply_fn, tx, size: int, symmetries: bool):
                     sub, planes, actions, size)
         (loss, (acc, extras)), grads = jax.value_and_grad(
             loss_fn, has_aux=True)(state.params, planes, actions)
+        # moves of leaves that carry no gradient (a sequence policy's
+        # selection biases, by their own rule), after the optimizer's
+        moves = extras.pop("no_grad_updates", None)
         with jax.named_scope(scopes.TRAIN_UPDATE):
             updates, opt_state = tx.update(
                 grads, state.opt_state, state.params)
             params = optax.apply_updates(state.params, updates)
+            if moves is not None:
+                params = _moved(params, moves)
         new = SLState(params, opt_state, state.step + 1, pack_rng(key))
         return new, {"loss": loss, "accuracy": acc, **extras}
 
@@ -172,6 +217,9 @@ def record_routing(metrics: list) -> None:
             sum(int(m[key]) for m in metrics))
     obs_registry.gauge(obs_registry.MOE_EXPERT_LOAD_MAX).set(
         int(metrics[-1]["moe_load_max"]))
+    if "mtp_loss" in metrics[-1]:
+        obs_registry.gauge(obs_registry.SEQ_MTP_LOSS).set(
+            float(metrics[-1]["mtp_loss"]))
 
 
 def make_eval_step(apply_fn, num_points: int):

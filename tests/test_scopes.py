@@ -130,6 +130,37 @@ def seq_ops():
 
 
 @pytest.fixture(scope="module")
+def xing_ops():
+    """The COMPILED supervised train step of the move-sequence policy
+    built from a toy ``xing4_0`` spec: latent attention, four
+    hyper-connected streams, a dense and an expert layer, the
+    multi-token-prediction module."""
+    from rocalphago_tpu.io.checkpoint import pack_rng
+    from rocalphago_tpu.models.seqpolicy import SeqPolicy
+    from rocalphago_tpu.training import sl
+
+    net = SeqPolicy(
+        board=SIZE, model_type="xing4_0", vocab_size=32, vocab_held=32,
+        hidden_size=8, intermediate_size=8, num_hidden_layers=2,
+        layers_held=2, first_k_dense_replace=1, num_attention_heads=2,
+        q_lora_rank=4, kv_lora_rank=4, qk_nope_head_dim=4,
+        qk_rope_head_dim=2, v_head_dim=4, rope_theta=10000,
+        n_routed_experts=4, n_shared_experts=1, num_experts_per_tok=2,
+        moe_intermediate_size=4, norm_topk_prob=True,
+        routed_scaling_factor=2, scoring_func="sigmoid", hc_mult=4,
+        hc_sinkhorn_iters=3, hc_eps=1e-6, mhc_h_res_clamp_min=-30,
+        mhc_h_res_clamp_max=30, num_nextn_predict_layers=1,
+        experts_held=2, expert_offset=0, rms_norm_eps=1e-6)
+    tx = sl.make_optimizer(sl.SLConfig())
+    step = sl.make_train_step(net.module.apply, tx, SIZE, True)
+    state = sl.SLState(net.params, tx.init(net.params), jnp.int32(0),
+                       pack_rng(jax.random.key(0)))
+    ids = jnp.zeros((2, 8), jnp.int32)
+    return op_names(jax.jit(step).lower(
+        state, ids, ids).compile().as_text())
+
+
+@pytest.fixture(scope="module")
 def ply_ops():
     from rocalphago_tpu.search.selfplay import _make_ply
 
@@ -187,12 +218,15 @@ SEQ = [scopes.SEQ_EMBED, scopes.SEQ_ATTN_FULL, scopes.SEQ_ATTN_WINDOW,
        scopes.SEQ_DENSE_FFN, scopes.SEQ_HEAD,
        scopes.SEQ_EXPERTS_SORT, scopes.SEQ_EXPERTS_DISPATCH,
        scopes.SEQ_EXPERTS_ACT, scopes.SEQ_EXPERTS_COMBINE]
+#: what an ``xing4_0`` spec adds to the sequence step
+XING = [scopes.SEQ_ATTN_MLA, scopes.SEQ_MHC_COEFF,
+        scopes.SEQ_MHC_SINKHORN, scopes.SEQ_MHC_MIX, scopes.SEQ_MTP]
 
 
 def test_every_constant_has_a_case():
     # the attention kernel's scope exists only in a program traced
     # for a TPU: tests/test_seqpolicy.py lowers it there
-    assert sorted(TRAIN + PLY + ENCODE + EVAL + MCTS + SEQ
+    assert sorted(TRAIN + PLY + ENCODE + EVAL + MCTS + SEQ + XING
                   + [scopes.SEQ_ATTN_KERNEL]) == sorted(scopes.ALL)
     assert len(set(scopes.ALL)) == len(scopes.ALL)
 
@@ -214,6 +248,36 @@ def test_sequence_scopes_name_the_backward_pass_too(seq_ops, name):
     mine = [op for op in seq_ops if name in op.split("/")]
     assert any("transpose(jvp(SeqPolicyNet))" in op for op in mine)
     assert any("transpose(" not in op for op in mine)
+
+
+@pytest.mark.parametrize("name", XING + [
+    scopes.SEQ_ROUTER, scopes.SEQ_EXPERTS, scopes.SEQ_DENSE_FFN,
+    scopes.SEQ_HEAD, scopes.TRAIN_LOSS])
+def test_xing_step_scope_survives_the_compile(xing_ops, name):
+    assert has(xing_ops, name), sorted(set(xing_ops))[:40]
+
+
+@pytest.mark.parametrize("name", XING)
+def test_xing_scopes_name_the_backward_pass_too(xing_ops, name):
+    mine = [op for op in xing_ops if name in op.split("/")]
+    assert any("transpose(jvp(SeqPolicyNet))" in op for op in mine)
+    assert any("transpose(" not in op for op in mine)
+
+
+def test_xing_scopes_lie_side_by_side(xing_ops):
+    """No new scope inside another top-level ``seq.*`` one (the
+    attention kernel's aside), so the by-scope account still
+    partitions; the MTP module's block runs under the layers'."""
+    tops = [n for n in scopes.ALL if n.startswith("seq.")
+            and n != scopes.SEQ_ATTN_KERNEL
+            and not n.startswith(scopes.SEQ_EXPERTS + ".")]
+    for op in xing_ops:
+        inside = [n for n in tops if n in op.split("/")]
+        assert len(inside) <= 1, op
+    block = [op for op in xing_ops if "/mtp_layer/" in op]
+    assert block and not any(scopes.SEQ_MTP in op.split("/")
+                             for op in block)
+    assert any(scopes.SEQ_ATTN_MLA in op.split("/") for op in block)
 
 
 def test_train_loss_is_scoped_forward_and_backward(train_ops):
