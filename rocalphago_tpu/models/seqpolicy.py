@@ -94,8 +94,13 @@ router, softmax and loss):
   body run more or fewer times and no second path. What the buffer
   left out is counted from the buffer (``moe_dropped``), not
   assumed, and so are the blocks that ran;
-* every layer is recomputed in the backward pass (``nn.remat``): what
-  a step saves is one ``[B, S, hidden]`` input per layer.
+* every layer is recomputed in the backward pass (``nn.remat``),
+  all of it but the attention kernel: what a step keeps is one
+  ``[B, S, hidden]`` input per layer and, where the kernel runs, its
+  output ``[B, H, S, dv]`` and row statistics ``[B, H, S]``, which
+  carry the name :data:`KERNEL_RESIDUALS` — so the kernel's forward
+  runs once a step and its backward reads what that call left. The
+  XLA form has no such name and keeps nothing more.
 """
 
 from __future__ import annotations
@@ -215,6 +220,10 @@ KERNEL_BLOCK = 512
 #: query block of the XLA form's full layers (a shorter row is one
 #: block)
 ATTENTION_BLOCK = 1024
+#: the name the kernel's output and row statistics carry
+#: (``jax.ad_checkpoint.checkpoint_name``), and the one thing a
+#: layer's recomputation keeps
+KERNEL_RESIDUALS = "seq_attn_kernel_residuals"
 
 
 def kernel_platform() -> str:
@@ -252,6 +261,7 @@ def kernel_attention(q, k, v, window: int, interpret: bool = False):
             block_q=b, block_kv=b, block_kv_compute=b, block_q_dkv=b,
             block_kv_dkv=b, block_kv_dkv_compute=b, block_q_dq=b,
             block_kv_dq=b),
+        residual_checkpoint_name=KERNEL_RESIDUALS,
         interpret=interpret)
     with jax.named_scope(scopes.SEQ_ATTN_KERNEL):
         out = jax.vmap(kernel)(*(x.transpose(0, 2, 1, 3)
@@ -1031,7 +1041,10 @@ class SeqPolicyNet(nn.Module):
             x = jnp.take(table, ids, axis=0).astype(self.dtype)
         totals = dict.fromkeys(MOE_STATS, jnp.int32(0))
         moves = {}
-        layer = nn.remat(DecoderLayer)
+        layer = nn.remat(
+            DecoderLayer,
+            policy=jax.checkpoint_policies.save_only_these_names(
+                KERNEL_RESIDUALS))
 
         def block(spec, name, x):
             """One layer on the state, its counts merged."""

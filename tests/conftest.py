@@ -65,3 +65,69 @@ def random_game_states():
             length=moves)[0][0])(key)
 
     return play
+
+
+@pytest.fixture
+def whole_layer_remat():
+    """A context in which ``SeqPolicyNet`` recomputes every layer
+    whole — ``nn.remat`` as it was before it had a policy: what the
+    tests of the policy compare with."""
+    import contextlib
+
+    import flax.linen as nn
+
+    @contextlib.contextmanager
+    def context():
+        with pytest.MonkeyPatch.context() as patch:
+            patch.setattr(nn, "remat",
+                          lambda cls, policy, plain=nn.remat: plain(cls))
+            yield
+
+    return context
+
+
+@pytest.fixture
+def kernel_gradient(whole_layer_remat):
+    """``(cfg, kept=True) -> (pallas_calls, names)``: the kernel calls
+    in the gradient's jaxpr of the trainer's loss of the sequence
+    policy ``cfg`` builds, and the names its values carry
+    (``checkpoint_name``), with the splash kernel traced as on a TPU
+    at the smallest shape its tiles take (one row of 256, blocks of
+    128); ``kept`` false recomputes every layer whole. A trace alone:
+    nothing is compiled or run."""
+    import contextlib
+
+    import jax.numpy as jnp
+
+    from rocalphago_tpu.models import seqpolicy
+    from rocalphago_tpu.training import sl
+
+    def eqns(jaxpr):
+        for eqn in jaxpr.eqns:
+            yield eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from eqns(sub)
+
+    def read(cfg, kept=True):
+        net = seqpolicy.SeqPolicy(board=19, init_weights=False, **cfg)
+        dummy = jnp.zeros((1, 1), jnp.int32)
+        params = jax.eval_shape(net.module.init, jax.random.key(0),
+                                dummy, dummy)
+        ids = jax.ShapeDtypeStruct((1, 256), jnp.int32)
+        # a trace that is only counted: without jax's own checks of
+        # every equation (JAX_ENABLE_CHECKS, above), which take most
+        # of its time
+        with pytest.MonkeyPatch.context() as patch, \
+                jax.enable_checks(False), \
+                contextlib.nullcontext() if kept else whole_layer_remat():
+            patch.setattr(seqpolicy, "kernel_platform", lambda: "tpu")
+            patch.setattr(seqpolicy, "KERNEL_BLOCK", 128)
+            found = list(eqns(jax.make_jaxpr(jax.grad(
+                lambda p, i, n: sl.policy_loss_fn(
+                    net.module.apply, p, i, n)[0]))(
+                        params, ids, ids).jaxpr))
+        return (sum(e.primitive.name == "pallas_call" for e in found),
+                {e.params["name"] for e in found
+                 if e.primitive.name == "name"})
+
+    return read

@@ -684,6 +684,88 @@ def test_the_attention_kernel_is_the_dense_masked_one(window):
         got, dense_attention(q, k, v, window)) < 1e-4
 
 
+# what a layer's recomputation keeps: the kernel's output and row
+# statistics by name, so its forward runs once a step
+
+#: the toy at a head the kernel takes: 5 layers, each with a kernel
+KERNEL_TOY = dict(TOY, head_dim=128)
+KERNEL_LAYERS = 5
+
+
+@pytest.mark.parametrize("kept", [True, False])
+def test_the_backward_pass_recomputes_a_layer_but_not_its_kernel(
+        kernel_gradient, kept):
+    """Forward, ``dq`` and ``dkv`` a layer — and a second forward
+    call where the layer is recomputed whole, as it was."""
+    calls, names = kernel_gradient(KERNEL_TOY, kept)
+    assert calls == (3 if kept else 4) * KERNEL_LAYERS
+    assert names == {seqpolicy.KERNEL_RESIDUALS}
+
+
+def test_the_kernels_name_and_the_policys_are_one_constant(
+        kernel_gradient, monkeypatch):
+    monkeypatch.setattr(seqpolicy, "KERNEL_RESIDUALS", "another")
+    assert kernel_gradient(KERNEL_TOY) == (3 * KERNEL_LAYERS,
+                                           {"another"})
+
+
+def test_the_policy_keeps_nothing_of_the_xla_form(
+        net, batch, whole_layer_remat):
+    """No value of the XLA form carries the name, so loss and
+    gradients are the whole-layer recomputation's to the bit: the
+    lowered program is that one's, letter for letter."""
+    def lowered():
+        # a fresh function each time: jit's cache holds functions,
+        # not what they close over
+        return jax.jit(jax.value_and_grad(
+            lambda p: sl.policy_loss_fn(
+                net.module.apply, p, *batch)[0])).lower(
+                net.params).as_text()
+
+    # two traces of the whole step: jax's own checks of every
+    # equation are not what is compared
+    with jax.enable_checks(False):
+        kept = lowered()
+        with whole_layer_remat():
+            assert lowered() == kept
+
+
+def test_the_kept_residuals_give_the_xla_forms_gradients(
+        whole_layer_remat):
+    """One full-attention layer through the kernel, interpreted on
+    the CPU in float32: the backward kernels read the output and row
+    statistics the forward call left, and the gradients are the
+    whole-layer recomputation's to the bit and the XLA form's to
+    rounding."""
+    import functools
+
+    layer = seqpolicy.LayerSpec(
+        heads=2, window=0, rope=seqpolicy.Rope("default", 1e4, 128),
+        sparse=False)
+    module = seqpolicy.SeqPolicyNet(
+        layers=(layer,), hidden=32, vocab_held=64, kv_heads=1,
+        head_dim=128, dense_width=32, ffn=(), dtype=jnp.float32)
+    ids = jax.random.randint(jax.random.key(5), (1, 256), 0, 64)
+    params = module.init(jax.random.key(6), ids)
+
+    def grads():
+        return jax.jit(jax.grad(lambda p: sl.policy_loss_fn(
+            module.apply, p, ids, ids[:, ::-1])[0]))(params)
+
+    xla = grads()
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(seqpolicy, "kernel_platform", lambda: "tpu")
+        patch.setattr(seqpolicy, "KERNEL_BLOCK", 128)
+        patch.setattr(seqpolicy, "kernel_attention", functools.partial(
+            seqpolicy.kernel_attention, interpret=True))
+        kept = grads()
+        with whole_layer_remat():
+            whole = grads()
+    for a, b, c in zip(*map(jax.tree.leaves, (kept, whole, xla))):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        assert reference.relative_error(a, c) < 1e-4
+
+
 def test_the_kernel_runs_under_its_scope_where_its_tiles_fit():
     from rocalphago_tpu.obs import scopes
 

@@ -337,6 +337,46 @@ def test_the_kernel_at_192_and_128_is_the_dense_masked_one():
     assert reference.relative_error(xla[0], want) < 1e-5
 
 
+# what a block's recomputation keeps: the kernel's output and row
+# statistics by name (tests/test_seqpolicy.py has the gated layers')
+
+#: the toy at the published heads, 192 for queries and keys and 128
+#: for values: three layers and the MTP's block, each with a kernel
+KERNEL_TOY = dict(TOY, qk_nope_head_dim=128, qk_rope_head_dim=64,
+                  v_head_dim=128)
+KERNEL_BLOCKS = 4
+
+
+@pytest.mark.parametrize("kept", [True, False])
+def test_the_backward_pass_recomputes_a_block_but_not_its_kernel(
+        kernel_gradient, kept):
+    """Forward, ``dq`` and ``dkv`` a block, the MTP's among them,
+    through the streams' mixing — and a second forward call where
+    the block is recomputed whole, as it was."""
+    calls, names = kernel_gradient(KERNEL_TOY, kept)
+    assert calls == (3 if kept else 4) * KERNEL_BLOCKS
+    assert names == {seqpolicy.KERNEL_RESIDUALS}
+
+
+def test_the_policy_keeps_nothing_of_the_xla_form(
+        net, batch, whole_layer_remat):
+    """No value of the XLA form carries the name, so both losses and
+    every gradient are the whole-block recomputation's to the bit:
+    the lowered program is that one's, letter for letter."""
+    def lowered():
+        return jax.jit(jax.value_and_grad(
+            lambda p: sl._policy_loss(
+                net.module.apply, p, *batch)[::2],
+            has_aux=True)).lower(net.params).as_text()
+
+    # two traces of the whole step: jax's own checks of every
+    # equation are not what is compared
+    with jax.enable_checks(False):
+        kept = lowered()
+        with whole_layer_remat():
+            assert lowered() == kept
+
+
 # ---------------------------------------------------- hyper-connections
 
 def raw_matrices(n: int = 4, tokens: int = 6):
