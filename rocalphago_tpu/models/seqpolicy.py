@@ -51,6 +51,30 @@ first. A spec chooses by the keys it carries, nothing else does:
   next through the shared embedding and head — whenever the next ids
   are given (training; a forward pass without them has no use for it).
 
+A third is read from the ``bailing_hybrid`` keys (inclusionAI
+Ling-3.0-flash, ``config.json``), which are the second's where the
+two overlap and add:
+
+* ``layer_group_size``: a **hybrid pattern of mixer kinds** — layer
+  ``i`` (by its published index) is latent attention where ``(i + 1)
+  % layer_group_size == 0`` and **Kimi delta attention** elsewhere
+  (:class:`KimiDeltaAttention`; Kimi Linear, arXiv:2510.26692, over
+  the gated delta rule of arXiv:2412.06464): a token mixer that is a
+  recurrence, per head a ``d_k × d_v`` state that decays per key
+  channel and is corrected by a delta rule, run as a chunked scan
+  (:func:`kda_chunked`);
+* ``q_lora_rank: null``: latent attention without the low-rank query
+  path, and ``gated_attention_proj_granularity_type: head_wise``: a
+  sigmoid gate per head before its output projection;
+* ``n_group`` / ``topk_group``: **group-limited choice** — the
+  experts lie in ``n_group`` groups by index, a group scores the sum
+  of its two best ``s + b``, and the choice is made among the
+  ``topk_group`` best groups' experts (DeepSeek-V3's ``noaux_tc``);
+* ``mtp_use_kda: false`` / ``mtp_loss_scaling_factor``: the
+  multi-token-prediction block is a latent-attention + expert layer
+  whatever the last layer is, and the module's loss weight is the
+  model's own key (the published value is 0).
+
 **The held share.** A spec also says what part of the model lives
 here: ``layers_held`` leading layers, ``vocab_held`` leading rows of
 the vocabulary (embedding, head, logits and loss are over the slice),
@@ -141,12 +165,23 @@ class Rope(NamedTuple):
 class Latent(NamedTuple):
     """Latent attention's sizes, as the config names them."""
 
-    q_rank: int             # q_lora_rank
+    q_rank: int             # q_lora_rank; 0 (null): no low-rank path
     kv_rank: int            # kv_lora_rank
     nope: int               # qk_nope_head_dim
     rope: int               # qk_rope_head_dim
     value: int              # v_head_dim
     scale: float            # of the scores: 1/sqrt(nope + rope) x mscale^2
+    gate: bool = False      # a head-wise sigmoid gate before o_proj
+
+
+class Kda(NamedTuple):
+    """Kimi delta attention's sizes and constants, as the config
+    names them."""
+
+    key: int                # head_dim: a head's queries and keys
+    value: int              # head_dim: a head's values
+    conv: int               # short_conv_kernel_size
+    lower: float            # kda_lower_bound: the log-decay's floor
 
 
 class Hyper(NamedTuple):
@@ -164,6 +199,7 @@ class LayerSpec(NamedTuple):
     rope: Rope
     sparse: bool            # routed experts (else the dense MLP)
     latent: Latent | None = None    # latent attention (else gated GQA)
+    kda: Kda | None = None          # Kimi delta attention (else either)
 
 
 def rope_inv_freq(rope: Rope) -> np.ndarray:
@@ -368,6 +404,183 @@ def _attention(q, k, v, window: int):
     return grouped_attention(q, k, v, window)
 
 
+# ------------------------------------------------- Kimi delta attention
+#
+# Per head a state ``S [d_k, d_v]``, zero at the row's start:
+#
+#     S_t = (I − β_t k_t k_tᵀ) Diag(exp g_t) S_{t−1} + β_t k_t v_tᵀ
+#     o_t = S_tᵀ q_t
+#
+# with a log-decay ``g_t ∈ (lower, 0)`` per key channel. The chunkwise
+# form (Kimi Linear, arXiv:2510.26692 §3; ``G_r = Σ_{i≤r} g_i`` inside
+# a chunk of ``C`` tokens, ``S`` the state that enters it):
+#
+#     A = strict_lower(Diag(β) (K ⊙ e^G) (K ⊙ e^−G)ᵀ)
+#     T = (I + A)^−1 Diag(β);  W = T (K ⊙ e^G);  U = T V
+#     V' = U − W S
+#     O  = (Q ⊙ e^G) S + lower((Q ⊙ e^G) (K ⊙ e^−G)ᵀ) V'
+#     S ← Diag(e^{G_C}) S + (K ⊙ e^{G_C − G})ᵀ V'
+#
+# Everything but the last three lines is the same work for every
+# chunk and runs for all of them at once; the last three are a
+# ``lax.scan`` over the chunks. ``e^−G`` reaches ``e^{5C}`` and
+# float32 ends at ``e^88``, so the two pairwise products are taken
+# sixteen rows at a time relative to the first of them: rows scaled
+# by ``e^{G_r − G_0} ≤ 1``, columns by ``e^{G_0 − G_c}``, which is at
+# most ``e^{5·15}`` on the rows' own sixteen and at most 1 before
+# them — what the config's lower bound of −5 is for. Exact: nothing
+# is clamped.
+
+#: tokens of a chunk of the scan (the published kernels' 64; a
+#: shorter row is one chunk)
+KDA_CHUNK = 64
+#: rows of a chunk whose pairwise decays share one point of
+#: reference: ``|lower| × (KDA_SUB − 1)`` must stay under float32's
+#: ``e^88`` (5 × 15 = 75)
+KDA_SUB = 16
+#: of the small float32 products the rest is built on — the pairwise
+#: decays and the inverse: the MXU's full float32, as the router's
+KDA_EXACT = jax.lax.Precision.HIGHEST
+
+
+@jax.custom_vjp
+def unit_lower_inverse(a: jax.Array) -> jax.Array:
+    """``(I + a)^−1`` of strictly lower triangular ``a [..., C, C]``:
+    ``a`` is nilpotent, so the inverse is ``Σ (−a)^i = (I − a)(I +
+    a²)(I + a⁴)…`` — ``log₂ C`` squarings and as many products, all
+    of them matrix products over every chunk and head at once."""
+    c = a.shape[-1]
+    x, power, covered = jnp.eye(c, dtype=a.dtype) - a, a, 2
+    while covered < c:
+        power = jnp.matmul(power, power, precision=KDA_EXACT)
+        x = x + jnp.matmul(x, power, precision=KDA_EXACT)
+        covered *= 2
+    return x
+
+
+def _unit_lower_inverse_fwd(a):
+    x = unit_lower_inverse(a)
+    return x, x
+
+
+def _unit_lower_inverse_bwd(x, d):
+    """``d(M^−1) = −M^−1 dM M^−1``: the inverse is all it keeps."""
+    xt = jnp.swapaxes(x, -1, -2)
+    return (-jnp.matmul(jnp.matmul(xt, d, precision=KDA_EXACT), xt,
+                        precision=KDA_EXACT),)
+
+
+unit_lower_inverse.defvjp(_unit_lower_inverse_fwd,
+                          _unit_lower_inverse_bwd)
+
+
+def _pairwise_decayed(q, k, gc, sub: int):
+    """``Σ_d x_{r,d} k_{c,d} e^{G_{r,d} − G_{c,d}}`` for ``x`` = ``q``
+    and ``x`` = ``k``, ``[..., C, C]`` each, right wherever ``r ≥ c``
+    (finite elsewhere, and masked by the caller): ``q, k, gc [..., C,
+    d_k]`` float32, ``gc`` the inclusive sums of the log-decay inside
+    the chunk. ``sub`` rows at a time against the columns up to their
+    last, rows and columns scaled relative to the first of the rows
+    (module comment)."""
+    c = q.shape[-2]
+    of_q, of_k = [], []
+    for lo in range(0, c, sub):
+        hi = lo + sub
+        first = gc[..., lo:lo + 1, :]
+        into = jnp.exp(gc[..., lo:hi, :] - first)
+        back = k[..., :hi, :] * jnp.exp(first - gc[..., :hi, :])
+        rows = jnp.concatenate([q[..., lo:hi, :] * into,
+                                k[..., lo:hi, :] * into], axis=-2)
+        both = jnp.einsum("...rd,...cd->...rc", rows, back,
+                          precision=KDA_EXACT)
+        both = jnp.pad(both, [(0, 0)] * (both.ndim - 1) + [(0, c - hi)])
+        of_q.append(both[..., :sub, :])
+        of_k.append(both[..., sub:, :])
+    return jnp.concatenate(of_q, axis=-2), jnp.concatenate(of_k, axis=-2)
+
+
+def kda_chunked(q, k, v, g, beta, dtype=jnp.bfloat16):
+    """The recurrence above in its chunkwise form: ``q, k [B, S, H,
+    d_k]`` (normed, ``q`` scaled), ``v [B, S, H, d_v]``, the float32
+    log-decay ``g [B, S, H, d_k]`` and ``beta [B, S, H]`` → float32
+    ``o [B, S, H, d_v]``. The log-decay, its sums and exponentials
+    and the carried state are float32; the products with the state
+    take their operands in ``dtype`` (every one of them at most 1 in
+    size: no ``e^−G`` among them) and add in float32."""
+    b, s_len, h, dk = q.shape
+    dv = v.shape[-1]
+    c = min(KDA_CHUNK, s_len)
+    sub = min(KDA_SUB, c)
+    if s_len % c or c % sub:
+        raise ValueError(f"a row of {s_len} is not whole chunks of {c} "
+                         f"in blocks of {sub}")
+    n = s_len // c
+    f32 = jnp.float32
+
+    def chunks(x):
+        """``[B, S, H, d]`` → float32 ``[B, H, N, C, d]``."""
+        return x.astype(f32).reshape(b, n, c, h, -1).transpose(
+            0, 3, 1, 2, 4)
+
+    qc, kc, vc = chunks(q), chunks(k), chunks(v)
+    gc = jnp.cumsum(chunks(g), axis=3)
+    bc = chunks(beta[..., None])                        # [B, H, N, C, 1]
+    a_q, a_k = _pairwise_decayed(qc, kc, gc, sub)
+    row = jnp.arange(c)[:, None]
+    col = jnp.arange(c)[None, :]
+    a_q = jnp.where(row >= col, a_q, 0.0)
+    t = unit_lower_inverse(jnp.where(row > col, bc * a_k, 0.0))
+    t = t * jnp.swapaxes(bc, -1, -2)                    # T Diag(β)
+    grown = jnp.exp(gc)                                 # e^G ≤ 1
+    last = gc[..., -1:, :]
+
+    def product(spec, x, y):
+        return jnp.einsum(spec, x.astype(dtype), y.astype(dtype),
+                          preferred_element_type=f32)
+
+    def by_chunk(x, to):
+        """The chunks' axis first, for the loop; its products'
+        operands in the compute type once, not once a turn."""
+        return jnp.moveaxis(x, 2, 0).astype(to)
+
+    per_chunk = (
+        by_chunk(product("...rc,...cd->...rd", t, kc * grown), dtype),
+        by_chunk(product("...rc,...cd->...rd", t, vc), f32),
+        by_chunk(qc * grown, dtype), by_chunk(a_q, dtype),
+        by_chunk(kc * jnp.exp(last - gc), dtype),
+        by_chunk(jnp.exp(last[..., 0, :]), f32))
+
+    def step(state, x):
+        w, u, q_in, a_q, k_out, decay = x
+        fresh = u - product("bhck,bhkv->bhcv", w, state)
+        out = (product("bhck,bhkv->bhcv", q_in, state)
+               + product("bhcr,bhrv->bhcv", a_q, fresh))
+        state = (decay[..., None] * state
+                 + product("bhck,bhcv->bhkv", k_out, fresh))
+        return state, out
+
+    _, out = jax.lax.scan(step, jnp.zeros((b, h, dk, dv), f32),
+                          per_chunk)
+    # [N, B, H, C, d_v] → [B, S, H, d_v]
+    return out.transpose(1, 0, 3, 2, 4).reshape(b, s_len, h, dv)
+
+
+def causal_conv(x: jax.Array, taps: jax.Array) -> jax.Array:
+    """Depthwise causal convolution along the row, no bias, zero
+    history at the row's start: ``x [B, S, C]``, ``taps [K, C]`` →
+    float32 ``y_t = Σ_j taps[j] · x_{t − (K−1) + j}``."""
+    k, s_len = taps.shape[0], x.shape[1]
+    xp = jnp.pad(x.astype(jnp.float32), ((0, 0), (k - 1, 0), (0, 0)))
+    return sum(taps[j].astype(jnp.float32) * xp[:, j:j + s_len]
+               for j in range(k))
+
+
+def l2_normed(x: jax.Array, eps: float = 1e-6) -> jax.Array:
+    """``x / ‖x‖₂`` over the last axis, in float32."""
+    x = x.astype(jnp.float32)
+    return x * jax.lax.rsqrt((x * x).sum(-1, keepdims=True) + eps)
+
+
 # -------------------------------------------------------------- modules
 
 def _weight(module, name: str, shape: tuple, dtype):
@@ -453,12 +666,16 @@ class LatentAttention(nn.Module):
         spec, lat = self.spec, self.spec.latent
         b, s_len, d = x.shape
         h, qk = spec.heads, lat.nope + lat.rope
-        c_q = RMSNorm(self.eps, name="q_a_norm")(jnp.dot(
-            x, _weight(self, "q_a_proj", (d, lat.q_rank), self.dtype)))
-        q = jnp.dot(
-            c_q.astype(self.dtype),
-            _weight(self, "q_b_proj", (lat.q_rank, h * qk), self.dtype)
-        ).reshape(b, s_len, h, qk)
+        if lat.q_rank:
+            c_q = RMSNorm(self.eps, name="q_a_norm")(jnp.dot(
+                x, _weight(self, "q_a_proj", (d, lat.q_rank),
+                           self.dtype)))
+            q = jnp.dot(c_q.astype(self.dtype), _weight(
+                self, "q_b_proj", (lat.q_rank, h * qk), self.dtype))
+        else:
+            q = jnp.dot(x, _weight(self, "q_proj", (d, h * qk),
+                                   self.dtype))
+        q = q.reshape(b, s_len, h, qk)
         kv_a = jnp.dot(x, _weight(
             self, "kv_a_proj", (d, lat.kv_rank + lat.rope), self.dtype))
         c_kv = RMSNorm(self.eps, name="kv_a_norm")(
@@ -479,9 +696,88 @@ class LatentAttention(nn.Module):
             [kv[..., :lat.nope],
              jnp.broadcast_to(k_pe, (b, s_len, h, lat.rope))], axis=-1)
         a = _attention(q, k, kv[..., lat.nope:], spec.window)
+        if lat.gate:
+            gate = jax.nn.sigmoid(jnp.dot(
+                x, _weight(self, "gate_proj", (d, h), self.dtype)
+            ).astype(jnp.float32))
+            a = a * gate[..., None]
         return jnp.dot(
             a.astype(self.dtype).reshape(b, s_len, h * lat.value),
             _weight(self, "o_proj", (h * lat.value, d), self.dtype))
+
+
+def _a_log_init(key, shape, dtype=jnp.float32):
+    """``log U(1, 16)`` (``fla/layers/kda.py``)."""
+    return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
+
+
+def _dt_bias_init(key, shape, dtype=jnp.float32):
+    """The inverse softplus of a step drawn log-uniformly from
+    ``[0.001, 0.1]`` (the same file; Mamba's)."""
+    dt = jnp.exp(jax.random.uniform(key, shape, dtype,
+                                    math.log(0.001), math.log(0.1)))
+    dt = jnp.maximum(dt, 1e-4)
+    return dt + jnp.log(-jnp.expm1(-dt))
+
+
+class KimiDeltaAttention(nn.Module):
+    """Kimi delta attention (``spec.kda``): queries, keys and values
+    through a projection, a depthwise causal convolution and SiLU
+    each; queries and keys L2-normed per head; a log-decay per key
+    channel held above the config's lower bound; a delta-rule
+    recurrence per head (:func:`kda_chunked`); the result normed per
+    head, gated channel by channel and projected back. No rotary."""
+
+    spec: LayerSpec
+    eps: float
+    dtype: jnp.dtype = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        spec, kda = self.spec, self.spec.kda
+        b, s_len, d = x.shape
+        h, dk, dv = spec.heads, kda.key, kda.value
+        f32 = jnp.float32
+
+        def mixed(name: str, width: int):
+            """Projection, convolution, SiLU: float32 ``[B, S, H,
+            width]``."""
+            y = jnp.dot(x, _weight(self, f"{name}_proj", (d, h * width),
+                                   self.dtype))
+            taps = self.param(f"{name}_conv",
+                              nn.initializers.normal(0.02),
+                              (kda.conv, h * width), f32)
+            return jax.nn.silu(causal_conv(y, taps)).reshape(
+                b, s_len, h, width)
+
+        with jax.named_scope(scopes.SEQ_ATTN_KDA_PROJ):
+            q = (l2_normed(mixed("q", dk)) / math.sqrt(dk)).astype(
+                self.dtype)
+            k = l2_normed(mixed("k", dk)).astype(self.dtype)
+            v = mixed("v", dv).astype(self.dtype)
+            beta = jax.nn.sigmoid(jnp.dot(
+                x, _weight(self, "b_proj", (d, h), self.dtype),
+                preferred_element_type=f32))
+            # the log-decay: lower · σ(e^{A_log} (x W_f + dt_bias)),
+            # in (lower, 0) whatever the weights
+            rate = jnp.exp(self.param("A_log", _a_log_init, (h,), f32))
+            raw = jnp.dot(
+                x, _weight(self, "f_proj", (d, h * dk), self.dtype),
+                preferred_element_type=f32
+            ) + self.param("dt_bias", _dt_bias_init, (h * dk,), f32)
+            g = kda.lower * jax.nn.sigmoid(
+                rate[:, None] * raw.reshape(b, s_len, h, dk))
+        with jax.named_scope(scopes.SEQ_ATTN_KDA_SCAN):
+            o = kda_chunked(q, k, v, g, beta, self.dtype)
+        with jax.named_scope(scopes.SEQ_ATTN_KDA_OUT):
+            gate = jax.nn.sigmoid(jnp.dot(
+                x, _weight(self, "g_proj", (d, h * dv), self.dtype)
+            ).astype(f32))
+            o = RMSNorm(self.eps, name="o_norm")(o).reshape(
+                b, s_len, h * dv) * gate
+            return jnp.dot(
+                o.astype(self.dtype),
+                _weight(self, "o_proj", (h * dv, d), self.dtype))
 
 
 # ----------------------------------------------------- hyper-connections
@@ -847,6 +1143,23 @@ def bias_step(load: jax.Array) -> jax.Array:
     return ROUTER_BIAS_RATE * jnp.sign(load.mean() - load)
 
 
+def group_limited(scores: jax.Array, n_group: int, topk_group: int
+                  ) -> jax.Array:
+    """``scores [T, E]`` with every expert outside the ``topk_group``
+    best of ``n_group`` groups at −∞ (DeepSeek-V3's group-limited
+    choice: the experts lie in groups by index — a group to a node
+    of the deployment — and a group scores the sum of its two
+    best). One group: the scores as they are."""
+    if n_group == 1:
+        return scores
+    t, e = scores.shape
+    grouped = scores.reshape(t, n_group, e // n_group)
+    best_two = jax.lax.top_k(grouped, 2)[0].sum(axis=-1)
+    _, kept = jax.lax.top_k(best_two, topk_group)
+    stays = (kept[..., None] == jnp.arange(n_group)).any(axis=1)
+    return jnp.where(stays[..., None], grouped, -jnp.inf).reshape(t, e)
+
+
 class SparseFFN(nn.Module):
     """Router over all ``num_experts``, the held experts' part of the
     routed result, and the shared expert.
@@ -859,7 +1172,9 @@ class SparseFFN(nn.Module):
     tree under a ``stop_gradient``, not a collection of its own: the
     model JSON, the checkpoints and the trainer's state carry one
     tree, and the optimizer's update of a zero gradient leaves the
-    leaf as it is. What moves it is the published rule
+    leaf as it is. With ``n_group`` groups the choice is among the
+    ``topk_group`` best groups' experts (:func:`group_limited`).
+    What moves the bias is the published rule
     (:func:`bias_step`): the layer returns the move with its counts
     (``bias_step``) and the train step adds it after the optimizer's
     update — without it the held experts' load drifts with the
@@ -874,6 +1189,8 @@ class SparseFFN(nn.Module):
     norm_topk: bool
     routed_scale: float
     scoring: str = "softmax"
+    n_group: int = 1
+    topk_group: int = 1
     dtype: jnp.dtype = jnp.bfloat16
 
     @nn.compact
@@ -893,8 +1210,9 @@ class SparseFFN(nn.Module):
                                   nn.initializers.normal(ROUTER_BIAS_STD),
                                   (self.num_experts,), jnp.float32)
                 p = jax.nn.sigmoid(logits)
-                _, chosen = jax.lax.top_k(
-                    p + jax.lax.stop_gradient(bias), self.top_k)
+                _, chosen = jax.lax.top_k(group_limited(
+                    p + jax.lax.stop_gradient(bias), self.n_group,
+                    self.topk_group), self.top_k)
                 weight = jnp.take_along_axis(p, chosen, axis=-1)
                 moves = {"bias_step": bias_step((
                     chosen[..., None] == jnp.arange(self.num_experts)
@@ -971,7 +1289,9 @@ class DecoderLayer(nn.Module):
         spec = self.spec
 
         def attn_scope():
-            return (jax.named_scope(scopes.SEQ_ATTN_MLA) if spec.latent
+            return (jax.named_scope(scopes.SEQ_ATTN_KDA) if spec.kda
+                    else jax.named_scope(scopes.SEQ_ATTN_MLA)
+                    if spec.latent
                     else jax.named_scope(scopes.SEQ_ATTN_WINDOW)
                     if spec.window
                     else jax.named_scope(scopes.SEQ_ATTN_FULL))
@@ -979,6 +1299,9 @@ class DecoderLayer(nn.Module):
         def attention(x):
             n = RMSNorm(self.eps, name="input_norm")(x).astype(
                 self.dtype)
+            if spec.kda:
+                return KimiDeltaAttention(spec, self.eps, self.dtype,
+                                          name="attn")(n)
             if spec.latent:
                 return LatentAttention(spec, self.eps, self.dtype,
                                        name="attn")(n)
@@ -1018,7 +1341,8 @@ class SeqPolicyNet(nn.Module):
     step to add. With a multi-token-prediction module
     (``mtp``) and the next ids ``[B, S]`` given, the counts come with
     ``mtp_logits`` ``[B, S, vocab_held]``: at each position the
-    logits of the id AFTER the next."""
+    logits of the id AFTER the next — and, where the config weighs
+    the module's loss itself, ``mtp_loss_weight``."""
 
     layers: tuple           # of LayerSpec
     hidden: int
@@ -1031,6 +1355,11 @@ class SeqPolicyNet(nn.Module):
     dtype: jnp.dtype = jnp.bfloat16
     hyper: Hyper | None = None
     mtp: int = 0            # multi-token-prediction modules: 0 or 1
+    #: the module's block, where it is not the last layer's kind
+    mtp_layer: LayerSpec | None = None
+    #: the module's loss weight, where the config has a key for it
+    #: (else the trainer's own)
+    mtp_weight: float | None = None
 
     @nn.compact
     def __call__(self, ids: jax.Array, next_ids: jax.Array | None = None):
@@ -1104,13 +1433,16 @@ class SeqPolicyNet(nn.Module):
             x = enter(jnp.dot(joined, _weight(
                 self, "mtp_eh_proj", (2 * self.hidden, self.hidden),
                 self.dtype)))
-        x = block(self.layers[-1], "mtp_layer", x)
+        x = block(self.mtp_layer or self.layers[-1], "mtp_layer", x)
         with jax.named_scope(scopes.SEQ_MTP):
             n = RMSNorm(self.eps, name="mtp_norm")(leave(x)).astype(
                 self.dtype)
             mtp_logits = jnp.dot(n, head_w,
                                  preferred_element_type=jnp.float32)
-        return logits, extras(mtp_logits=mtp_logits)
+        more = {"mtp_logits": mtp_logits}
+        if self.mtp_weight is not None:
+            more["mtp_loss_weight"] = self.mtp_weight
+        return logits, extras(**more)
 
 
 def chosen_experts(kept: dict) -> dict:
@@ -1189,18 +1521,82 @@ def latent_layer_specs(kw: dict) -> tuple:
         for i in range(int(kw["layers_held"])))
 
 
+def ling_layer_specs(kw: dict) -> tuple:
+    """The held layers' ``LayerSpec``s, and the multi-token-
+    prediction block's, from the ``bailing_hybrid`` keys: held layer
+    ``i`` is the published layer ``i`` (the leading stage) — latent
+    attention where ``(i + 1) % layer_group_size == 0``, Kimi delta
+    attention elsewhere, the dense MLP in the ``first_k_dense_
+    replace`` leading layers. Latent attention has no low-rank query
+    path where ``q_lora_rank`` is null, plain rotary on its
+    ``qk_rope_head_dim``, and a gate per head; the MTP block is a
+    latent-attention + expert layer (``mtp_use_kda: false``)."""
+    if kw.get("rope_scaling"):
+        raise ValueError("SeqPolicy computes a bailing_hybrid spec's "
+                         "rotary unscaled (rope_scaling null) only; "
+                         f"the spec says {kw['rope_scaling']!r}")
+    held = int(kw["layers_held"])
+    for key in ("expert_swiglu_limit_list",
+                "share_expert_swiglu_limit_list"):
+        if any(kw.get(key, ())[:held]):
+            raise ValueError(
+                f"SeqPolicy computes an unclamped SwiGLU only; the "
+                f"spec's {key} is {kw[key][:held]!r} on the held "
+                f"layers")
+    heads, hd = int(kw["num_attention_heads"]), int(kw["head_dim"])
+    qk = int(kw["qk_nope_head_dim"]) + int(kw["qk_rope_head_dim"])
+    rope = Rope(kind="default", theta=float(kw["rope_theta"]),
+                dims=int(kw["qk_rope_head_dim"]))
+    latent = Latent(
+        q_rank=int(kw["q_lora_rank"] or 0),
+        kv_rank=int(kw["kv_lora_rank"]),
+        nope=int(kw["qk_nope_head_dim"]),
+        rope=int(kw["qk_rope_head_dim"]), value=int(kw["v_head_dim"]),
+        scale=1.0 / math.sqrt(qk), gate=True)
+    kda = Kda(key=hd, value=hd, conv=int(kw["short_conv_kernel_size"]),
+              lower=float(kw["kda_lower_bound"]))
+    period = int(kw["layer_group_size"])
+
+    def spec(i: int, softmax: bool) -> LayerSpec:
+        return LayerSpec(
+            heads=heads, window=0, rope=rope,
+            sparse=i >= int(kw["first_k_dense_replace"]),
+            latent=latent if softmax else None,
+            kda=None if softmax else kda)
+
+    return (tuple(spec(i, (i + 1) % period == 0) for i in range(held)),
+            spec(held, True))
+
+
 #: what this decoder computes one way only; a spec that says
 #: otherwise is refused rather than run as something else
 FIXED = {"attention_bias": False, "gating": "per-head",
          "moe_apply_router_weight_on_input": False,
          "moe_router_logit_softcapping": 0,
          "tie_word_embeddings": False,
-         # the xing4_0 keys: no group-limited routing, an expert
-         # layer wherever the dense ones end, the bias-steered choice
-         "hidden_act": "silu", "n_group": 1, "topk_group": 1,
-         "moe_layer_freq": 1, "topk_method": "noaux_tc"}
+         # the xing4_0 keys: an expert layer wherever the dense
+         # ones end, the bias-steered choice
+         "hidden_act": "silu", "moe_layer_freq": 1,
+         "topk_method": "noaux_tc",
+         # the bailing_hybrid keys: the safe gate with its lower
+         # bound and a full-rank decay projection in the delta
+         # layers, SiLU behind their convolutions, L2-normed queries
+         # and keys, as many key/value heads as query heads, the
+         # output norm per head; latent attention gated per head,
+         # its rotary key shared; the MTP block latent; a router
+         # bias; no biases, no nGPT, no further norms
+         "kda_safe_gate": True, "no_kda_lora": True,
+         "use_kda_lora": False, "linear_silu": True,
+         "use_qk_norm": True, "num_kv_heads_for_linear_attn": 0,
+         "group_norm_size": 1,
+         "gated_attention_proj_granularity_type": "head_wise",
+         "use_mla_nope": False, "rope_interleave": True,
+         "mtp_use_kda": False, "moe_router_enable_expert_bias": True,
+         "score_function": "sigmoid", "scale_router_input": False,
+         "use_bias": False, "use_qkv_bias": False, "use_nGPT": False,
+         "value_norm": False, "up_proj_norm": False}
 #: and what it computes in more than one way, by the spec's key
-CHOICES = {"model_type": ("laguna", "xing4_0"),
+CHOICES = {"model_type": ("laguna", "xing4_0", "bailing_hybrid"),
            "scoring_func": ("softmax", "sigmoid"),
            "num_nextn_predict_layers": (0, 1)}
 
@@ -1267,7 +1663,18 @@ class SeqPolicy(NeuralNetBase):
                 "width": int(kw["moe_intermediate_size"]),
                 "norm_topk": bool(kw["norm_topk_prob"]),
                 "scoring": kw.get("scoring_func", "softmax")}
-        if kw.get("model_type", "laguna") == "xing4_0":
+        model_type = kw.get("model_type", "laguna")
+        mtp_layer = mtp_weight = None
+        if model_type == "bailing_hybrid":
+            layers, mtp_layer = ling_layer_specs(kw)
+            mtp_weight = float(kw["mtp_loss_scaling_factor"])
+            ffn = dict(
+                held, num_experts=int(kw["num_experts"]),
+                shared_width=int(kw["num_shared_experts"])
+                * int(kw["moe_shared_expert_intermediate_size"]),
+                routed_scale=float(kw["routed_scaling_factor"]))
+            kv_heads, head_dim = int(kw["num_attention_heads"]), 0
+        elif model_type == "xing4_0":
             layers = latent_layer_specs(kw)
             ffn = dict(
                 held, num_experts=int(kw["n_routed_experts"]),
@@ -1285,6 +1692,10 @@ class SeqPolicy(NeuralNetBase):
                 routed_scale=float(kw["moe_routed_scaling_factor"]))
             kv_heads = int(kw["num_key_value_heads"])
             head_dim = int(kw["head_dim"])
+        if model_type != "laguna":
+            # DeepSeek-V3's group-limited choice (one group: none)
+            ffn.update(n_group=int(kw.get("n_group", 1)),
+                       topk_group=int(kw.get("topk_group", 1)))
         hyper = None
         if "hc_mult" in kw:
             hyper = Hyper(
@@ -1300,4 +1711,5 @@ class SeqPolicy(NeuralNetBase):
             dense_width=int(kw["intermediate_size"]),
             ffn=tuple(sorted(ffn.items())),
             eps=float(kw["rms_norm_eps"]), hyper=hyper,
-            mtp=int(kw.get("num_nextn_predict_layers", 0)))
+            mtp=int(kw.get("num_nextn_predict_layers", 0)),
+            mtp_layer=mtp_layer, mtp_weight=mtp_weight)

@@ -39,7 +39,20 @@ SEQ_ATTN_WINDOW = "seq.attn.window"
 #: a latent-attention layer's norms, low-rank projections, rotary,
 #: attention and output projection
 SEQ_ATTN_MLA = "seq.attn.mla"
-#: inside any of the three: the attention kernel alone (Pallas
+#: a Kimi-delta-attention layer's norm and residual, and the three
+#: parts below (a reader of ``seq.attn.kda`` sums them)
+SEQ_ATTN_KDA = "seq.attn.kda"
+#: inside it: the projections of queries, keys, values, decay and
+#: ``beta``, the three causal convolutions, SiLU, the L2 norms, the
+#: log-decay's gate
+SEQ_ATTN_KDA_PROJ = "seq.attn.kda.proj"
+#: from ``q, k, v, g, beta`` to ``o``: the chunked recurrence —
+#: the pairwise decays, the inverse, the scan over chunks — forward,
+#: recomputed forward and backward
+SEQ_ATTN_KDA_SCAN = "seq.attn.kda.scan"
+#: the per-head output norm, the channel-wise gate and ``o_proj``
+SEQ_ATTN_KDA_OUT = "seq.attn.kda.out"
+#: inside any of the softmax three: the attention kernel alone (Pallas
 #: ``splash_attention``, forward and backward kernels) where it runs
 SEQ_ATTN_KERNEL = "seq.attn.kernel"
 #: hyper-connections, three scopes side by side (a reader of

@@ -104,7 +104,8 @@ def policy_loss_fn(apply_fn, params, planes, actions, weights=None):
 
 #: weight of a multi-token-prediction module's loss beside the main
 #: one (DeepSeek-V3 report §4.2: 0.3 for most of pre-training). The
-#: trainer's, not the model's: no published config has a key for it
+#: trainer's, not the model's, unless the model's config has a key
+#: for it (``mtp_loss_weight`` beside ``mtp_logits``)
 MTP_LOSS_WEIGHT = 0.3
 
 
@@ -137,7 +138,8 @@ def _policy_loss(apply_fn, params, planes, actions, weights=None):
     next. Their cross-entropy against the next ids one to the left
     (a row's last position has no target; a game separator is a
     target like any other: attention crosses it too) is added
-    ``MTP_LOSS_WEIGHT`` times and returned as ``mtp_loss``."""
+    ``MTP_LOSS_WEIGHT`` times (or the model's own
+    ``mtp_loss_weight`` times) and returned as ``mtp_loss``."""
     if planes.ndim == 2:
         out = apply_fn(params, planes, actions)
     else:
@@ -145,6 +147,8 @@ def _policy_loss(apply_fn, params, planes, actions, weights=None):
     logits, extras = out if isinstance(out, tuple) else (out, {})
     extras = dict(extras)
     ahead = extras.pop("mtp_logits", None)
+    # a model whose config weighs the module's loss says so
+    mtp_weight = extras.pop("mtp_loss_weight", MTP_LOSS_WEIGHT)
     with jax.named_scope(scopes.TRAIN_LOSS):
         loss, acc = _masked_xent(logits, actions, weights)
         if ahead is not None:
@@ -152,7 +156,7 @@ def _policy_loss(apply_fn, params, planes, actions, weights=None):
             extras["mtp_loss"], _ = _masked_xent(
                 ahead, jnp.concatenate([actions[:, 1:], beyond], axis=1),
                 weights)
-            loss = loss + MTP_LOSS_WEIGHT * extras["mtp_loss"]
+            loss = loss + mtp_weight * extras["mtp_loss"]
     return loss, acc, extras
 
 

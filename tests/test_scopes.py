@@ -161,6 +161,38 @@ def xing_ops():
 
 
 @pytest.fixture(scope="module")
+def ling_ops():
+    """The COMPILED supervised train step of the move-sequence policy
+    built from a toy ``bailing_hybrid`` spec: a delta layer with the
+    dense MLP, a latent layer with experts under a group limit."""
+    from rocalphago_tpu.io.checkpoint import pack_rng
+    from rocalphago_tpu.models.seqpolicy import SeqPolicy
+    from rocalphago_tpu.training import sl
+
+    net = SeqPolicy(
+        board=SIZE, model_type="bailing_hybrid", vocab_size=32,
+        vocab_held=32, hidden_size=8, intermediate_size=8,
+        num_hidden_layers=2, layers_held=2, layer_group_size=2,
+        first_k_dense_replace=1, num_attention_heads=2, head_dim=4,
+        q_lora_rank=None, kv_lora_rank=4, qk_nope_head_dim=4,
+        qk_rope_head_dim=2, v_head_dim=4, rope_theta=10000,
+        short_conv_kernel_size=4, kda_lower_bound=-5, num_experts=4,
+        num_shared_experts=1, num_experts_per_tok=2,
+        moe_intermediate_size=4, moe_shared_expert_intermediate_size=4,
+        n_group=2, topk_group=1, norm_topk_prob=True,
+        routed_scaling_factor=2.5, scoring_func="sigmoid",
+        num_nextn_predict_layers=0, mtp_loss_scaling_factor=0,
+        experts_held=2, expert_offset=0, rms_norm_eps=1e-6)
+    tx = sl.make_optimizer(sl.SLConfig())
+    step = sl.make_train_step(net.module.apply, tx, SIZE, True)
+    state = sl.SLState(net.params, tx.init(net.params), jnp.int32(0),
+                       pack_rng(jax.random.key(0)))
+    ids = jnp.zeros((2, 8), jnp.int32)
+    return op_names(jax.jit(step).lower(
+        state, ids, ids).compile().as_text())
+
+
+@pytest.fixture(scope="module")
 def ply_ops():
     from rocalphago_tpu.search.selfplay import _make_ply
 
@@ -223,10 +255,16 @@ XING = [scopes.SEQ_ATTN_MLA, scopes.SEQ_MHC_COEFF,
         scopes.SEQ_MHC_SINKHORN, scopes.SEQ_MHC_MIX, scopes.SEQ_MTP]
 
 
+#: what a ``bailing_hybrid`` spec adds: the delta layers' scope and
+#: the three parts inside it
+LING = [scopes.SEQ_ATTN_KDA, scopes.SEQ_ATTN_KDA_PROJ,
+        scopes.SEQ_ATTN_KDA_SCAN, scopes.SEQ_ATTN_KDA_OUT]
+
+
 def test_every_constant_has_a_case():
     # the attention kernel's scope exists only in a program traced
     # for a TPU: tests/test_seqpolicy.py lowers it there
-    assert sorted(TRAIN + PLY + ENCODE + EVAL + MCTS + SEQ + XING
+    assert sorted(TRAIN + PLY + ENCODE + EVAL + MCTS + SEQ + XING + LING
                   + [scopes.SEQ_ATTN_KERNEL]) == sorted(scopes.ALL)
     assert len(set(scopes.ALL)) == len(scopes.ALL)
 
@@ -278,6 +316,43 @@ def test_xing_scopes_lie_side_by_side(xing_ops):
     assert block and not any(scopes.SEQ_MTP in op.split("/")
                              for op in block)
     assert any(scopes.SEQ_ATTN_MLA in op.split("/") for op in block)
+
+
+@pytest.mark.parametrize("name", LING + [
+    scopes.SEQ_ATTN_MLA, scopes.SEQ_ROUTER, scopes.SEQ_EXPERTS,
+    scopes.SEQ_DENSE_FFN, scopes.SEQ_HEAD, scopes.TRAIN_LOSS])
+def test_ling_step_scope_survives_the_compile(ling_ops, name):
+    assert has(ling_ops, name), sorted(set(ling_ops))[:40]
+
+
+@pytest.mark.parametrize("name", LING)
+def test_ling_scopes_name_the_backward_pass_too(ling_ops, name):
+    mine = [op for op in ling_ops if name in op.split("/")]
+    assert any("transpose(jvp(SeqPolicyNet))" in op for op in mine)
+    assert any("transpose(" not in op for op in mine)
+
+
+def test_ling_scopes_lie_side_by_side(ling_ops):
+    """The delta layer's three parts lie inside ``seq.attn.kda`` and
+    nowhere else, beside each other; no other top-level ``seq.*``
+    scope holds or is held by them, so the by-scope account still
+    partitions; the group limit runs under the router's scope."""
+    parts = LING[1:]
+    tops = [n for n in scopes.ALL if n.startswith("seq.")
+            and n != scopes.SEQ_ATTN_KERNEL and n not in parts
+            and not n.startswith(scopes.SEQ_EXPERTS + ".")]
+    for op in ling_ops:
+        path = op.split("/")
+        assert len([n for n in tops if n in path]) <= 1, op
+        inside = [n for n in parts if n in path]
+        assert len(inside) <= 1, op
+        if inside:
+            assert scopes.SEQ_ATTN_KDA in path, op
+    scan = [op for op in ling_ops if scopes.SEQ_ATTN_KDA_SCAN
+            in op.split("/")]
+    assert any("while" in op for op in scan)     # the loop over chunks
+    assert any(scopes.SEQ_ROUTER in op.split("/") and "top_k" in op
+               for op in ling_ops)
 
 
 def test_train_loss_is_scoped_forward_and_backward(train_ops):

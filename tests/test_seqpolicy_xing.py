@@ -746,7 +746,7 @@ def test_a_laguna_spec_still_builds_the_network_it_built():
 
 
 @pytest.mark.parametrize("key,value", [
-    ("n_group", 8), ("topk_group", 4), ("topk_method", "greedy"),
+    ("topk_method", "greedy"),
     ("scoring_func", "tanh"), ("num_nextn_predict_layers", 2),
     ("hidden_act", "gelu"), ("model_type", "deepseek_v3"),
     ("moe_layer_freq", 2)])
@@ -755,6 +755,18 @@ def test_a_spec_that_asks_for_what_is_not_computed_is_refused(key,
     with pytest.raises(ValueError, match=key):
         SeqPolicy(board=SIZE, init_weights=False,
                   **dict(TOY, **{key: value}))
+
+
+@pytest.mark.parametrize("key,value", [("n_group", 4),
+                                       ("topk_group", 2)])
+def test_a_group_limit_is_taken_since_pr_32(key, value):
+    """``FIXED`` refused ``n_group != 1`` until the decoder computed
+    the group-limited choice (``tests/test_seqpolicy_ling.py`` holds
+    it to the reference): an xing4_0 spec with groups now builds a
+    router with them."""
+    spec = dict(TOY, n_group=4, topk_group=2)
+    net = SeqPolicy(board=SIZE, init_weights=False, **spec)
+    assert dict(net.module.ffn)[key] == value
 
 
 def test_another_rotary_scaling_is_refused():
