@@ -422,14 +422,27 @@ def _attention(q, k, v, window: int):
 #     S ← Diag(e^{G_C}) S + (K ⊙ e^{G_C − G})ᵀ V'
 #
 # Everything but the last three lines is the same work for every
-# chunk and runs for all of them at once; the last three are a
-# ``lax.scan`` over the chunks. ``e^−G`` reaches ``e^{5C}`` and
-# float32 ends at ``e^88``, so the two pairwise products are taken
-# sixteen rows at a time relative to the first of them: rows scaled
-# by ``e^{G_r − G_0} ≤ 1``, columns by ``e^{G_0 − G_c}``, which is at
-# most ``e^{5·15}`` on the rows' own sixteen and at most 1 before
-# them — what the config's lower bound of −5 is for. Exact: nothing
-# is clamped.
+# chunk and runs for all of them at once, on arrays ``[N, B, H, C,
+# ·]`` — the chunks' axis first from the first reshape, which is how
+# the loop reads them; the last three are a ``lax.scan`` over the
+# chunks. ``e^−G`` reaches ``e^{5C}`` and float32 ends at ``e^88``,
+# so the two pairwise products are taken sixteen rows at a time
+# relative to the first of them: rows scaled by ``e^{G_r − G_0} ≤
+# 1``, columns by ``e^{G_0 − G_c}``, which is at most ``e^{5·15}`` on
+# the rows' own sixteen and at most 1 before them — what the config's
+# lower bound of −5 is for. The ``C / 16`` blocks of rows are a BATCH
+# axis of one product, each block against all ``C`` columns: no
+# slice, pad or concatenation, whose cotangents are pads and slices
+# again. On the columns after a block ``G_0 − G_c`` has no bound, so
+# there the EXPONENT is set to 0 before the exponential is taken
+# (the column enters as plain ``k``): those entries lie above the
+# diagonal, the mask ``r ≥ c`` discards them, and neither they nor
+# their gradient ever hold an overflow. Exact: nothing is clamped.
+# The products' backward (:func:`_pairwise_decayed_bwd`) keeps ``q,
+# k, G`` alone and takes the exponentials again; towards the columns
+# it is the same trick from the other side — the blocks of COLUMNS a
+# batch axis, each against all ``C`` rows relative to the block's
+# last column.
 
 #: tokens of a chunk of the scan (the published kernels' 64; a
 #: shorter row is one chunk)
@@ -474,29 +487,94 @@ unit_lower_inverse.defvjp(_unit_lower_inverse_fwd,
                           _unit_lower_inverse_bwd)
 
 
+def _blocks(gc, sub: int):
+    """``gc [..., C, d]`` as ``[..., C // sub, sub, d]``, and for
+    block ``j`` (axis −3) a mask ``[C // sub, C, 1]`` of the positions
+    of the chunk before the block's end, and one of those from its
+    start on."""
+    *lead, c, d = gc.shape
+    nb = c // sub
+    at = jnp.arange(c)[:, None]
+    start = (jnp.arange(nb) * sub)[:, None, None]
+    return gc.reshape(*lead, nb, sub, d), at < start + sub, at >= start
+
+
+def _decayed_back(k, gc, g4, upto):
+    """The columns for each block of rows ``[..., C // sub, C, d]``:
+    ``k_c e^{G_0 − G_c}`` relative to the block's first row on the
+    columns up to the block's end, plain ``k_c`` after it."""
+    return k[..., None, :, :] * jnp.exp(jnp.where(
+        upto, g4[..., :1, :] - gc[..., None, :, :], 0.0))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
 def _pairwise_decayed(q, k, gc, sub: int):
     """``Σ_d x_{r,d} k_{c,d} e^{G_{r,d} − G_{c,d}}`` for ``x`` = ``q``
     and ``x`` = ``k``, ``[..., C, C]`` each, right wherever ``r ≥ c``
     (finite elsewhere, and masked by the caller): ``q, k, gc [..., C,
     d_k]`` float32, ``gc`` the inclusive sums of the log-decay inside
-    the chunk. ``sub`` rows at a time against the columns up to their
-    last, rows and columns scaled relative to the first of the rows
-    (module comment)."""
-    c = q.shape[-2]
-    of_q, of_k = [], []
-    for lo in range(0, c, sub):
-        hi = lo + sub
-        first = gc[..., lo:lo + 1, :]
-        into = jnp.exp(gc[..., lo:hi, :] - first)
-        back = k[..., :hi, :] * jnp.exp(first - gc[..., :hi, :])
-        rows = jnp.concatenate([q[..., lo:hi, :] * into,
-                                k[..., lo:hi, :] * into], axis=-2)
-        both = jnp.einsum("...rd,...cd->...rc", rows, back,
+    the chunk. The ``C // sub`` blocks of ``sub`` rows are a batch
+    axis of one product against all ``C`` columns, rows and columns
+    scaled relative to the first of the rows (module comment)."""
+    *lead, c, _ = gc.shape
+    g4, upto, _ = _blocks(gc, sub)
+    into = jnp.exp(g4 - g4[..., :1, :])                 # ≤ 1
+    back = _decayed_back(k, gc, g4, upto)
+
+    def against(x):
+        return jnp.einsum("...jrd,...jcd->...jrc",
+                          x.reshape(g4.shape) * into, back,
+                          precision=KDA_EXACT).reshape(*lead, c, c)
+
+    return against(q), against(k)
+
+
+def _pairwise_decayed_fwd(q, k, gc, sub):
+    return _pairwise_decayed(q, k, gc, sub), (q, k, gc)
+
+
+def _pairwise_decayed_bwd(sub, kept, cotangents):
+    """With ``D`` a product's cotangent where ``r ≥ c`` and 0 above:
+    ``dx_r = Σ_c D_{rc} k_c e^{G_r − G_c}`` is the forward's product
+    with ``D`` in the rows' place; the columns' ``dk_c = Σ_r D_{rc}
+    x_r e^{G_r − G_c}`` takes the blocks of columns as the batch axis,
+    relative to each block's LAST column — columns × ``e^{G_last −
+    G_c} ≤ 1``, rows × ``e^{G_r − G_last}``: at most ``e^{5·15}``
+    inside the block, at most 1 after it and the exponent zeroed
+    before it, where ``D`` is 0; and ``dG = q dq + k (dk_rows −
+    dk_columns)`` elementwise. No ``[..., C // sub, C, d]`` array is
+    kept or summed over its blocks."""
+    q, k, gc = kept
+    *lead, c, _ = gc.shape
+    nb = c // sub
+    g4, upto, since = _blocks(gc, sub)
+    lower = jnp.arange(c)[:, None] >= jnp.arange(c)[None, :]
+    d_q, d_k = (jnp.where(lower, x, 0.0) for x in cotangents)
+    into = jnp.exp(g4 - g4[..., :1, :])
+    back = _decayed_back(k, gc, g4, upto)
+
+    def to_rows(da):
+        return (into * jnp.einsum(
+            "...jrc,...jcd->...jrd", da.reshape(*lead, nb, sub, c), back,
+            precision=KDA_EXACT)).reshape(gc.shape)
+
+    last = g4[..., -1:, :]
+    forth = jnp.exp(jnp.where(since, gc[..., None, :, :] - last, 0.0))
+
+    def to_columns(da, x):
+        da = jnp.moveaxis(da.reshape(*lead, c, nb, sub), -3, -2)
+        return jnp.einsum("...irc,...ird->...icd", da,
+                          x[..., None, :, :] * forth,
                           precision=KDA_EXACT)
-        both = jnp.pad(both, [(0, 0)] * (both.ndim - 1) + [(0, c - hi)])
-        of_q.append(both[..., :sub, :])
-        of_k.append(both[..., sub:, :])
-    return jnp.concatenate(of_q, axis=-2), jnp.concatenate(of_k, axis=-2)
+
+    dq, dk_rows = to_rows(d_q), to_rows(d_k)
+    dk_columns = (jnp.exp(last - g4) * (
+        to_columns(d_q, q) + to_columns(d_k, k))).reshape(gc.shape)
+    return (dq, dk_rows + dk_columns,
+            q * dq + k * (dk_rows - dk_columns))
+
+
+_pairwise_decayed.defvjp(_pairwise_decayed_fwd, _pairwise_decayed_bwd)
 
 
 def kda_chunked(q, k, v, g, beta, dtype=jnp.bfloat16):
@@ -506,7 +584,11 @@ def kda_chunked(q, k, v, g, beta, dtype=jnp.bfloat16):
     ``o [B, S, H, d_v]``. The log-decay, its sums and exponentials
     and the carried state are float32; the products with the state
     take their operands in ``dtype`` (every one of them at most 1 in
-    size: no ``e^−G`` among them) and add in float32."""
+    size: no ``e^−G`` among them) and add in float32. Every
+    intra-chunk array is chunk-major, ``[N, B, H, C, ·]``: moved
+    there once in its storage type, and the result moved back once;
+    the pairwise decays' blocks of rows are a batch axis (module
+    comment)."""
     b, s_len, h, dk = q.shape
     dv = v.shape[-1]
     c = min(KDA_CHUNK, s_len)
@@ -518,13 +600,14 @@ def kda_chunked(q, k, v, g, beta, dtype=jnp.bfloat16):
     f32 = jnp.float32
 
     def chunks(x):
-        """``[B, S, H, d]`` → float32 ``[B, H, N, C, d]``."""
-        return x.astype(f32).reshape(b, n, c, h, -1).transpose(
-            0, 3, 1, 2, 4)
+        """``[B, S, H, d]`` → ``[N, B, H, C, d]``, in its own type."""
+        return x.reshape(b, n, c, h, -1).transpose(1, 0, 3, 2, 4)
 
-    qc, kc, vc = chunks(q), chunks(k), chunks(v)
-    gc = jnp.cumsum(chunks(g), axis=3)
-    bc = chunks(beta[..., None])                        # [B, H, N, C, 1]
+    # q and k enter float32 once, so their cotangents add up in
+    # float32; v meets only a product that takes it in ``dtype``
+    qc, kc, vc = chunks(q).astype(f32), chunks(k).astype(f32), chunks(v)
+    gc = jnp.cumsum(chunks(g.astype(f32)), axis=3)
+    bc = chunks(beta.astype(f32)[..., None])            # [N, B, H, C, 1]
     a_q, a_k = _pairwise_decayed(qc, kc, gc, sub)
     row = jnp.arange(c)[:, None]
     col = jnp.arange(c)[None, :]
@@ -538,17 +621,14 @@ def kda_chunked(q, k, v, g, beta, dtype=jnp.bfloat16):
         return jnp.einsum(spec, x.astype(dtype), y.astype(dtype),
                           preferred_element_type=f32)
 
-    def by_chunk(x, to):
-        """The chunks' axis first, for the loop; its products'
-        operands in the compute type once, not once a turn."""
-        return jnp.moveaxis(x, 2, 0).astype(to)
-
+    # the loop's operands: its products' in the compute type once,
+    # not once a turn
     per_chunk = (
-        by_chunk(product("...rc,...cd->...rd", t, kc * grown), dtype),
-        by_chunk(product("...rc,...cd->...rd", t, vc), f32),
-        by_chunk(qc * grown, dtype), by_chunk(a_q, dtype),
-        by_chunk(kc * jnp.exp(last - gc), dtype),
-        by_chunk(jnp.exp(last[..., 0, :]), f32))
+        product("...rc,...cd->...rd", t, kc * grown).astype(dtype),
+        product("...rc,...cd->...rd", t, vc),
+        (qc * grown).astype(dtype), a_q.astype(dtype),
+        (kc * jnp.exp(last - gc)).astype(dtype),
+        jnp.exp(last[..., 0, :]))
 
     def step(state, x):
         w, u, q_in, a_q, k_out, decay = x

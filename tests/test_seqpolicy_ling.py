@@ -308,6 +308,79 @@ def test_without_decay_or_correction_it_is_causal_linear_attention(
     assert reference.relative_error(got, want) < 1e-3
 
 
+def pairwise_by_definition(q, k, gc, d_q, d_k):
+    """``A_x[r, c] = Σ_d x_{r,d} k_{c,d} e^{G_{r,d} − G_{c,d}}`` where
+    ``r ≥ c`` for ``x`` = ``q`` and ``x`` = ``k``, and the gradients
+    of ``Σ d_q A_q + Σ d_k A_k`` over those entries, in float64 on
+    the host: ``q, k, gc [C, d]``, ``d_q, d_k [C, C]``."""
+    q, k, gc, d_q, d_k = (np.asarray(x, np.float64)
+                          for x in (q, k, gc, d_q, d_k))
+    lower = np.tril(np.ones(d_q.shape, bool))
+    decay = np.exp(np.where(lower[..., None],
+                            gc[:, None, :] - gc[None, :, :], -np.inf))
+    a_q = np.einsum("rd,cd,rcd->rc", q, k, decay)
+    a_k = np.einsum("rd,cd,rcd->rc", k, k, decay)
+    dq = np.einsum("rc,cd,rcd->rd", d_q, k, decay)
+    dk_rows = np.einsum("rc,cd,rcd->rd", d_k, k, decay)
+    dk_columns = (np.einsum("rc,rd,rcd->cd", d_q, q, decay)
+                  + np.einsum("rc,rd,rcd->cd", d_k, k, decay))
+    return (a_q, a_k), (dq, dk_rows + dk_columns,
+                        q * dq + k * (dk_rows - dk_columns))
+
+
+@pytest.mark.parametrize("gates", ["drawn", "at_their_bound"])
+@pytest.mark.parametrize("chunk,sub", [(8, 4), (16, 16), (64, 16)])
+def test_the_pairwise_decays_are_their_definition(chunk, sub, gates):
+    """The blocks of rows as a batch axis of one product, and its
+    hand-written backward, against the definition in float64. With
+    every gate at −5 + 1e-3 the columns AFTER a block of rows would
+    be scaled by up to ``e^{5·48}``: the exponent is zeroed there, so
+    value and gradient stay finite."""
+    ks = jax.random.split(jax.random.key(12), 5)
+    q = seqpolicy.l2_normed(jax.random.normal(ks[0], (chunk, 8)))
+    k = seqpolicy.l2_normed(jax.random.normal(ks[1], (chunk, 8)))
+    if gates == "drawn":
+        g = -5.0 * jax.random.uniform(ks[2], (chunk, 8))
+    else:
+        g = jnp.full((chunk, 8), -5.0 + 1e-3)
+    gc = jnp.cumsum(g, axis=0)
+    lower = jnp.tril(jnp.ones((chunk, chunk), bool))
+    weigh = [jax.random.normal(key, (chunk, chunk)) for key in ks[3:]]
+
+    def weighed(q, k, gc):
+        both = seqpolicy._pairwise_decayed(q, k, gc, sub)
+        # the caller's mask: what lies above the diagonal is dropped
+        return sum((jnp.where(lower, a, 0.0) * w).sum()
+                   for a, w in zip(both, weigh)), both
+
+    (_, got), grads = jax.jit(jax.value_and_grad(
+        weighed, argnums=(0, 1, 2), has_aux=True))(q, k, gc)
+    want, want_grads = pairwise_by_definition(q, k, gc, *weigh)
+    for a, b in zip(got, want):
+        assert np.isfinite(np.asarray(a)).all()
+        assert reference.relative_error(
+            jnp.where(lower, a, 0.0), np.tril(b)) < 2e-6
+    # the diagonal's two shares of dG cancel, and at the bound what
+    # is left is e^−5 of them: float32 keeps five digits of it
+    for a, b, limit in zip(grads, want_grads, (2e-6, 2e-6, 5e-5)):
+        assert np.isfinite(np.asarray(a)).all()
+        assert reference.relative_error(a, b) < limit
+
+
+def test_the_scans_forward_builds_nothing_by_pads_or_concatenations():
+    """Every intra-chunk array is written once in the form its
+    consumer reads: the lowered forward holds no ``pad`` and no
+    ``concatenate`` (their cotangents are slices and pads again)."""
+    shaped = jax.ShapeDtypeStruct
+    rows = shaped((1, 256, 2, 16), jnp.bfloat16)
+    text = jax.jit(seqpolicy.kda_chunked).lower(
+        rows, rows, rows, shaped((1, 256, 2, 16), jnp.float32),
+        shaped((1, 256, 2), jnp.float32)).as_text()
+    assert "stablehlo.dot_general" in text
+    assert "stablehlo.pad" not in text
+    assert "stablehlo.concatenate" not in text
+
+
 def test_the_inverse_is_the_inverse_and_so_is_its_gradient():
     a = jnp.tril(jax.random.normal(jax.random.key(10), (3, 16, 16)), -1)
     eye = jnp.eye(16)
