@@ -715,20 +715,29 @@ class GatedAttention(nn.Module):
         spec, hd = self.spec, self.head_dim
         b, s_len, d = x.shape
         h, g = spec.heads, self.kv_heads
-        q = jnp.dot(x, _weight(self, "q_proj", (d, h * hd), self.dtype))
-        k = jnp.dot(x, _weight(self, "k_proj", (d, g * hd), self.dtype))
-        v = jnp.dot(x, _weight(self, "v_proj", (d, g * hd), self.dtype))
-        gate = jax.nn.sigmoid(jnp.dot(
-            x, _weight(self, "gate_proj", (d, h), self.dtype)
-        ).astype(jnp.float32))
-        q = apply_rope(q.reshape(b, s_len, h, hd), spec.rope,
-                       1.0 / math.sqrt(hd))
-        k = apply_rope(k.reshape(b, s_len, g, hd), spec.rope)
+        with jax.named_scope(scopes.SEQ_ATTN_PROJ):
+            q = jnp.dot(x, _weight(self, "q_proj", (d, h * hd),
+                                   self.dtype))
+            k = jnp.dot(x, _weight(self, "k_proj", (d, g * hd),
+                                   self.dtype))
+            v = jnp.dot(x, _weight(self, "v_proj", (d, g * hd),
+                                   self.dtype))
+        with jax.named_scope(scopes.SEQ_ATTN_GATE):
+            gate = jax.nn.sigmoid(jnp.dot(
+                x, _weight(self, "gate_proj", (d, h), self.dtype)
+            ).astype(jnp.float32))
+        with jax.named_scope(scopes.SEQ_ATTN_ROPE):
+            q = apply_rope(q.reshape(b, s_len, h, hd), spec.rope,
+                           1.0 / math.sqrt(hd))
+            k = apply_rope(k.reshape(b, s_len, g, hd), spec.rope)
         v = v.reshape(b, s_len, g, hd)
         a = _attention(q, k, v, spec.window)
-        a = (a * gate[..., None]).astype(self.dtype)
-        return jnp.dot(a.reshape(b, s_len, h * hd),
-                       _weight(self, "o_proj", (h * hd, d), self.dtype))
+        with jax.named_scope(scopes.SEQ_ATTN_GATE):
+            a = (a * gate[..., None]).astype(self.dtype)
+        with jax.named_scope(scopes.SEQ_ATTN_OUT):
+            return jnp.dot(
+                a.reshape(b, s_len, h * hd),
+                _weight(self, "o_proj", (h * hd, d), self.dtype))
 
 
 class LatentAttention(nn.Module):
@@ -746,44 +755,50 @@ class LatentAttention(nn.Module):
         spec, lat = self.spec, self.spec.latent
         b, s_len, d = x.shape
         h, qk = spec.heads, lat.nope + lat.rope
-        if lat.q_rank:
-            c_q = RMSNorm(self.eps, name="q_a_norm")(jnp.dot(
-                x, _weight(self, "q_a_proj", (d, lat.q_rank),
-                           self.dtype)))
-            q = jnp.dot(c_q.astype(self.dtype), _weight(
-                self, "q_b_proj", (lat.q_rank, h * qk), self.dtype))
-        else:
-            q = jnp.dot(x, _weight(self, "q_proj", (d, h * qk),
-                                   self.dtype))
-        q = q.reshape(b, s_len, h, qk)
-        kv_a = jnp.dot(x, _weight(
-            self, "kv_a_proj", (d, lat.kv_rank + lat.rope), self.dtype))
-        c_kv = RMSNorm(self.eps, name="kv_a_norm")(
-            kv_a[..., :lat.kv_rank])
-        kv = jnp.dot(
-            c_kv.astype(self.dtype),
-            _weight(self, "kv_b_proj",
-                    (lat.kv_rank, h * (lat.nope + lat.value)),
-                    self.dtype)
-        ).reshape(b, s_len, h, lat.nope + lat.value)
-        # the scores' scale goes on the queries, in float32
-        q_nope = (q[..., :lat.nope].astype(jnp.float32)
-                  * lat.scale).astype(self.dtype)
-        q_pe = apply_rope(q[..., lat.nope:], spec.rope, lat.scale)
-        k_pe = apply_rope(kv_a[:, :, None, lat.kv_rank:], spec.rope)
-        q = jnp.concatenate([q_nope, q_pe], axis=-1)
-        k = jnp.concatenate(
-            [kv[..., :lat.nope],
-             jnp.broadcast_to(k_pe, (b, s_len, h, lat.rope))], axis=-1)
+        with jax.named_scope(scopes.SEQ_ATTN_PROJ):
+            if lat.q_rank:
+                c_q = RMSNorm(self.eps, name="q_a_norm")(jnp.dot(
+                    x, _weight(self, "q_a_proj", (d, lat.q_rank),
+                               self.dtype)))
+                q = jnp.dot(c_q.astype(self.dtype), _weight(
+                    self, "q_b_proj", (lat.q_rank, h * qk), self.dtype))
+            else:
+                q = jnp.dot(x, _weight(self, "q_proj", (d, h * qk),
+                                       self.dtype))
+            q = q.reshape(b, s_len, h, qk)
+            kv_a = jnp.dot(x, _weight(
+                self, "kv_a_proj", (d, lat.kv_rank + lat.rope),
+                self.dtype))
+            c_kv = RMSNorm(self.eps, name="kv_a_norm")(
+                kv_a[..., :lat.kv_rank])
+            kv = jnp.dot(
+                c_kv.astype(self.dtype),
+                _weight(self, "kv_b_proj",
+                        (lat.kv_rank, h * (lat.nope + lat.value)),
+                        self.dtype)
+            ).reshape(b, s_len, h, lat.nope + lat.value)
+        with jax.named_scope(scopes.SEQ_ATTN_ROPE):
+            # the scores' scale goes on the queries, in float32
+            q_nope = (q[..., :lat.nope].astype(jnp.float32)
+                      * lat.scale).astype(self.dtype)
+            q_pe = apply_rope(q[..., lat.nope:], spec.rope, lat.scale)
+            k_pe = apply_rope(kv_a[:, :, None, lat.kv_rank:], spec.rope)
+            q = jnp.concatenate([q_nope, q_pe], axis=-1)
+            k = jnp.concatenate(
+                [kv[..., :lat.nope],
+                 jnp.broadcast_to(k_pe, (b, s_len, h, lat.rope))],
+                axis=-1)
         a = _attention(q, k, kv[..., lat.nope:], spec.window)
         if lat.gate:
-            gate = jax.nn.sigmoid(jnp.dot(
-                x, _weight(self, "gate_proj", (d, h), self.dtype)
-            ).astype(jnp.float32))
-            a = a * gate[..., None]
-        return jnp.dot(
-            a.astype(self.dtype).reshape(b, s_len, h * lat.value),
-            _weight(self, "o_proj", (h * lat.value, d), self.dtype))
+            with jax.named_scope(scopes.SEQ_ATTN_GATE):
+                gate = jax.nn.sigmoid(jnp.dot(
+                    x, _weight(self, "gate_proj", (d, h), self.dtype)
+                ).astype(jnp.float32))
+                a = a * gate[..., None]
+        with jax.named_scope(scopes.SEQ_ATTN_OUT):
+            return jnp.dot(
+                a.astype(self.dtype).reshape(b, s_len, h * lat.value),
+                _weight(self, "o_proj", (h * lat.value, d), self.dtype))
 
 
 def _a_log_init(key, shape, dtype=jnp.float32):
@@ -819,34 +834,38 @@ class KimiDeltaAttention(nn.Module):
         h, dk, dv = spec.heads, kda.key, kda.value
         f32 = jnp.float32
 
-        def mixed(name: str, width: int):
-            """Projection, convolution, SiLU: float32 ``[B, S, H,
-            width]``."""
+        def mixed(name: str, width: int, norm=lambda y: y):
+            """Projection, convolution, SiLU and ``norm`` in float32:
+            ``[B, S, H, width]`` in the compute type."""
             y = jnp.dot(x, _weight(self, f"{name}_proj", (d, h * width),
                                    self.dtype))
             taps = self.param(f"{name}_conv",
                               nn.initializers.normal(0.02),
                               (kda.conv, h * width), f32)
-            return jax.nn.silu(causal_conv(y, taps)).reshape(
-                b, s_len, h, width)
+            with jax.named_scope(scopes.SEQ_ATTN_KDA_CONV):
+                y = jax.nn.silu(causal_conv(y, taps)).reshape(
+                    b, s_len, h, width)
+            with jax.named_scope(scopes.SEQ_ATTN_KDA_QKNORM):
+                return norm(y).astype(self.dtype)
 
         with jax.named_scope(scopes.SEQ_ATTN_KDA_PROJ):
-            q = (l2_normed(mixed("q", dk)) / math.sqrt(dk)).astype(
-                self.dtype)
-            k = l2_normed(mixed("k", dk)).astype(self.dtype)
-            v = mixed("v", dv).astype(self.dtype)
-            beta = jax.nn.sigmoid(jnp.dot(
-                x, _weight(self, "b_proj", (d, h), self.dtype),
-                preferred_element_type=f32))
-            # the log-decay: lower · σ(e^{A_log} (x W_f + dt_bias)),
-            # in (lower, 0) whatever the weights
-            rate = jnp.exp(self.param("A_log", _a_log_init, (h,), f32))
-            raw = jnp.dot(
-                x, _weight(self, "f_proj", (d, h * dk), self.dtype),
-                preferred_element_type=f32
-            ) + self.param("dt_bias", _dt_bias_init, (h * dk,), f32)
-            g = kda.lower * jax.nn.sigmoid(
-                rate[:, None] * raw.reshape(b, s_len, h, dk))
+            q = mixed("q", dk, lambda y: l2_normed(y) / math.sqrt(dk))
+            k = mixed("k", dk, l2_normed)
+            v = mixed("v", dv)
+            with jax.named_scope(scopes.SEQ_ATTN_KDA_DECAY):
+                beta = jax.nn.sigmoid(jnp.dot(
+                    x, _weight(self, "b_proj", (d, h), self.dtype),
+                    preferred_element_type=f32))
+                # the log-decay: lower · σ(e^{A_log} (x W_f +
+                # dt_bias)), in (lower, 0) whatever the weights
+                rate = jnp.exp(self.param("A_log", _a_log_init, (h,),
+                                          f32))
+                raw = jnp.dot(
+                    x, _weight(self, "f_proj", (d, h * dk), self.dtype),
+                    preferred_element_type=f32
+                ) + self.param("dt_bias", _dt_bias_init, (h * dk,), f32)
+                g = kda.lower * jax.nn.sigmoid(
+                    rate[:, None] * raw.reshape(b, s_len, h, dk))
         with jax.named_scope(scopes.SEQ_ATTN_KDA_SCAN):
             o = kda_chunked(q, k, v, g, beta, self.dtype)
         with jax.named_scope(scopes.SEQ_ATTN_KDA_OUT):

@@ -32,7 +32,11 @@ TRAIN_UPDATE = "train.update"
 #: the embedding lookup (backward: the scatter of its gradient)
 SEQ_EMBED = "seq.embed"
 #: a full-attention layer's norm, projections, rotary, gate,
-#: attention and output projection
+#: attention and output projection. Directly under it (and under the
+#: two below): the input norm, the residual add and the ``[B,H,S,dv]``
+#: → ``[B,S,H,dv]`` transpose of the kernel's output; the rest lies
+#: in the five parts ``seq.attn.kernel`` / ``.proj`` / ``.rope`` /
+#: ``.gate`` / ``.out``
 SEQ_ATTN_FULL = "seq.attn.full"
 #: the same of a sliding-window layer
 SEQ_ATTN_WINDOW = "seq.attn.window"
@@ -44,17 +48,47 @@ SEQ_ATTN_MLA = "seq.attn.mla"
 SEQ_ATTN_KDA = "seq.attn.kda"
 #: inside it: the projections of queries, keys, values, decay and
 #: ``beta``, the three causal convolutions, SiLU, the L2 norms, the
-#: log-decay's gate
+#: log-decay's gate. Directly under it: the three products ``q_proj``
+#: / ``k_proj`` / ``v_proj``; the rest lies in the three parts below
 SEQ_ATTN_KDA_PROJ = "seq.attn.kda.proj"
+#: inside ``.kda.proj``: ``causal_conv`` (a pad, four shifted slices,
+#: the taps) and SiLU, for q, k and v
+SEQ_ATTN_KDA_CONV = "seq.attn.kda.proj.conv"
+#: the two L2 norms, the queries' ``1/√d_k`` and the casts of q, k, v
+#: to the compute type
+SEQ_ATTN_KDA_QKNORM = "seq.attn.kda.proj.norm"
+#: ``beta`` (its product and sigmoid) and the log-decay ``g``:
+#: ``f_proj``, ``dt_bias``, ``A_log``, the sigmoid
+SEQ_ATTN_KDA_DECAY = "seq.attn.kda.proj.decay"
 #: from ``q, k, v, g, beta`` to ``o``: the chunked recurrence —
 #: the pairwise decays, the inverse, the scan over chunks — forward,
 #: recomputed forward and backward
 SEQ_ATTN_KDA_SCAN = "seq.attn.kda.scan"
 #: the per-head output norm, the channel-wise gate and ``o_proj``
 SEQ_ATTN_KDA_OUT = "seq.attn.kda.out"
-#: inside any of the softmax three: the attention kernel alone (Pallas
-#: ``splash_attention``, forward and backward kernels) where it runs
+#: inside any of the softmax three, where the kernel runs: Pallas
+#: ``splash_attention`` (forward and backward kernels) and the
+#: ``[B,S,H,d]`` → ``[B,H,S,d]`` transposes of q, k, v in front of it
 SEQ_ATTN_KERNEL = "seq.attn.kernel"
+# The other four parts of a softmax layer (``GatedAttention``,
+# ``LatentAttention``), each inside one of the three layer scopes and
+# beside the kernel's. A fusion reads under the scope of the dot
+# inside it, else its root's (``chipbench/scopes.py::resolve``): an
+# elementwise pass XLA fuses into a product reads under the product's
+# name
+#: the MXU work in front of the kernel: the products from ``x`` (q, k,
+#: v) and their weights' casts; latent attention's low-rank products
+#: and their two norms
+SEQ_ATTN_PROJ = "seq.attn.proj"
+#: the float32 rotary passes over ``[B, S, H, d]``; in latent
+#: attention also the scaling of ``q_nope``, the broadcast of ``k_pe``
+#: to the heads and the two concatenations that build q and k
+SEQ_ATTN_ROPE = "seq.attn.rope"
+#: the per-head gate: ``gate_proj``'s product from ``x``, the sigmoid
+#: and the product with the kernel's output
+SEQ_ATTN_GATE = "seq.attn.gate"
+#: the reshape and ``o_proj``
+SEQ_ATTN_OUT = "seq.attn.out"
 #: hyper-connections, three scopes side by side (a reader of
 #: ``seq.mhc`` sums them). The coefficients of one sublayer: the
 #: streams' norm statistic, their product with the three ``phi``s,

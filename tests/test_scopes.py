@@ -230,6 +230,43 @@ def sim_ops(search):
     return lowered_hlo(lambda t: search.simulate(None, None, t), tree)
 
 
+@pytest.fixture(scope="module")
+def kernel_paths():
+    """The name stack of every equation of a gated and a latent layer
+    traced for a TPU (nothing runs: the kernel's scope exists only in
+    such a trace) — ``{"pallas": [...], "all": [...]}``."""
+    from rocalphago_tpu.models import seqpolicy
+
+    rope = seqpolicy.Rope("default", 1e4, 64)
+    gated = seqpolicy.LayerSpec(heads=2, window=0, rope=rope,
+                                sparse=False)
+    latent = gated._replace(latent=seqpolicy.Latent(
+        q_rank=8, kv_rank=8, nope=128, rope=64, value=128, scale=0.07,
+        gate=True))
+    module = seqpolicy.SeqPolicyNet(
+        layers=(gated, latent), hidden=32, vocab_held=64, kv_heads=1,
+        head_dim=128, dense_width=32, ffn=())
+    ids = jax.ShapeDtypeStruct((1, seqpolicy.KERNEL_BLOCK), jnp.int32)
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(seqpolicy, "kernel_platform", lambda: "tpu")
+        params = jax.eval_shape(module.init, jax.random.key(0), ids)
+        jaxpr = jax.make_jaxpr(module.apply)(params, ids)
+    found = {"pallas": [], "all": []}
+
+    def walk(jaxpr, outer=""):
+        # an inner jaxpr's name stacks are relative to its caller's
+        for eqn in jaxpr.eqns:
+            name = f"{outer}/{eqn.source_info.name_stack}"
+            found["all"].append(name)
+            if eqn.primitive.name == "pallas_call":
+                found["pallas"].append(name)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub, name)
+
+    walk(jaxpr.jaxpr)
+    return found
+
+
 def has(ops: list, name: str) -> bool:
     return any(name in op.split("/") or f"({name})" in op
                for op in ops)
@@ -250,6 +287,18 @@ SEQ = [scopes.SEQ_EMBED, scopes.SEQ_ATTN_FULL, scopes.SEQ_ATTN_WINDOW,
        scopes.SEQ_DENSE_FFN, scopes.SEQ_HEAD,
        scopes.SEQ_EXPERTS_SORT, scopes.SEQ_EXPERTS_DISPATCH,
        scopes.SEQ_EXPERTS_ACT, scopes.SEQ_EXPERTS_COMBINE]
+#: the parts of a softmax layer beside the kernel's, each inside one
+#: of the three layer scopes (Xing's latent layers have no gate)
+ATTN_LAYERS = [scopes.SEQ_ATTN_FULL, scopes.SEQ_ATTN_WINDOW,
+               scopes.SEQ_ATTN_MLA]
+ATTN_PARTS = [scopes.SEQ_ATTN_PROJ, scopes.SEQ_ATTN_ROPE,
+              scopes.SEQ_ATTN_GATE, scopes.SEQ_ATTN_OUT]
+XING_PARTS = [n for n in ATTN_PARTS if n != scopes.SEQ_ATTN_GATE]
+#: the parts inside ``seq.attn.kda.proj``
+KDA_PARTS = [scopes.SEQ_ATTN_KDA_CONV, scopes.SEQ_ATTN_KDA_QKNORM,
+             scopes.SEQ_ATTN_KDA_DECAY]
+#: every scope that lies inside another's and is no top-level one
+PARTS = ATTN_PARTS + KDA_PARTS
 #: what an ``xing4_0`` spec adds to the sequence step
 XING = [scopes.SEQ_ATTN_MLA, scopes.SEQ_MHC_COEFF,
         scopes.SEQ_MHC_SINKHORN, scopes.SEQ_MHC_MIX, scopes.SEQ_MTP]
@@ -265,7 +314,7 @@ def test_every_constant_has_a_case():
     # the attention kernel's scope exists only in a program traced
     # for a TPU: tests/test_seqpolicy.py lowers it there
     assert sorted(TRAIN + PLY + ENCODE + EVAL + MCTS + SEQ + XING + LING
-                  + [scopes.SEQ_ATTN_KERNEL]) == sorted(scopes.ALL)
+                  + PARTS + [scopes.SEQ_ATTN_KERNEL]) == sorted(scopes.ALL)
     assert len(set(scopes.ALL)) == len(scopes.ALL)
 
 
@@ -274,12 +323,12 @@ def test_train_step_scope_survives_the_compile(train_ops, name):
     assert has(train_ops, name), sorted(set(train_ops))[:40]
 
 
-@pytest.mark.parametrize("name", TRAIN + SEQ)
+@pytest.mark.parametrize("name", TRAIN + SEQ + ATTN_PARTS)
 def test_sequence_step_scope_survives_the_compile(seq_ops, name):
     assert has(seq_ops, name), sorted(set(seq_ops))[:40]
 
 
-@pytest.mark.parametrize("name", SEQ)
+@pytest.mark.parametrize("name", SEQ + ATTN_PARTS)
 def test_sequence_scopes_name_the_backward_pass_too(seq_ops, name):
     """Forward under ``jvp(SeqPolicyNet)``, backward — and each
     layer's recomputed forward — under ``transpose(jvp(…))``."""
@@ -290,12 +339,12 @@ def test_sequence_scopes_name_the_backward_pass_too(seq_ops, name):
 
 @pytest.mark.parametrize("name", XING + [
     scopes.SEQ_ROUTER, scopes.SEQ_EXPERTS, scopes.SEQ_DENSE_FFN,
-    scopes.SEQ_HEAD, scopes.TRAIN_LOSS])
+    scopes.SEQ_HEAD, scopes.TRAIN_LOSS] + XING_PARTS)
 def test_xing_step_scope_survives_the_compile(xing_ops, name):
     assert has(xing_ops, name), sorted(set(xing_ops))[:40]
 
 
-@pytest.mark.parametrize("name", XING)
+@pytest.mark.parametrize("name", XING + XING_PARTS)
 def test_xing_scopes_name_the_backward_pass_too(xing_ops, name):
     mine = [op for op in xing_ops if name in op.split("/")]
     assert any("transpose(jvp(SeqPolicyNet))" in op for op in mine)
@@ -308,6 +357,7 @@ def test_xing_scopes_lie_side_by_side(xing_ops):
     partitions; the MTP module's block runs under the layers'."""
     tops = [n for n in scopes.ALL if n.startswith("seq.")
             and n != scopes.SEQ_ATTN_KERNEL
+            and n not in PARTS
             and not n.startswith(scopes.SEQ_EXPERTS + ".")]
     for op in xing_ops:
         inside = [n for n in tops if n in op.split("/")]
@@ -320,12 +370,12 @@ def test_xing_scopes_lie_side_by_side(xing_ops):
 
 @pytest.mark.parametrize("name", LING + [
     scopes.SEQ_ATTN_MLA, scopes.SEQ_ROUTER, scopes.SEQ_EXPERTS,
-    scopes.SEQ_DENSE_FFN, scopes.SEQ_HEAD, scopes.TRAIN_LOSS])
+    scopes.SEQ_DENSE_FFN, scopes.SEQ_HEAD, scopes.TRAIN_LOSS] + PARTS)
 def test_ling_step_scope_survives_the_compile(ling_ops, name):
     assert has(ling_ops, name), sorted(set(ling_ops))[:40]
 
 
-@pytest.mark.parametrize("name", LING)
+@pytest.mark.parametrize("name", LING + PARTS)
 def test_ling_scopes_name_the_backward_pass_too(ling_ops, name):
     mine = [op for op in ling_ops if name in op.split("/")]
     assert any("transpose(jvp(SeqPolicyNet))" in op for op in mine)
@@ -339,7 +389,8 @@ def test_ling_scopes_lie_side_by_side(ling_ops):
     partitions; the group limit runs under the router's scope."""
     parts = LING[1:]
     tops = [n for n in scopes.ALL if n.startswith("seq.")
-            and n != scopes.SEQ_ATTN_KERNEL and n not in parts
+            and n != scopes.SEQ_ATTN_KERNEL
+            and n not in parts + PARTS
             and not n.startswith(scopes.SEQ_EXPERTS + ".")]
     for op in ling_ops:
         path = op.split("/")
@@ -353,6 +404,55 @@ def test_ling_scopes_lie_side_by_side(ling_ops):
     assert any("while" in op for op in scan)     # the loop over chunks
     assert any(scopes.SEQ_ROUTER in op.split("/") and "top_k" in op
                for op in ling_ops)
+
+
+@pytest.mark.parametrize("ops, name", [
+    (ops, name) for ops, names in (
+        ("seq_ops", ATTN_PARTS), ("xing_ops", XING_PARTS),
+        ("ling_ops", PARTS)) for name in names])
+def test_a_part_lies_inside_its_parent_and_beside_the_others(
+        request, ops, name):
+    """The four parts of a softmax layer each inside exactly one of
+    the three layer scopes, the delta layer's three inside
+    ``seq.attn.kda.proj`` and no softmax layer; no part inside another,
+    so a reader's substring match reads one part's time."""
+    mine = [op.split("/") for op in request.getfixturevalue(ops)
+            if name in op.split("/")]
+    assert mine
+    for path in mine:
+        assert [n for n in PARTS if n in path] == [name], path
+        layers = [n for n in ATTN_LAYERS if n in path]
+        if name in KDA_PARTS:
+            assert scopes.SEQ_ATTN_KDA_PROJ in path and not layers, path
+        else:
+            assert len(layers) == 1, path
+            assert scopes.SEQ_ATTN_KDA not in path, path
+
+
+def test_no_part_is_a_substring_of_another_scope():
+    """``chipbench``'s readers match a scope by substring: a new name
+    may be contained in, or contain, its parents' alone."""
+    for name in PARTS:
+        for other in scopes.ALL:
+            if other == name:
+                continue
+            assert name not in other, (name, other)
+            if other in name:
+                assert name in KDA_PARTS and other in (
+                    scopes.SEQ_ATTN_KDA, scopes.SEQ_ATTN_KDA_PROJ)
+
+
+@pytest.mark.parametrize("name", ATTN_PARTS)
+def test_the_kernels_scope_holds_no_part(kernel_paths, name):
+    """Traced for a TPU, a gated and a gated latent layer: the kernel
+    runs under ``seq.attn.kernel`` beside the four parts, never inside
+    one, and each part is there."""
+    assert len(kernel_paths["pallas"]) == 2
+    for path in kernel_paths["pallas"]:
+        assert scopes.SEQ_ATTN_KERNEL in path and name not in path
+    mine = [p for p in kernel_paths["all"] if name in p.split("/")]
+    assert mine
+    assert not [p for p in mine if scopes.SEQ_ATTN_KERNEL in p]
 
 
 def test_train_loss_is_scoped_forward_and_backward(train_ops):
