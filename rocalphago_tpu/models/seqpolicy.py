@@ -118,6 +118,16 @@ router, softmax and loss):
   body run more or fewer times and no second path. What the buffer
   left out is counted from the buffer (``moe_dropped``), not
   assumed, and so are the blocks that ran;
+* in a delta layer, what lies between a projection's product and
+  the scan's ``q``, ``k`` or ``v`` — causal convolution, SiLU, the L2
+  norm over a head, the cast — is one float32 pass over the bf16
+  product each way (:func:`kda_mixed`): on a TPU at whole tiles two
+  Pallas kernels, a block of rows with a halo of sixteen, the result
+  written once in the scan's own chunk-major order; anywhere else the same algorithm as XLA writes it
+  (:func:`causal_conv`, :func:`l2_normed`: the definition). Its
+  backward keeps the product and the taps alone and recomputes the
+  rest in VMEM. In XLA alone the same work moved fourteen times its
+  bytes (PERF.md, PR 34 and PR 35);
 * every layer is recomputed in the backward pass (``nn.remat``),
   all of it but the attention kernel: what a step keeps is one
   ``[B, S, hidden]`` input per layer and, where the kernel runs, its
@@ -577,6 +587,14 @@ def _pairwise_decayed_bwd(sub, kept, cotangents):
 _pairwise_decayed.defvjp(_pairwise_decayed_fwd, _pairwise_decayed_bwd)
 
 
+def _chunk_major(x, chunk: int):
+    """``[B, S, H, d]`` → ``[N, B, H, C, d]`` in its own type: the
+    order the scan works in."""
+    b, s_len, h, w = x.shape
+    return x.reshape(b, s_len // chunk, chunk, h, w).transpose(
+        1, 0, 3, 2, 4)
+
+
 def kda_chunked(q, k, v, g, beta, dtype=jnp.bfloat16):
     """The recurrence above in its chunkwise form: ``q, k [B, S, H,
     d_k]`` (normed, ``q`` scaled), ``v [B, S, H, d_v]``, the float32
@@ -596,13 +614,8 @@ def kda_chunked(q, k, v, g, beta, dtype=jnp.bfloat16):
     if s_len % c or c % sub:
         raise ValueError(f"a row of {s_len} is not whole chunks of {c} "
                          f"in blocks of {sub}")
-    n = s_len // c
     f32 = jnp.float32
-
-    def chunks(x):
-        """``[B, S, H, d]`` → ``[N, B, H, C, d]``, in its own type."""
-        return x.reshape(b, n, c, h, -1).transpose(1, 0, 3, 2, 4)
-
+    chunks = functools.partial(_chunk_major, chunk=c)
     # q and k enter float32 once, so their cotangents add up in
     # float32; v meets only a product that takes it in ``dtype``
     qc, kc, vc = chunks(q).astype(f32), chunks(k).astype(f32), chunks(v)
@@ -655,10 +668,291 @@ def causal_conv(x: jax.Array, taps: jax.Array) -> jax.Array:
                for j in range(k))
 
 
-def l2_normed(x: jax.Array, eps: float = 1e-6) -> jax.Array:
+#: ε under the L2 norms' root (``fla``'s)
+L2_EPS = 1e-6
+
+
+def l2_normed(x: jax.Array, eps: float = L2_EPS) -> jax.Array:
     """``x / ‖x‖₂`` over the last axis, in float32."""
     x = x.astype(jnp.float32)
     return x * jax.lax.rsqrt((x * x).sum(-1, keepdims=True) + eps)
+
+
+# ------------------- from a projection's product to the scan's operand
+#
+# What lies between ``y = x W`` ``[B, S, H·w]`` and the scan's ``q``,
+# ``k`` or ``v`` — the causal convolution, SiLU, for q and k the L2
+# norm over a head and a divisor — is one function, :func:`kda_mixed`,
+# with a backward of its own that keeps ``y`` and the taps ALONE and
+# recomputes the rest: with ``c`` the convolution, ``a = silu(c)``,
+# ``n = a r`` with ``r = (Σ a² + ε)^−½`` over a head and ``o = n / ν``,
+#
+#     da = (r / ν) (do − n Σ n·do)          (``da = do`` without a norm)
+#     dc = da · σ(c) (1 + c (1 − σ(c)))
+#     dy_t = Σ_j taps[j] · dc_{t + (K−1) − j};  dtaps[j] = Σ_t dc_t y_{t − (K−1) + j}
+#
+# It has two lowerings of that one algorithm. :func:`causal_conv` and
+# :func:`l2_normed` are its definition and what runs anywhere
+# (:func:`_mixed_by_definition`, differentiated by JAX from ``y``
+# again). On a TPU at whole tiles (:func:`use_mixing_kernel`) two
+# Pallas kernels move a tensor through HBM once each way: a block of
+# ``KDA_MIX_ROWS`` rows × ``KDA_MIX_LANES`` lanes of ``y`` as the
+# product leaves it — S on the sublanes, a head its own lane tiles —
+# with the ``KDA_MIX_HALO`` rows before it through a ``BlockSpec`` of
+# their own (zeros at a row's start), everything in float32 in VMEM,
+# shifts along the sublanes by ``pltpu.roll``, and the result written
+# once, CHUNK-MAJOR ``[N, B, H, C, w]`` — the order the scan moves its
+# operands to at once (:func:`_chunk_major`), S still on the sublanes,
+# so a permutation of whole tiles inside the kernel; the transposition
+# back to the signature's ``[B, S, H, w]`` and the scan's own cancel
+# in XLA, and the scan's arrays lie as they always did. (Written
+# head-major ``[B, H, S, w]`` XLA made both transpositions bitcasts by
+# giving the scan's arrays THAT layout, and the scan read 13 ms a
+# step slower: PERF.md, PR 35.) The backward kernel reads ``y`` with a
+# halo on both sides and the cotangent, chunk-major too, with the
+# rows after the block, recomputes ``dc`` on ``rows + halo`` rows
+# (never written out), and writes ``dy`` once and a block's share of
+# ``dtaps``, summed over the blocks by XLA.
+
+#: rows, lanes and halo rows of a block of the mixing kernels (a bf16
+#: tile is 16 rows; the halo must hold the ``K − 1`` rows a tap sees)
+KDA_MIX_ROWS = 512
+KDA_MIX_LANES = 512
+KDA_MIX_HALO = 16
+
+
+def use_mixing_kernel(y, taps, heads: int) -> bool:
+    """Whether the mixing kernels take ``y [B, S, H·w]`` and ``taps
+    [K, H·w]``: on a TPU, a head whole 128-lane tiles, the row whole
+    blocks of rows."""
+    _, s_len, width = y.shape
+    head = width // heads
+    return (kernel_platform() == "tpu" and head % 128 == 0
+            and width % KDA_MIX_LANES == 0 and KDA_MIX_LANES % head == 0
+            and s_len % KDA_MIX_ROWS == 0
+            and KDA_MIX_ROWS % KDA_CHUNK == 0
+            and KDA_CHUNK % KDA_MIX_HALO == 0
+            and taps.shape[0] <= KDA_MIX_HALO)
+
+
+def _mixed_by_definition(y, taps, heads: int, norm):
+    b, s_len, width = y.shape
+    a = jax.nn.silu(causal_conv(y, taps)).reshape(
+        b, s_len, heads, width // heads)
+    if norm is not None:
+        a = l2_normed(a) / norm
+    return a.astype(y.dtype)
+
+
+def _shifted(x: jax.Array, by: int) -> jax.Array:
+    """Rows moved down by ``by`` (row ``r`` holds what row ``r − by``
+    held; the first ``by`` rows hold the last ones)."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    return pltpu.roll(x, by % x.shape[0], 0) if by else x
+
+
+def _mix_forward_kernel(before_ref, y_ref, taps_ref, o_ref, *, norm):
+    from jax.experimental import pallas as pl
+
+    f32 = jnp.float32
+    halo, (chunk, head) = before_ref.shape[1], o_ref.shape[3:]
+    taps = taps_ref[...]
+    k = taps.shape[0]
+    # the block's rows behind the halo before them (zeros at a row's
+    # start)
+    before = jnp.where(pl.program_id(1) > 0, before_ref[0].astype(f32),
+                       0.0)
+    rows = jnp.concatenate([before, y_ref[0].astype(f32)], axis=0)
+    c = sum(taps[j:j + 1] * _shifted(rows, k - 1 - j)
+            for j in range(k))[halo:]
+    a = jax.nn.silu(c)
+    for i in range(o_ref.shape[2]):
+        a_h = a[:, i * head:(i + 1) * head]
+        if norm is not None:
+            a_h = a_h * (jax.lax.rsqrt(
+                (a_h * a_h).sum(-1, keepdims=True) + L2_EPS) / norm)
+        for n in range(o_ref.shape[0]):
+            o_ref[n, 0, i] = a_h[n * chunk:(n + 1) * chunk].astype(
+                o_ref.dtype)
+
+
+def _mix_backward_kernel(before_ref, y_ref, after_ref, d_ref,
+                         d_after_ref, taps_ref, dy_ref, dtaps_ref, *,
+                         norm):
+    from jax.experimental import pallas as pl
+
+    f32 = jnp.float32
+    halo, head = before_ref.shape[1], d_ref.shape[4]
+    n_rows = y_ref.shape[1]
+    taps = taps_ref[...]
+    k = taps.shape[0]
+    at = pl.program_id(1)
+    before = jnp.where(at > 0, before_ref[0].astype(f32), 0.0)
+    rows = jnp.concatenate(
+        [before, y_ref[0].astype(f32), after_ref[0].astype(f32)], axis=0)
+    seen = [_shifted(rows, k - 1 - j) for j in range(k)]
+    # the block's rows and the halo after them
+    c = sum(taps[j:j + 1] * seen[j] for j in range(k))[halo:]
+    gate = jax.nn.sigmoid(c)
+    a = c * gate
+    da = []
+    for i in range(d_ref.shape[2]):
+        d_h = jnp.concatenate(
+            [d_ref[n, 0, i] for n in range(d_ref.shape[0])]
+            + [d_after_ref[0, 0, i]], axis=0).astype(f32)
+        if norm is not None:
+            a_h = a[:, i * head:(i + 1) * head]
+            r = jax.lax.rsqrt((a_h * a_h).sum(-1, keepdims=True) + L2_EPS)
+            n_h = a_h * r
+            d_h = (r / norm) * (
+                d_h - n_h * (n_h * d_h).sum(-1, keepdims=True))
+        da.append(d_h)
+    dc = jnp.concatenate(da, axis=1) * (gate * (1.0 + c * (1.0 - gate)))
+    # past the row's end there is no position
+    row = jax.lax.broadcasted_iota(jnp.int32, (dc.shape[0], 1), 0)
+    dc = jnp.where((row < n_rows) | (at < pl.num_programs(1) - 1),
+                   dc, 0.0)
+    dy_ref[0] = sum(taps[j:j + 1] * _shifted(dc, -(k - 1 - j))
+                    for j in range(k))[:n_rows].astype(dy_ref.dtype)
+    dtaps_ref[0, 0] = jnp.concatenate(
+        [(dc[:n_rows] * seen[j][halo:halo + n_rows]).sum(0, keepdims=True)
+         for j in range(k)], axis=0)
+
+
+def _mix_specs(y, taps, head: int, rows: int, lanes: int, chunk: int):
+    """What the two kernels share: the grid (batch row, block of rows,
+    block of lanes), the taps' spec, and the makers of a ``BlockSpec``
+    of ``n`` rows of ``y`` and of ``n`` rows of a chunk-major array
+    ``[N, B, H, C, w]`` (whole chunks, or the first rows of one) —
+    the grid's own block (``side`` 0), or the ``n`` rows before it
+    (−1) or after it (+1), held inside the row: the kernels mask what
+    that repeats."""
+    from jax.experimental import pallas as pl
+
+    b, s_len, width = y.shape
+
+    def at(side: int, unit: int):
+        """A grid block's index → the index of a block of ``unit``
+        rows."""
+        if not side:
+            return lambda i: i
+        per, last = rows // unit, s_len // unit - 1
+        if side < 0:
+            return lambda i: jnp.maximum(i * per - 1, 0)
+        return lambda i: jnp.minimum((i + 1) * per, last)
+
+    def y_rows(n, side=0):
+        to = at(side, n)
+        return pl.BlockSpec((1, n, lanes), lambda b, i, j: (b, to(i), j))
+
+    def chunk_rows(n, side=0):
+        to = at(side, max(n, chunk))
+        return pl.BlockSpec(
+            (max(n // chunk, 1), 1, lanes // head, min(n, chunk), head),
+            lambda b, i, j: (to(i), b, j, 0, 0))
+
+    taps_spec = pl.BlockSpec((taps.shape[0], lanes),
+                             lambda b, i, j: (0, j))
+    return ((b, s_len // rows, width // lanes), taps_spec, y_rows,
+            chunk_rows)
+
+
+def _mix_call(kernel, y, name: str, passes: int, ops: int, **kw):
+    """``pallas_call`` as both kernels make it; for XLA's scheduler,
+    ``passes`` arrays of ``y``'s size through HBM and about ``ops``
+    float32 operations an element."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    return pl.pallas_call(
+        kernel, name=name,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel"),
+            vmem_limit_bytes=64 * 1024 * 1024),
+        cost_estimate=pl.CostEstimate(
+            flops=ops * y.size, transcendentals=y.size,
+            bytes_accessed=passes * y.size * y.dtype.itemsize), **kw)
+
+
+def mixing_kernel_forward(y, taps, heads: int, norm, *,
+                          rows: int = KDA_MIX_ROWS,
+                          lanes: int = KDA_MIX_LANES,
+                          chunk: int = KDA_CHUNK,
+                          interpret: bool = False):
+    """:func:`kda_mixed`'s value by the Pallas kernel: ``y [B, S,
+    H·w]``, float32 ``taps [K, H·w]`` → ``[B, S, H, w]`` in ``y``'s
+    type, written in chunks of ``chunk`` rows, chunk-major."""
+    b, s_len, width = y.shape
+    head, halo = width // heads, KDA_MIX_HALO
+    grid, taps_spec, y_rows, chunk_rows = _mix_specs(
+        y, taps, head, rows, lanes, chunk)
+    out = _mix_call(
+        functools.partial(_mix_forward_kernel, norm=norm),
+        y, "kda_mixed_fwd", 2, 24, grid=grid, interpret=interpret,
+        in_specs=[y_rows(halo, -1), y_rows(rows), taps_spec],
+        out_specs=chunk_rows(rows),
+        out_shape=jax.ShapeDtypeStruct(
+            (s_len // chunk, b, heads, chunk, head), y.dtype)
+    )(y, y, taps)
+    return out.transpose(1, 0, 3, 2, 4).reshape(b, s_len, heads, head)
+
+
+def mixing_kernel_backward(y, taps, d, heads: int, norm, *,
+                           rows: int = KDA_MIX_ROWS,
+                           lanes: int = KDA_MIX_LANES,
+                            chunk: int = KDA_CHUNK,
+                           interpret: bool = False):
+    """:func:`kda_mixed`'s backward by the Pallas kernel: ``y``, the
+    taps and the cotangent ``d [B, S, H, w]`` (read chunk-major) →
+    ``dy`` in ``y``'s type and float32 ``dtaps``."""
+    from jax.experimental import pallas as pl
+
+    b, _, width = y.shape
+    head, halo, k = width // heads, KDA_MIX_HALO, taps.shape[0]
+    grid, taps_spec, y_rows, chunk_rows = _mix_specs(
+        y, taps, head, rows, lanes, chunk)
+    d = _chunk_major(d, chunk)
+    dy, dtaps = _mix_call(
+        functools.partial(_mix_backward_kernel, norm=norm),
+        y, "kda_mixed_bwd", 3, 64, grid=grid, interpret=interpret,
+        in_specs=[y_rows(halo, -1), y_rows(rows), y_rows(halo, 1),
+                  chunk_rows(rows), chunk_rows(halo, 1), taps_spec],
+        out_specs=[y_rows(rows),
+                   pl.BlockSpec((1, 1, k, lanes),
+                                lambda b, i, j: (b, i, 0, j))],
+        out_shape=[jax.ShapeDtypeStruct(y.shape, y.dtype),
+                   jax.ShapeDtypeStruct((b, grid[1], k, width),
+                                        jnp.float32)]
+    )(y, y, y, d, d, taps)
+    return dy, dtaps.sum((0, 1))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def kda_mixed(y, taps, heads: int, norm):
+    """A projection's product ``y [B, S, H·w]`` → the scan's operand
+    ``[B, S, H, w]`` in ``y``'s type: :func:`causal_conv` with float32
+    ``taps [K, H·w]``, SiLU, and where ``norm`` is a number
+    :func:`l2_normed` over a head divided by it — all in float32. The
+    backward keeps ``y`` and the taps alone (comment above)."""
+    if use_mixing_kernel(y, taps, heads):
+        return mixing_kernel_forward(y, taps, heads, norm)
+    return _mixed_by_definition(y, taps, heads, norm)
+
+
+def _kda_mixed_fwd(y, taps, heads, norm):
+    return kda_mixed(y, taps, heads, norm), (y, taps)
+
+
+def _kda_mixed_bwd(heads, norm, kept, d):
+    y, taps = kept
+    if use_mixing_kernel(y, taps, heads):
+        return mixing_kernel_backward(y, taps, d, heads, norm)
+    return jax.vjp(lambda y, taps: _mixed_by_definition(
+        y, taps, heads, norm), y, taps)[1](d)
+
+
+kda_mixed.defvjp(_kda_mixed_fwd, _kda_mixed_bwd)
 
 
 # -------------------------------------------------------------- modules
@@ -817,8 +1111,11 @@ def _dt_bias_init(key, shape, dtype=jnp.float32):
 
 class KimiDeltaAttention(nn.Module):
     """Kimi delta attention (``spec.kda``): queries, keys and values
-    through a projection, a depthwise causal convolution and SiLU
-    each; queries and keys L2-normed per head; a log-decay per key
+    through a projection and then, as ONE pass from the projection's
+    product to the scan's operand (:func:`kda_mixed`: a Pallas kernel
+    on a TPU, XLA elsewhere, one hand-written backward), a depthwise
+    causal convolution and SiLU each, queries and keys L2-normed per
+    head and the queries divided by ``√d_k``; a log-decay per key
     channel held above the config's lower bound; a delta-rule
     recurrence per head (:func:`kda_chunked`); the result normed per
     head, gated channel by channel and projected back. No rotary."""
@@ -834,23 +1131,26 @@ class KimiDeltaAttention(nn.Module):
         h, dk, dv = spec.heads, kda.key, kda.value
         f32 = jnp.float32
 
-        def mixed(name: str, width: int, norm=lambda y: y):
-            """Projection, convolution, SiLU and ``norm`` in float32:
-            ``[B, S, H, width]`` in the compute type."""
+        def mixed(name: str, width: int, norm=None):
+            """Projection, then convolution, SiLU and where ``norm``
+            is given the L2 norm over a head divided by it, as one
+            pass (:func:`kda_mixed`): ``[B, S, H, width]`` in the
+            compute type. A pass that ends in a norm runs under the
+            norm's scope, the values' under the convolution's."""
             y = jnp.dot(x, _weight(self, f"{name}_proj", (d, h * width),
                                    self.dtype))
             taps = self.param(f"{name}_conv",
                               nn.initializers.normal(0.02),
                               (kda.conv, h * width), f32)
-            with jax.named_scope(scopes.SEQ_ATTN_KDA_CONV):
-                y = jax.nn.silu(causal_conv(y, taps)).reshape(
-                    b, s_len, h, width)
+            if norm is None:
+                with jax.named_scope(scopes.SEQ_ATTN_KDA_CONV):
+                    return kda_mixed(y, taps, h, norm)
             with jax.named_scope(scopes.SEQ_ATTN_KDA_QKNORM):
-                return norm(y).astype(self.dtype)
+                return kda_mixed(y, taps, h, norm)
 
         with jax.named_scope(scopes.SEQ_ATTN_KDA_PROJ):
-            q = mixed("q", dk, lambda y: l2_normed(y) / math.sqrt(dk))
-            k = mixed("k", dk, l2_normed)
+            q = mixed("q", dk, math.sqrt(dk))
+            k = mixed("k", dk, 1.0)
             v = mixed("v", dv)
             with jax.named_scope(scopes.SEQ_ATTN_KDA_DECAY):
                 beta = jax.nn.sigmoid(jnp.dot(

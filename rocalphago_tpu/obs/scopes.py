@@ -51,11 +51,16 @@ SEQ_ATTN_KDA = "seq.attn.kda"
 #: log-decay's gate. Directly under it: the three products ``q_proj``
 #: / ``k_proj`` / ``v_proj``; the rest lies in the three parts below
 SEQ_ATTN_KDA_PROJ = "seq.attn.kda.proj"
-#: inside ``.kda.proj``: ``causal_conv`` (a pad, four shifted slices,
-#: the taps) and SiLU, for q, k and v
+#: inside ``.kda.proj``, since PR 35 two halves of ONE quantity — the
+#: pass from a projection's product to the scan's operand
+#: (``seqpolicy.kda_mixed``: causal convolution, SiLU, L2 norm, cast;
+#: the kernels ``kda_mixed_fwd`` / ``kda_mixed_bwd`` on a TPU) — read
+#: them as a sum. This one holds the VALUES' pass, which ends in
+#: SiLU: forward, recomputed forward and its backward
 SEQ_ATTN_KDA_CONV = "seq.attn.kda.proj.conv"
-#: the two L2 norms, the queries' ``1/√d_k`` and the casts of q, k, v
-#: to the compute type
+#: and this one the QUERIES' and the KEYS' passes, which end in the
+#: L2 norm over a head (and the queries' ``1/√d_k``): convolution and
+#: SiLU included: two tensors to the other's one
 SEQ_ATTN_KDA_QKNORM = "seq.attn.kda.proj.norm"
 #: ``beta`` (its product and sigmoid) and the log-decay ``g``:
 #: ``f_proj``, ``dt_bias``, ``A_log``, the sigmoid

@@ -14,6 +14,7 @@ number.
 from __future__ import annotations
 
 import json
+import math
 import os
 import sys
 
@@ -412,6 +413,156 @@ def test_the_convolutions_first_positions_see_zeros():
                                rtol=1e-6)
 
 
+# ------------------- from a projection's product to the scan's operand
+
+#: a row of three blocks of rows written in chunks of sixteen, two
+#: heads to a block of lanes: block, chunk and head boundaries, the
+#: first three positions and the last three all lie inside what is
+#: compared, and the second batch row starts where the first ends
+MIX = dict(rows=32, lanes=256, chunk=16)
+MIX_SHAPE = (2, 96, 4, 128)                         # B, S, H, w
+NORMS = {"values": None, "keys": 1.0, "queries": math.sqrt(128)}
+
+
+def mixing_case(dtype):
+    b, s_len, h, w = MIX_SHAPE
+    y = jax.random.normal(jax.random.key(30), (b, s_len, h * w))
+    taps = 0.5 * jax.random.normal(jax.random.key(31), (4, h * w))
+    d = jax.random.normal(jax.random.key(32), MIX_SHAPE)
+    return y.astype(dtype), taps, d.astype(dtype)
+
+
+def mixed_in_float64(y, taps, d, norm):
+    """The definition on the host: the convolution as ``causal_conv``
+    has it, SiLU, the L2 norm over a head (ε 1e-6) divided by
+    ``norm`` — value, ``dy`` and ``dtaps`` by JAX in float64."""
+    b, s_len, h, w = MIX_SHAPE
+    with jax.enable_x64(True):
+        def definition(y, taps):
+            k = taps.shape[0]
+            yp = jnp.pad(y, ((0, 0), (k - 1, 0), (0, 0)))
+            c = sum(taps[j] * yp[:, j:j + s_len] for j in range(k))
+            a = (c / (1.0 + jnp.exp(-c))).reshape(b, s_len, h, w)
+            if norm is not None:
+                a = a / jnp.sqrt((a * a).sum(-1, keepdims=True)
+                                 + 1e-6) / norm
+            return a
+
+        y, taps, d = (jnp.asarray(np.asarray(x, np.float64))
+                      for x in (y, taps, d))
+        value, back = jax.vjp(definition, y, taps)
+        return tuple(np.asarray(x) for x in (value, *back(d)))
+
+
+def mixed_by(form: str, y, taps, d, norm):
+    """Value, ``dy`` and ``dtaps`` of the XLA form (through
+    ``kda_mixed`` itself, which takes it on the CPU) or of the two
+    Pallas kernels, interpreted."""
+    h = MIX_SHAPE[2]
+    if form == "xla":
+        value, back = jax.vjp(
+            lambda y, taps: seqpolicy.kda_mixed(y, taps, h, norm),
+            y, taps)
+        return (value, *back(d))
+    return (seqpolicy.mixing_kernel_forward(
+        y, taps, h, norm, interpret=True, **MIX),
+        *seqpolicy.mixing_kernel_backward(
+            y, taps, d, h, norm, interpret=True, **MIX))
+
+
+def far(got, want) -> float:
+    """The largest difference, relative to the largest entry."""
+    got, want = (np.asarray(x, np.float64) for x in (got, want))
+    return float(np.abs(got - want).max() / np.abs(want).max())
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("norm", list(NORMS))
+@pytest.mark.parametrize("form", ["xla", "kernel"])
+def test_the_mixing_pass_is_its_definition(form, norm, dtype):
+    """Value AND backward of both lowerings against the definition in
+    float64, over two batch rows and every position (the definition
+    starts each row from zeros: a leak across rows or blocks would
+    show). float32 holds the formulae to rounding; bf16 is what the
+    chip runs, results and ``dy`` rounded once to it."""
+    y, taps, d = mixing_case(dtype)
+    got = mixed_by(form, y, taps, d, NORMS[norm])
+    want = mixed_in_float64(y, taps, d, NORMS[norm])
+    assert [x.dtype for x in got] == [y.dtype, y.dtype, jnp.float32]
+    assert got[0].shape == MIX_SHAPE and got[1].shape == y.shape
+    rounding = {"float32": 2e-6, "bfloat16": 2.0 ** -8}[dtype]
+    for name, a, b, limit in zip(("value", "dy", "dtaps"), got, want,
+                                 (rounding, rounding, 1e-5)):
+        assert np.isfinite(np.asarray(a, np.float32)).all(), name
+        assert far(a, b) < limit, name
+    if norm == "values":
+        # the first positions see zeros, not the row before them
+        np.testing.assert_allclose(
+            np.asarray(got[0][:, 0].reshape(2, -1), np.float32),
+            jax.nn.silu(taps[3] * y[:, 0].astype(jnp.float32)),
+            rtol=3 * rounding, atol=1e-6)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("norm", list(NORMS))
+def test_the_mixing_kernel_is_the_xla_form(norm, dtype):
+    """The same inputs through both lowerings, forward and backward:
+    in float32 to a few roundings, in bf16 to one of the result's."""
+    y, taps, d = mixing_case(dtype)
+    xla = mixed_by("xla", y, taps, d, NORMS[norm])
+    kernel = mixed_by("kernel", y, taps, d, NORMS[norm])
+    limit = {"float32": 2e-6, "bfloat16": 2.0 ** -8}[dtype]
+    for name, a, b in zip(("value", "dy", "dtaps"), kernel, xla):
+        assert far(a, b) < (2e-6 if name == "dtaps" else limit), name
+
+
+@pytest.mark.parametrize("platform", ["cpu", "tpu"])
+@pytest.mark.parametrize("norm", ["values", "queries"])
+def test_the_mixing_backward_keeps_the_product_and_the_taps_alone(
+        monkeypatch, norm, platform):
+    """What a ``jax.vjp`` of the pass saves, traced for the CPU (the
+    XLA form) and for a TPU at whole tiles (the kernels; shapes only,
+    nothing runs): the bf16 product and the float32 taps, no float32
+    array of the product's size."""
+    from jax._src.ad_checkpoint import saved_residuals
+
+    monkeypatch.setattr(seqpolicy, "kernel_platform", lambda: platform)
+    monkeypatch.setattr(seqpolicy, "KDA_CHUNK", 64)     # not the toy's
+    y = jax.ShapeDtypeStruct((1, 1024, 4 * 128), jnp.bfloat16)
+    taps = jax.ShapeDtypeStruct((4, 4 * 128), jnp.float32)
+    assert seqpolicy.use_mixing_kernel(y, taps, 4) == (platform == "tpu")
+    assert not seqpolicy.use_mixing_kernel(       # a head of 64 lanes
+        y, taps, 8)
+    kept = [aval for aval, _ in saved_residuals(
+        lambda y, taps: seqpolicy.kda_mixed(y, taps, 4, NORMS[norm]),
+        y, taps)]
+    assert sorted((str(a.dtype), a.shape) for a in kept) == [
+        ("bfloat16", y.shape), ("float32", taps.shape)]
+
+
+def test_the_layer_saves_no_float32_array_between_product_and_scan(net):
+    """The layer's ``jax.vjp``: it keeps the three bf16 products, and
+    nothing made between a product and the scan's operand (the parent
+    kept the convolution's four shifted slices and sum, two of SiLU's
+    and the norm's — float32 ``[B, S, H·w]`` each — for q, k and
+    v)."""
+    from jax._src.ad_checkpoint import saved_residuals
+
+    layer = seqpolicy.KimiDeltaAttention(net.module.layers[1], 1e-6,
+                                         jnp.bfloat16)
+    attn = net.params["params"]["layer1"]["attn"]
+    x = jax.random.normal(jax.random.key(33), (2, SEQ, 32), jnp.bfloat16)
+    kept = [(aval, str(why)) for aval, why in saved_residuals(
+        lambda p, x: layer.apply({"params": p}, x), attn, x)]
+    products = [aval for aval, why in kept
+                if "dot_general" in why and "mixed" in why]
+    assert [(a.shape, str(a.dtype)) for a in products] == [
+        ((2, SEQ, 4 * 8), "bfloat16")] * 3
+    for made_in in ("causal_conv", "l2_normed", "silu",
+                    "_mixed_by_definition"):
+        assert not [why for _, why in kept if made_in in why], made_in
+
+
 def test_the_decay_stays_above_its_bound_whatever_the_weights(net):
     """The log-decay the layer hands its scan lies in (−5, 0) even
     with the decay's projection blown up."""
@@ -709,6 +860,46 @@ def test_the_other_two_blocks_build_what_they_built(other):
         assert lowered() == text
 
 
+#: sha256 of the toy Laguna and Xing train steps' lowered text, as
+#: the last commit that meant to change those steps left it
+LOWERINGS = os.path.join(ROOT, "tests", "data",
+                         "seq_step_lowerings.json")
+
+
+def toy_step_digest(other: str) -> str:
+    """sha256 of the StableHLO the toy train step of ``tests/<other>``
+    lowers to (this file's toy tiles; shapes only, nothing runs)."""
+    import hashlib
+    import importlib
+
+    toy = importlib.import_module(other).TOY
+    net = SeqPolicy(board=SIZE, init_weights=False, **toy)
+    ids = jax.ShapeDtypeStruct((2, SEQ), jnp.int32)
+    params = jax.eval_shape(net.module.init, jax.random.key(0), ids,
+                            ids)
+    tx = sl.make_optimizer(sl.SLConfig())
+    state = sl.SLState(params, jax.eval_shape(tx.init, params),
+                       jax.ShapeDtypeStruct((), jnp.int32),
+                       jax.eval_shape(jax.random.key_data,
+                                      jax.random.key(0)))
+    step = jax.jit(sl.make_train_step(net.module.apply, tx, SIZE, True))
+    return hashlib.sha256(
+        step.lower(state, ids, ids).as_text().encode()).hexdigest()
+
+
+@pytest.mark.parametrize("other", ["test_seqpolicy",
+                                   "test_seqpolicy_xing"])
+def test_the_other_two_steps_lower_to_the_text_on_record(other):
+    """A laguna and an xing4_0 spec build no delta layer, so a change
+    to the delta layers leaves their train steps' StableHLO what it
+    was, byte for byte: the digests on record are those of the commit
+    before the mixing pass (PR 34's), taken from its checkout by this
+    function. A commit that MEANS to change those steps records anew:
+    ``python tests/test_seqpolicy_ling.py`` rewrites the file."""
+    with open(LOWERINGS) as f:
+        assert toy_step_digest(other) == json.load(f)[other]
+
+
 @pytest.mark.parametrize("key,value", [
     ("expert_swiglu_limit_list", [0, 0, 4, 0]),
     ("share_expert_swiglu_limit_list", [0, 5, 0, 0]),
@@ -730,3 +921,16 @@ def test_a_limit_on_a_layer_that_is_not_held_is_no_refusal():
     SeqPolicy(board=SIZE, init_weights=False,
               **dict(TOY, expert_swiglu_limit_list=limits,
                      share_expert_swiglu_limit_list=limits))
+
+
+if __name__ == "__main__":
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(seqpolicy, "EXPERT_CHUNK", 16)
+        patch.setattr(seqpolicy, "ATTENTION_BLOCK", 8)
+        digests = {other: toy_step_digest(other) for other in
+                   ("test_seqpolicy", "test_seqpolicy_xing")}
+    os.makedirs(os.path.dirname(LOWERINGS), exist_ok=True)
+    with open(LOWERINGS, "w") as f:
+        json.dump(digests, f, indent=1)
+        f.write("\n")
+    print(digests)
