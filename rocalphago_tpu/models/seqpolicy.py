@@ -137,8 +137,9 @@ router, softmax and loss):
 * in a delta layer, what lies between a projection's product and
   the scan's ``q``, ``k`` or ``v`` — causal convolution, SiLU, the L2
   norm over a head, the cast — is one float32 pass over the bf16
-  product each way (:func:`kda_mixed`): on a TPU at whole tiles two
-  Pallas kernels, a block of rows with a halo of sixteen, the result
+  product each way (:func:`kda_mixed`): on a TPU two Pallas kernels,
+  a block of rows × whole heads chosen from the shapes
+  (:func:`mixing_blocks`) with a halo of sixteen rows, the result
   written once in the scan's own chunk-major order; anywhere else the same algorithm as XLA writes it
   (:func:`causal_conv`, :func:`l2_normed`: the definition). Its
   backward keeps the product and the taps alone and recomputes the
@@ -752,18 +753,24 @@ def l2_normed(x: jax.Array, eps: float = L2_EPS) -> jax.Array:
 # It has two lowerings of that one algorithm. :func:`causal_conv` and
 # :func:`l2_normed` are its definition and what runs anywhere
 # (:func:`_mixed_by_definition`, differentiated by JAX from ``y``
-# again). On a TPU at whole tiles (:func:`use_mixing_kernel`) two
-# Pallas kernels move a tensor through HBM once each way: a block of
-# ``KDA_MIX_ROWS`` rows × ``KDA_MIX_LANES`` lanes of ``y`` as the
-# product leaves it — S on the sublanes, a head its own lane tiles —
+# again). On a TPU (:func:`use_mixing_kernel`) two Pallas kernels move
+# a tensor through HBM once each way: a block of rows × lanes of ``y``
+# as the product leaves it — S on the sublanes, whole heads on the
+# lanes, the block chosen from the shapes (:func:`mixing_blocks`) —
 # with the ``KDA_MIX_HALO`` rows before it through a ``BlockSpec`` of
 # their own (zeros at a row's start), everything in float32 in VMEM,
 # shifts along the sublanes by ``pltpu.roll``, and the result written
 # once, CHUNK-MAJOR ``[N, B, H, C, w]`` — the order the scan moves its
 # operands to at once (:func:`_chunk_major`), S still on the sublanes,
-# so a permutation of whole tiles inside the kernel; the transposition
-# back to the signature's ``[B, S, H, w]`` and the scan's own cancel
-# in XLA, and the scan's arrays lie as they always did. (Written
+# so a permutation of whole tiles inside the kernel where a head is
+# whole tiles, and where it is not (Olmo's 96 and 192) each head moved
+# to lane 0 by lane rotations and selects (:func:`_lanes`), and the
+# cotangent's heads back beside each other the same way
+# (:func:`_interleaved`), which a slice at the head's own offset left
+# to Mosaic takes 1.2 × as long forward, 1.3 × backward (PERF.md §6);
+# the transposition back to the signature's ``[B, S, H, w]`` and the
+# scan's own cancel in XLA, and the scan's arrays lie as they always
+# did. (Written
 # head-major ``[B, H, S, w]`` XLA made both transpositions bitcasts by
 # giving the scan's arrays THAT layout, and the scan read 13 ms a
 # step slower: PERF.md, PR 35.) The backward kernel reads ``y`` with a
@@ -772,25 +779,48 @@ def l2_normed(x: jax.Array, eps: float = L2_EPS) -> jax.Array:
 # (never written out), and writes ``dy`` once and a block's share of
 # ``dtaps``, summed over the blocks by XLA.
 
-#: rows, lanes and halo rows of a block of the mixing kernels (a bf16
-#: tile is 16 rows; the halo must hold the ``K − 1`` rows a tap sees)
+#: rows and lanes of a block of the mixing kernels at most, where the
+#: shapes allow; the halo rows (a bf16 tile is 16 rows; the halo must
+#: hold the ``K − 1`` rows a tap sees); and the elements of a block at
+#: most: the backward's float32 temporaries fill the 64 MiB of VMEM
+#: its call may take at about 47 bytes an element
 KDA_MIX_ROWS = 512
 KDA_MIX_LANES = 512
 KDA_MIX_HALO = 16
+KDA_MIX_BLOCK = 1 << 20
+
+
+def mixing_blocks(s_len: int, width: int, head: int, k: int):
+    """The block ``(rows, lanes)`` of the mixing kernels for a row of
+    ``s_len`` positions, ``width`` lanes of heads ``head`` wide and
+    ``k`` taps, or None where none is legal. Its lanes hold whole
+    heads (the norm sums over one), divide the width and are whole
+    128-lane tiles or the whole width (what a ``BlockSpec`` takes): the
+    widest such block up to ``KDA_MIX_LANES``, else the narrowest —
+    the whole width where no multiple of ``lcm(head, 128)`` divides it
+    (30 heads of 96). Its rows are whole chunks of the scan and divide
+    the row: the most up to ``KDA_MIX_ROWS`` and ``KDA_MIX_BLOCK``
+    elements."""
+    if (width % head or k > KDA_MIX_HALO or KDA_CHUNK % KDA_MIX_HALO
+            or KDA_MIX_ROWS % KDA_CHUNK):
+        return None
+    tile = math.lcm(head, 128)
+    legal = [n for n in range(tile, width, tile) if width % n == 0]
+    legal.append(width)
+    lanes = max((n for n in legal if n <= KDA_MIX_LANES),
+                default=legal[0])
+    rows = [n for n in range(KDA_CHUNK, KDA_MIX_ROWS + 1, KDA_CHUNK)
+            if s_len % n == 0 and n * lanes <= KDA_MIX_BLOCK]
+    return (rows[-1], lanes) if rows else None
 
 
 def use_mixing_kernel(y, taps, heads: int) -> bool:
     """Whether the mixing kernels take ``y [B, S, H·w]`` and ``taps
-    [K, H·w]``: on a TPU, a head whole 128-lane tiles, the row whole
-    blocks of rows."""
+    [K, H·w]``: on a TPU, wherever :func:`mixing_blocks` finds a
+    block."""
     _, s_len, width = y.shape
-    head = width // heads
-    return (kernel_platform() == "tpu" and head % 128 == 0
-            and width % KDA_MIX_LANES == 0 and KDA_MIX_LANES % head == 0
-            and s_len % KDA_MIX_ROWS == 0
-            and KDA_MIX_ROWS % KDA_CHUNK == 0
-            and KDA_CHUNK % KDA_MIX_HALO == 0
-            and taps.shape[0] <= KDA_MIX_HALO)
+    return (kernel_platform() == "tpu" and mixing_blocks(
+        s_len, width, width // heads, taps.shape[0]) is not None)
 
 
 def _mixed_by_definition(y, taps, heads: int, norm):
@@ -810,6 +840,67 @@ def _shifted(x: jax.Array, by: int) -> jax.Array:
     return pltpu.roll(x, by % x.shape[0], 0) if by else x
 
 
+def _tile(x: jax.Array, at: int) -> jax.Array:
+    """The 128 lanes of ``x`` from ``at`` (a multiple of 128), zeros
+    past its last lane."""
+    if at >= x.shape[1]:
+        return jnp.zeros((x.shape[0], 128), x.dtype)
+    part = x[:, at:at + 128]
+    if part.shape[1] < 128:
+        part = jnp.concatenate(
+            [part, jnp.zeros((x.shape[0], 128 - part.shape[1]), x.dtype)],
+            axis=1)
+    return part
+
+
+def _lanes(x: jax.Array, start: int, width: int) -> jax.Array:
+    """Lanes ``start`` … ``start + width`` of ``x [R, L]`` from lane 0:
+    a slice where they start a 128-lane tile, else each tile of the
+    result from the one or two tiles of ``x`` it lies across, rotated
+    along the lanes and selected."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    if start % 128 == 0:
+        return x[:, start:start + width]
+    tiles = []
+    for k in range(0, width, 128):
+        s = (start + k) % 128
+        at = start + k - s
+        t = pltpu.roll(_tile(x, at), 128 - s, 1)
+        if s + min(128, width - k) > 128:
+            lane = jax.lax.broadcasted_iota(jnp.int32, t.shape, 1)
+            t = jnp.where(lane < 128 - s, t,
+                          pltpu.roll(_tile(x, at + 128), 128 - s, 1))
+        tiles.append(t)
+    if len(tiles) > 1:
+        t = jnp.concatenate(tiles, axis=1)
+    return t[:, :width]
+
+
+def _interleaved(heads: list, lanes: int) -> jax.Array:
+    """``heads``, each ``[R, w]`` from lane 0, side by side as ``[R,
+    lanes]``: each 128-lane tile of the result from the heads it holds,
+    taken by :func:`_lanes` from their tiles laid side by side (head
+    ``i``'s lane ``l`` at ``i·wide + l``)."""
+    w = heads[0].shape[1]
+    if w % 128 == 0:
+        return jnp.concatenate(heads, axis=1)
+    wide = -(-w // 128) * 128
+    padded = jnp.concatenate([jnp.concatenate(
+        [h, jnp.zeros((h.shape[0], wide - w), h.dtype)], axis=1)
+        for h in heads], axis=1)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (padded.shape[0], 128), 1)
+    tiles = []
+    for at in range(0, lanes, 128):
+        end = min(at + 128, lanes)
+        t = None
+        for i in range(at // w, (end - 1) // w + 1):
+            part = _lanes(padded, at + i * (wide - w), 128)
+            t = part if t is None else jnp.where(lane < i * w - at, t, part)
+        tiles.append(t)
+    return jnp.concatenate(tiles, axis=1)[:, :lanes]
+
+
 def _mix_forward_kernel(before_ref, y_ref, taps_ref, o_ref, *, norm):
     from jax.experimental import pallas as pl
 
@@ -826,7 +917,7 @@ def _mix_forward_kernel(before_ref, y_ref, taps_ref, o_ref, *, norm):
             for j in range(k))[halo:]
     a = jax.nn.silu(c)
     for i in range(o_ref.shape[2]):
-        a_h = a[:, i * head:(i + 1) * head]
+        a_h = _lanes(a, i * head, head)
         if norm is not None:
             a_h = a_h * (jax.lax.rsqrt(
                 (a_h * a_h).sum(-1, keepdims=True) + L2_EPS) / norm)
@@ -860,13 +951,13 @@ def _mix_backward_kernel(before_ref, y_ref, after_ref, d_ref,
             [d_ref[n, 0, i] for n in range(d_ref.shape[0])]
             + [d_after_ref[0, 0, i]], axis=0).astype(f32)
         if norm is not None:
-            a_h = a[:, i * head:(i + 1) * head]
+            a_h = _lanes(a, i * head, head)
             r = jax.lax.rsqrt((a_h * a_h).sum(-1, keepdims=True) + L2_EPS)
             n_h = a_h * r
             d_h = (r / norm) * (
                 d_h - n_h * (n_h * d_h).sum(-1, keepdims=True))
         da.append(d_h)
-    dc = jnp.concatenate(da, axis=1) * (gate * (1.0 + c * (1.0 - gate)))
+    dc = _interleaved(da, a.shape[1]) * (gate * (1.0 + c * (1.0 - gate)))
     # past the row's end there is no position
     row = jax.lax.broadcasted_iota(jnp.int32, (dc.shape[0], 1), 0)
     dc = jnp.where((row < n_rows) | (at < pl.num_programs(1) - 1),
@@ -933,16 +1024,28 @@ def _mix_call(kernel, y, name: str, passes: int, ops: int, **kw):
             bytes_accessed=passes * y.size * y.dtype.itemsize), **kw)
 
 
+#: a ``pallas_call`` is lowered by Python wherever it is traced, and a
+#: step holds up to 54 of these, their bodies unrolled over the heads:
+#: jitted, the calls at one shape share one lowering
+_mixing_jit = functools.partial(
+    jax.jit, static_argnames=("heads", "norm", "rows", "lanes", "chunk",
+                              "interpret"))
+
+
+@_mixing_jit
 def mixing_kernel_forward(y, taps, heads: int, norm, *,
-                          rows: int = KDA_MIX_ROWS,
-                          lanes: int = KDA_MIX_LANES,
+                          rows: int | None = None,
+                          lanes: int | None = None,
                           chunk: int = KDA_CHUNK,
                           interpret: bool = False):
     """:func:`kda_mixed`'s value by the Pallas kernel: ``y [B, S,
     H·w]``, float32 ``taps [K, H·w]`` → ``[B, S, H, w]`` in ``y``'s
-    type, written in chunks of ``chunk`` rows, chunk-major."""
+    type, written in chunks of ``chunk`` rows, chunk-major; in blocks
+    of ``rows`` × ``lanes``, :func:`mixing_blocks`' unless given."""
     b, s_len, width = y.shape
     head, halo = width // heads, KDA_MIX_HALO
+    if rows is None or lanes is None:
+        rows, lanes = mixing_blocks(s_len, width, head, taps.shape[0])
     grid, taps_spec, y_rows, chunk_rows = _mix_specs(
         y, taps, head, rows, lanes, chunk)
     out = _mix_call(
@@ -956,18 +1059,22 @@ def mixing_kernel_forward(y, taps, heads: int, norm, *,
     return out.transpose(1, 0, 3, 2, 4).reshape(b, s_len, heads, head)
 
 
+@_mixing_jit
 def mixing_kernel_backward(y, taps, d, heads: int, norm, *,
-                           rows: int = KDA_MIX_ROWS,
-                           lanes: int = KDA_MIX_LANES,
-                            chunk: int = KDA_CHUNK,
+                           rows: int | None = None,
+                           lanes: int | None = None,
+                           chunk: int = KDA_CHUNK,
                            interpret: bool = False):
     """:func:`kda_mixed`'s backward by the Pallas kernel: ``y``, the
     taps and the cotangent ``d [B, S, H, w]`` (read chunk-major) →
-    ``dy`` in ``y``'s type and float32 ``dtaps``."""
+    ``dy`` in ``y``'s type and float32 ``dtaps``; blocks as the
+    forward's."""
     from jax.experimental import pallas as pl
 
-    b, _, width = y.shape
+    b, s_len, width = y.shape
     head, halo, k = width // heads, KDA_MIX_HALO, taps.shape[0]
+    if rows is None or lanes is None:
+        rows, lanes = mixing_blocks(s_len, width, head, k)
     grid, taps_spec, y_rows, chunk_rows = _mix_specs(
         y, taps, head, rows, lanes, chunk)
     d = _chunk_major(d, chunk)

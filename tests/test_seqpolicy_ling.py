@@ -415,20 +415,36 @@ def test_the_convolutions_first_positions_see_zeros():
 
 # ------------------- from a projection's product to the scan's operand
 
-#: a row of three blocks of rows written in chunks of sixteen, two
-#: heads to a block of lanes: block, chunk and head boundaries, the
-#: first three positions and the last three all lie inside what is
+#: a row of three blocks of rows written in chunks of sixteen, by head
+#: width: two heads of 128 to a block of lanes; six heads of 96 (no
+#: multiple of lcm(96, 128) = 384 divides their 576 lanes, so a block
+#: is the whole width, as Olmo's q and k take it); two heads of 192 to
+#: a block of 384 lanes, of four (as Olmo's v). Block, chunk and head
+#: boundaries — a head of 96 or 192 across a lane tile among them —
+#: the first three positions and the last three all lie inside what is
 #: compared, and the second batch row starts where the first ends
-MIX = dict(rows=32, lanes=256, chunk=16)
-MIX_SHAPE = (2, 96, 4, 128)                         # B, S, H, w
-NORMS = {"values": None, "keys": 1.0, "queries": math.sqrt(128)}
+MIX = {128: dict(rows=32, lanes=256, chunk=16),
+       96: dict(rows=32, lanes=576, chunk=16),
+       192: dict(rows=32, lanes=384, chunk=16)}
+MIX_HEADS = {128: 4, 96: 6, 192: 4}
+MIX_ROWS = (2, 96)                                   # B, S
+NORMS = {"values": None, "keys": 1.0, "queries": "√w"}
 
 
-def mixing_case(dtype):
-    b, s_len, h, w = MIX_SHAPE
+def mix_shape(w: int) -> tuple:
+    return (*MIX_ROWS, MIX_HEADS[w], w)                # B, S, H, w
+
+
+def norm_of(name: str, w: int):
+    norm = NORMS[name]
+    return math.sqrt(w) if norm == "√w" else norm
+
+
+def mixing_case(dtype, w: int = 128):
+    b, s_len, h, w = mix_shape(w)
     y = jax.random.normal(jax.random.key(30), (b, s_len, h * w))
     taps = 0.5 * jax.random.normal(jax.random.key(31), (4, h * w))
-    d = jax.random.normal(jax.random.key(32), MIX_SHAPE)
+    d = jax.random.normal(jax.random.key(32), (b, s_len, h, w))
     return y.astype(dtype), taps, d.astype(dtype)
 
 
@@ -436,7 +452,7 @@ def mixed_in_float64(y, taps, d, norm):
     """The definition on the host: the convolution as ``causal_conv``
     has it, SiLU, the L2 norm over a head (ε 1e-6) divided by
     ``norm`` — value, ``dy`` and ``dtaps`` by JAX in float64."""
-    b, s_len, h, w = MIX_SHAPE
+    b, s_len, h, w = d.shape
     with jax.enable_x64(True):
         def definition(y, taps):
             k = taps.shape[0]
@@ -458,16 +474,16 @@ def mixed_by(form: str, y, taps, d, norm):
     """Value, ``dy`` and ``dtaps`` of the XLA form (through
     ``kda_mixed`` itself, which takes it on the CPU) or of the two
     Pallas kernels, interpreted."""
-    h = MIX_SHAPE[2]
+    h, w = d.shape[2:]
     if form == "xla":
         value, back = jax.vjp(
             lambda y, taps: seqpolicy.kda_mixed(y, taps, h, norm),
             y, taps)
         return (value, *back(d))
     return (seqpolicy.mixing_kernel_forward(
-        y, taps, h, norm, interpret=True, **MIX),
+        y, taps, h, norm, interpret=True, **MIX[w]),
         *seqpolicy.mixing_kernel_backward(
-            y, taps, d, h, norm, interpret=True, **MIX))
+            y, taps, d, h, norm, interpret=True, **MIX[w]))
 
 
 def far(got, want) -> float:
@@ -476,20 +492,22 @@ def far(got, want) -> float:
     return float(np.abs(got - want).max() / np.abs(want).max())
 
 
+@pytest.mark.parametrize("w", list(MIX))
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 @pytest.mark.parametrize("norm", list(NORMS))
 @pytest.mark.parametrize("form", ["xla", "kernel"])
-def test_the_mixing_pass_is_its_definition(form, norm, dtype):
+def test_the_mixing_pass_is_its_definition(form, norm, dtype, w):
     """Value AND backward of both lowerings against the definition in
     float64, over two batch rows and every position (the definition
     starts each row from zeros: a leak across rows or blocks would
-    show). float32 holds the formulae to rounding; bf16 is what the
-    chip runs, results and ``dy`` rounded once to it."""
-    y, taps, d = mixing_case(dtype)
-    got = mixed_by(form, y, taps, d, NORMS[norm])
-    want = mixed_in_float64(y, taps, d, NORMS[norm])
+    show), at heads of 128, 96 and 192 lanes. float32 holds the
+    formulae to rounding; bf16 is what the chip runs, results and
+    ``dy`` rounded once to it."""
+    y, taps, d = mixing_case(dtype, w)
+    got = mixed_by(form, y, taps, d, norm_of(norm, w))
+    want = mixed_in_float64(y, taps, d, norm_of(norm, w))
     assert [x.dtype for x in got] == [y.dtype, y.dtype, jnp.float32]
-    assert got[0].shape == MIX_SHAPE and got[1].shape == y.shape
+    assert got[0].shape == mix_shape(w) and got[1].shape == y.shape
     rounding = {"float32": 2e-6, "bfloat16": 2.0 ** -8}[dtype]
     for name, a, b, limit in zip(("value", "dy", "dtaps"), got, want,
                                  (rounding, rounding, 1e-5)):
@@ -503,17 +521,45 @@ def test_the_mixing_pass_is_its_definition(form, norm, dtype):
             rtol=3 * rounding, atol=1e-6)
 
 
+@pytest.mark.parametrize("w", list(MIX))
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 @pytest.mark.parametrize("norm", list(NORMS))
-def test_the_mixing_kernel_is_the_xla_form(norm, dtype):
+def test_the_mixing_kernel_is_the_xla_form(norm, dtype, w):
     """The same inputs through both lowerings, forward and backward:
     in float32 to a few roundings, in bf16 to one of the result's."""
-    y, taps, d = mixing_case(dtype)
-    xla = mixed_by("xla", y, taps, d, NORMS[norm])
-    kernel = mixed_by("kernel", y, taps, d, NORMS[norm])
+    y, taps, d = mixing_case(dtype, w)
+    xla = mixed_by("xla", y, taps, d, norm_of(norm, w))
+    kernel = mixed_by("kernel", y, taps, d, norm_of(norm, w))
     limit = {"float32": 2e-6, "bfloat16": 2.0 ** -8}[dtype]
     for name, a, b in zip(("value", "dy", "dtaps"), kernel, xla):
         assert far(a, b) < (2e-6 if name == "dtaps" else limit), name
+
+
+@pytest.mark.parametrize("s_len,width,head,k,blocks", [
+    (8192, 4096, 128, 4, (512, 512)),       # Ling's q, k and v
+    (8192, 2880, 96, 4, (256, 2880)),       # Olmo's q and k
+    (8192, 5760, 192, 4, (512, 384)),       # Olmo's v
+    (8192, 512, 64, 4, (512, 512)),         # heads of 64
+    (8192, 4096, 1024, 4, (512, 1024)),     # a head wider than a block
+    (1000, 4096, 128, 4, None),             # rows not whole chunks
+    (8192, 4096, 128, 17, None),            # taps past the halo
+], ids=["ling", "olmo_qk", "olmo_v", "head_64", "head_1024",
+        "rows_not_whole", "taps_past_the_halo"])
+def test_the_mixing_blocks_are_chosen_from_the_shapes(
+        monkeypatch, s_len, width, head, k, blocks):
+    """The block of rows × lanes: Ling's exactly as before; elsewhere
+    whole heads, a divisor of the width, whole 128-lane tiles or the
+    whole width, whole chunks of the scan that divide the row, within
+    the elements VMEM holds — or none."""
+    monkeypatch.setattr(seqpolicy, "KDA_CHUNK", 64)     # not the toy's
+    got = seqpolicy.mixing_blocks(s_len, width, head, k)
+    assert got == blocks
+    if got is not None:
+        rows, lanes = got
+        assert lanes % head == 0 and width % lanes == 0
+        assert lanes % 128 == 0 or lanes == width
+        assert rows % seqpolicy.KDA_CHUNK == 0 and s_len % rows == 0
+        assert rows * lanes <= seqpolicy.KDA_MIX_BLOCK
 
 
 @pytest.mark.parametrize("platform", ["cpu", "tpu"])
@@ -531,10 +577,13 @@ def test_the_mixing_backward_keeps_the_product_and_the_taps_alone(
     y = jax.ShapeDtypeStruct((1, 1024, 4 * 128), jnp.bfloat16)
     taps = jax.ShapeDtypeStruct((4, 4 * 128), jnp.float32)
     assert seqpolicy.use_mixing_kernel(y, taps, 4) == (platform == "tpu")
-    assert not seqpolicy.use_mixing_kernel(       # a head of 64 lanes
-        y, taps, 8)
+    assert seqpolicy.use_mixing_kernel(           # heads of 64 lanes
+        y, taps, 8) == (platform == "tpu")
+    assert not seqpolicy.use_mixing_kernel(       # rows not whole chunks
+        jax.ShapeDtypeStruct((1, 1000, 4 * 128), jnp.bfloat16), taps, 4)
     kept = [aval for aval, _ in saved_residuals(
-        lambda y, taps: seqpolicy.kda_mixed(y, taps, 4, NORMS[norm]),
+        lambda y, taps: seqpolicy.kda_mixed(y, taps, 4,
+                                            norm_of(norm, 128)),
         y, taps)]
     assert sorted((str(a.dtype), a.shape) for a in kept) == [
         ("bfloat16", y.shape), ("float32", taps.shape)]

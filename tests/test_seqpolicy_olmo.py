@@ -12,6 +12,7 @@ nothing here is a device number.
 
 from __future__ import annotations
 
+import math
 import os
 import sys
 
@@ -296,6 +297,33 @@ def test_the_per_channel_scan_takes_a_broadcast_head_decay(monkeypatch):
 
 
 # ------------------------------------------------ the dense step
+
+@pytest.mark.parametrize("width,norm", [(96, math.sqrt(96)), (96, 1.0),
+                                        (192, None)],
+                         ids=["queries", "keys", "values"])
+def test_the_mixing_kernels_keep_the_product_and_the_taps_alone(
+        monkeypatch, width, norm):
+    """The mixing pass at the cell's heads — 30 of 96 lanes (q, k), 30
+    of 192 (v) — traced for a TPU (shapes only, nothing runs): the
+    Pallas kernels take it, and a ``jax.vjp`` of it saves the bf16
+    product and the float32 taps, no float32 array of the product's
+    size."""
+    from jax._src.ad_checkpoint import saved_residuals
+
+    monkeypatch.setattr(seqpolicy, "kernel_platform", lambda: "tpu")
+    monkeypatch.setattr(seqpolicy, "KDA_CHUNK", 64)     # not the toy's
+    y = jax.ShapeDtypeStruct((1, 8192, 30 * width), jnp.bfloat16)
+    taps = jax.ShapeDtypeStruct((4, 30 * width), jnp.float32)
+
+    def mixed(y, taps):
+        return seqpolicy.kda_mixed(y, taps, 30, norm)
+
+    assert seqpolicy.use_mixing_kernel(y, taps, 30)
+    assert "kda_mixed_fwd" in str(jax.make_jaxpr(mixed)(y, taps))
+    kept = [aval for aval, _ in saved_residuals(mixed, y, taps)]
+    assert sorted((str(a.dtype), a.shape) for a in kept) == [
+        ("bfloat16", y.shape), ("float32", taps.shape)]
+
 
 def test_the_dense_step_runs_through_the_trainer_without_counts(net,
                                                                  batch):
