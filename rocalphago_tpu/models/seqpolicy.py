@@ -91,6 +91,22 @@ third's delta layer and the first's softmax layer:
   no norm on a sublayer's input; a dense SwiGLU in every layer and no
   expert layer, so the step returns no routing counts.
 
+A fifth is read from the ``lfm2_moe`` keys (LiquidAI LFM2-8B-A1B,
+``config.json``), pre-norm blocks like the first's:
+
+* ``layer_types``: held layer ``i`` is a **gated short convolution**
+  where it says ``conv`` (:class:`ShortConv`: ``[B | C | x̃] = x
+  W_in``, ``y = (C ⊙ conv(B ⊙ x̃)) W_out`` with ``conv_L_cache``
+  depthwise causal taps) and softmax attention with **QK-norm per
+  head** (one scale of the head's width) and no gate where it says
+  ``full_attention``;
+* ``num_dense_layers`` leading layers with the dense MLP, then routed
+  experts chosen by a sigmoid score plus a selection bias
+  (``use_expert_bias``), weighted by the score, and **no shared
+  expert**;
+* the head is **tied** to the embedding: its gradient and the
+  lookup's add up in one leaf.
+
 **The held share.** A spec also says what part of the model lives
 here: ``layers_held`` leading layers, ``vocab_held`` leading rows of
 the vocabulary (embedding, head, logits and loss are over the slice),
@@ -233,11 +249,16 @@ class LayerSpec(NamedTuple):
     kda: Kda | None = None          # a delta layer (else either)
     #: GQA's per-head sigmoid gate; without it the plain softmax layer
     gate: bool = True
-    #: GQA's QK-norm: an RMSNorm over the whole q and k projections
-    qk_norm: bool = False
+    #: GQA's QK-norm: ``"whole"``, an RMSNorm over the whole q and k
+    #: projections (Olmo 2's); ``"head"``, one over each head's lanes
+    #: with a scale of the head's width (LFM2's); ``""``, none
+    qk_norm: str = ""
     #: the post-norm block (Olmo 2's reordered norm): ``x +
     #: norm(F(x))``, no norm on a sublayer's input
     post_norm: bool = False
+    #: the gated short convolution's taps (LFM2's ``conv_L_cache``) in
+    #: place of attention; 0: no such layer
+    short_conv: int = 0
 
 
 def rope_inv_freq(rope: Rope) -> np.ndarray:
@@ -306,12 +327,13 @@ def kernel_platform() -> str:
 
 
 def use_kernel(s_len: int, *head_dims: int) -> bool:
-    """Whether the kernel takes these heads: whole 128-lane tiles, or
-    whole tiles and a half (latent attention's 192: Mosaic compiles
-    it for the v5e and the chip agrees with the reference, PERF.md,
-    PR 30)."""
+    """Whether the kernel takes these heads: whole 128-lane tiles,
+    whole tiles and a half (latent attention's 192) or half a tile
+    (LFM2's 64, unpadded). Mosaic compiles each for the v5e and the
+    chip agrees with the reference; at 64 the forward and backward
+    take no longer than zero-padded to 128 (PERF.md §6)."""
     return (kernel_platform() == "tpu" and s_len % KERNEL_BLOCK == 0
-            and all(d >= 128 and d % 64 == 0 for d in head_dims))
+            and all(d >= 64 and d % 64 == 0 for d in head_dims))
 
 
 def kernel_attention(q, k, v, window: int, interpret: bool = False):
@@ -1166,8 +1188,9 @@ class SwiGLU(nn.Module):
 class GatedAttention(nn.Module):
     """Grouped-query softmax attention with rotary: gated per head by
     a sigmoid (``spec.gate``) or not, its q and k projections
-    RMS-normed over their whole width before the heads are split
-    (``spec.qk_norm``, Olmo 2's QK-norm) or not."""
+    RMS-normed (``spec.qk_norm``) over their whole width before the
+    heads are split (Olmo 2's QK-norm), over each head's lanes after
+    (LFM2's), or not."""
 
     spec: LayerSpec
     kv_heads: int
@@ -1187,6 +1210,8 @@ class GatedAttention(nn.Module):
                                    self.dtype))
             v = jnp.dot(x, _weight(self, "v_proj", (d, g * hd),
                                    self.dtype))
+            if spec.qk_norm == "head":
+                q, k = q.reshape(b, s_len, h, hd), k.reshape(b, s_len, g, hd)
             if spec.qk_norm:
                 q = RMSNorm(self.eps, name="q_norm")(q).astype(self.dtype)
                 k = RMSNorm(self.eps, name="k_norm")(k).astype(self.dtype)
@@ -1371,6 +1396,40 @@ class KimiDeltaAttention(nn.Module):
             return jnp.dot(
                 o.astype(self.dtype),
                 _weight(self, "o_proj", (h * dv, d), self.dtype))
+
+
+class ShortConv(nn.Module):
+    """LFM2's gated short convolution (``spec.short_conv`` taps), a
+    token mixer with no attention: ``[B | C | x̃] = x W_in`` (that
+    order), ``v = conv(B ⊙ x̃)`` depthwise and causal
+    (:func:`causal_conv`: zero history at a row's start, no bias),
+    ``y = (C ⊙ v) W_out``. The products in the compute type, the
+    gating and the convolution in float32."""
+
+    taps: int
+    dtype: jnp.dtype = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        d = x.shape[-1]
+        with jax.named_scope(scopes.SEQ_SHORTCONV_PROJ):
+            bcx = jnp.dot(x, _weight(self, "in_proj", (d, 3 * d),
+                                     self.dtype))
+        with jax.named_scope(scopes.SEQ_SHORTCONV_MIX):
+            taps = self.param("conv", nn.initializers.normal(0.02),
+                              (self.taps, d), jnp.float32)
+            bcx = bcx.astype(jnp.float32)
+            b, c, xt = jnp.split(bcx, 3, axis=-1)
+            mixed = c * causal_conv(b * xt, taps)
+            # for a check of the float32 gating (``chipbench``): kept
+            # only by a caller that makes ``intermediates`` mutable.
+            # The product as the gating reads it: in a fusion XLA may
+            # hand on the product's float32 sum, not its bf16 rounding
+            self.sow("intermediates", "mix", (bcx, mixed))
+            y = mixed.astype(self.dtype)
+        with jax.named_scope(scopes.SEQ_SHORTCONV_OUT):
+            return jnp.dot(y, _weight(self, "out_proj", (d, d),
+                                      self.dtype))
 
 
 # ----------------------------------------------------- hyper-connections
@@ -1755,7 +1814,8 @@ def group_limited(scores: jax.Array, n_group: int, topk_group: int
 
 class SparseFFN(nn.Module):
     """Router over all ``num_experts``, the held experts' part of the
-    routed result, and the shared expert.
+    routed result, and the shared expert (none where ``shared_width``
+    is 0).
 
     ``scoring`` ``softmax``: the top ``top_k`` of the softmax, by and
     with its probabilities. ``sigmoid``: scores ``s = sigmoid(x W_r)``;
@@ -1839,10 +1899,12 @@ class SparseFFN(nn.Module):
             routed = routed.reshape(t, d) * self.routed_scale
             sizes = sizes.sum(axis=0)
             blocks = blocks.sum(axis=0)
-        with jax.named_scope(scopes.SEQ_SHARED):
-            shared = SwiGLU(self.shared_width, self.dtype,
-                            name="shared")(xb)
-        out = (routed + shared.astype(jnp.float32)).astype(self.dtype)
+        if self.shared_width:
+            with jax.named_scope(scopes.SEQ_SHARED):
+                shared = SwiGLU(self.shared_width, self.dtype,
+                                name="shared")(xb)
+            routed = routed + shared.astype(jnp.float32)
+        out = routed.astype(self.dtype)
         stats = {"moe_routed": jnp.int32(t * self.top_k),
                  "moe_held": sizes.sum(),
                  "moe_dropped": dropped.sum(),
@@ -1885,7 +1947,9 @@ class DecoderLayer(nn.Module):
         spec = self.spec
 
         def attn_scope():
-            return (jax.named_scope(scopes.SEQ_ATTN_KDA) if spec.kda
+            return (jax.named_scope(scopes.SEQ_SHORTCONV)
+                    if spec.short_conv
+                    else jax.named_scope(scopes.SEQ_ATTN_KDA) if spec.kda
                     else jax.named_scope(scopes.SEQ_ATTN_MLA)
                     if spec.latent
                     else jax.named_scope(scopes.SEQ_ATTN_WINDOW)
@@ -1895,6 +1959,9 @@ class DecoderLayer(nn.Module):
         def attention(x):
             n = x if spec.post_norm else RMSNorm(
                 self.eps, name="input_norm")(x).astype(self.dtype)
+            if spec.short_conv:
+                return ShortConv(spec.short_conv, self.dtype,
+                                 name="attn")(n)
             if spec.kda:
                 return KimiDeltaAttention(spec, self.eps, self.dtype,
                                           name="attn")(n)
@@ -1969,6 +2036,9 @@ class SeqPolicyNet(nn.Module):
     #: the module's loss weight, where the config has a key for it
     #: (else the trainer's own)
     mtp_weight: float | None = None
+    #: the head is the embedding's transpose (LFM2's tied head): its
+    #: gradient and the lookup's add up in ``embed``
+    tied: bool = False
 
     @nn.compact
     def __call__(self, ids: jax.Array, next_ids: jax.Array | None = None):
@@ -2028,8 +2098,9 @@ class SeqPolicyNet(nn.Module):
             x = leave(x)
         with jax.named_scope(scopes.SEQ_HEAD):
             n = RMSNorm(self.eps, name="norm")(x).astype(self.dtype)
-            head_w = _weight(self, "head", (self.hidden, self.vocab_held),
-                             self.dtype)
+            head_w = (table.T.astype(self.dtype) if self.tied else
+                      _weight(self, "head", (self.hidden, self.vocab_held),
+                              self.dtype))
             logits = jnp.dot(n, head_w,
                              preferred_element_type=jnp.float32)
         if not self.mtp or next_ids is None:
@@ -2216,9 +2287,34 @@ def olmo_layer_specs(kw: dict) -> tuple:
                   kda=delta, post_norm=True)
         if kind == "linear_attention" else
         LayerSpec(heads=int(kw["num_attention_heads"]), window=0,
-                  rope=rope, sparse=False, gate=False, qk_norm=True,
+                  rope=rope, sparse=False, gate=False, qk_norm="whole",
                   post_norm=True)
         for kind in kinds)
+
+
+def lfm2_layer_specs(kw: dict) -> tuple:
+    """The held layers' ``LayerSpec``s from the ``lfm2_moe`` keys:
+    the gated short convolution where ``layer_types`` says ``conv``,
+    softmax attention with per-head QK-norm and no gate where it says
+    ``full_attention`` (plain rotary on the whole head); the dense MLP
+    in the ``num_dense_layers`` leading layers, routed experts after
+    them; held layer ``i`` is published layer ``i``."""
+    dense = int(kw["num_dense_layers"])
+    kinds = kw["layer_types"][:int(kw["layers_held"])]
+    other = sorted(set(kinds) - {"conv", "full_attention"})
+    if other:
+        raise ValueError(f"SeqPolicy computes an lfm2_moe spec's "
+                         f"layer_types conv and full_attention only; "
+                         f"the spec says {other!r}")
+    heads = int(kw["num_attention_heads"])
+    rope = Rope(kind="default", theta=float(kw["rope_theta"]),
+                dims=int(kw["hidden_size"]) // heads)
+    return tuple(
+        LayerSpec(heads=heads, window=0, rope=rope,
+                  sparse=i >= dense, gate=False, qk_norm="head",
+                  short_conv=int(kw["conv_L_cache"])
+                  if kind == "conv" else 0)
+        for i, kind in enumerate(kinds))
 
 
 #: what this decoder computes one way only; a spec that says
@@ -2226,7 +2322,6 @@ def olmo_layer_specs(kw: dict) -> tuple:
 FIXED = {"attention_bias": False, "gating": "per-head",
          "moe_apply_router_weight_on_input": False,
          "moe_router_logit_softcapping": 0,
-         "tie_word_embeddings": False,
          # the xing4_0 keys: an expert layer wherever the dense
          # ones end, the bias-steered choice
          "hidden_act": "silu", "moe_layer_freq": 1,
@@ -2249,10 +2344,13 @@ FIXED = {"attention_bias": False, "gating": "per-head",
          "use_bias": False, "use_qkv_bias": False, "use_nGPT": False,
          "value_norm": False, "up_proj_norm": False,
          # the olmo_hybrid keys: β = 2σ in the delta layers
-         "linear_allow_neg_eigval": True}
+         "linear_allow_neg_eigval": True,
+         # the lfm2_moe keys: no bias on the short convolution, the
+         # router's selection bias
+         "conv_bias": False, "use_expert_bias": True}
 #: and what it computes in more than one way, by the spec's key
 CHOICES = {"model_type": ("laguna", "xing4_0", "bailing_hybrid",
-                          "olmo_hybrid"),
+                          "olmo_hybrid", "lfm2_moe"),
            "scoring_func": ("softmax", "sigmoid"),
            "num_nextn_predict_layers": (0, 1)}
 
@@ -2314,6 +2412,14 @@ class SeqPolicy(NeuralNetBase):
     @staticmethod
     def create_network(**kw) -> SeqPolicyNet:
         model_type = kw.get("model_type", "laguna")
+        # the head is tied in the LFM2 family and untied in the others
+        # (each one's reference reads the head where its config has it)
+        tied = model_type == "lfm2_moe"
+        if bool(kw.get("tie_word_embeddings", tied)) != tied:
+            raise ValueError(
+                f"SeqPolicy computes tie_word_embeddings={tied!r} for a "
+                f"{model_type} spec only; the spec says "
+                f"{kw['tie_word_embeddings']!r}")
         if model_type == "olmo_hybrid":
             # dense: no expert layer, no expert keys read
             heads = int(kw["num_attention_heads"])
@@ -2331,6 +2437,22 @@ class SeqPolicy(NeuralNetBase):
                 "width": int(kw["moe_intermediate_size"]),
                 "norm_topk": bool(kw["norm_topk_prob"]),
                 "scoring": kw.get("scoring_func", "softmax")}
+        if model_type == "lfm2_moe":
+            # a sigmoid router steered by its selection bias, no shared
+            # expert, the tied head
+            heads = int(kw["num_attention_heads"])
+            ffn = dict(held, scoring="sigmoid",
+                       num_experts=int(kw["num_experts"]), shared_width=0,
+                       routed_scale=float(kw["routed_scaling_factor"]))
+            return SeqPolicyNet(
+                layers=lfm2_layer_specs(kw),
+                hidden=int(kw["hidden_size"]),
+                vocab_held=int(kw["vocab_held"]),
+                kv_heads=int(kw["num_key_value_heads"]),
+                head_dim=int(kw["hidden_size"]) // heads,
+                dense_width=int(kw["intermediate_size"]),
+                ffn=tuple(sorted(ffn.items())), eps=float(kw["norm_eps"]),
+                tied=True)
         mtp_layer = mtp_weight = None
         if model_type == "bailing_hybrid":
             layers, mtp_layer = ling_layer_specs(kw)

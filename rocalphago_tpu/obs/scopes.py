@@ -71,6 +71,16 @@ SEQ_ATTN_KDA_DECAY = "seq.attn.kda.proj.decay"
 SEQ_ATTN_KDA_SCAN = "seq.attn.kda.scan"
 #: the per-head output norm, the channel-wise gate and ``o_proj``
 SEQ_ATTN_KDA_OUT = "seq.attn.kda.out"
+#: a gated short-convolution layer's input norm and residual, and the
+#: three parts below (a reader of ``seq.shortconv`` sums them)
+SEQ_SHORTCONV = "seq.shortconv"
+#: inside it: ``in_proj``, the product ``[B | C | x̃] = x W_in``
+SEQ_SHORTCONV_PROJ = "seq.shortconv.proj"
+#: ``B ⊙ x̃``, the depthwise causal convolution's taps and ``C ⊙``, in
+#: float32, and the cast of the result
+SEQ_SHORTCONV_MIX = "seq.shortconv.mix"
+#: ``out_proj``
+SEQ_SHORTCONV_OUT = "seq.shortconv.out"
 #: inside any of the softmax three, where the kernel runs: Pallas
 #: ``splash_attention`` (forward and backward kernels) and the
 #: ``[B,S,H,d]`` → ``[B,H,S,d]`` transposes of q, k, v in front of it

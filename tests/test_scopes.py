@@ -221,6 +221,34 @@ def olmo_ops():
 
 
 @pytest.fixture(scope="module")
+def lfm2_ops():
+    """The COMPILED supervised train step of the move-sequence policy
+    built from a toy ``lfm2_moe`` spec: a gated short convolution with
+    the dense MLP, a per-head QK-normed full layer with experts and no
+    shared expert, the tied head."""
+    from rocalphago_tpu.io.checkpoint import pack_rng
+    from rocalphago_tpu.models.seqpolicy import SeqPolicy
+    from rocalphago_tpu.training import sl
+
+    net = SeqPolicy(
+        board=SIZE, model_type="lfm2_moe", vocab_size=32, vocab_held=32,
+        hidden_size=8, intermediate_size=8, moe_intermediate_size=4,
+        num_hidden_layers=2, layers_held=2, num_dense_layers=1,
+        num_attention_heads=2, num_key_value_heads=1, num_experts=4,
+        num_experts_per_tok=2, experts_held=2, expert_offset=0,
+        layer_types=["conv", "full_attention"], conv_L_cache=3,
+        norm_eps=1e-5, norm_topk_prob=True, routed_scaling_factor=1,
+        rope_theta=1000000)
+    tx = sl.make_optimizer(sl.SLConfig())
+    step = sl.make_train_step(net.module.apply, tx, SIZE, True)
+    state = sl.SLState(net.params, tx.init(net.params), jnp.int32(0),
+                       pack_rng(jax.random.key(0)))
+    ids = jnp.zeros((2, 8), jnp.int32)
+    return op_names(jax.jit(step).lower(
+        state, ids, ids).compile().as_text())
+
+
+@pytest.fixture(scope="module")
 def ply_ops():
     from rocalphago_tpu.search.selfplay import _make_ply
 
@@ -338,13 +366,18 @@ LING = [scopes.SEQ_ATTN_KDA, scopes.SEQ_ATTN_KDA_PROJ,
         scopes.SEQ_ATTN_KDA_SCAN, scopes.SEQ_ATTN_KDA_OUT]
 #: what an ``olmo_hybrid`` spec adds: the post-norm blocks' norms
 OLMO = [scopes.SEQ_POST_NORM]
+#: what an ``lfm2_moe`` spec adds: the short convolution's scope and
+#: the three parts inside it
+LFM2 = [scopes.SEQ_SHORTCONV, scopes.SEQ_SHORTCONV_PROJ,
+        scopes.SEQ_SHORTCONV_MIX, scopes.SEQ_SHORTCONV_OUT]
+SHORTCONV_PARTS = LFM2[1:]
 
 
 def test_every_constant_has_a_case():
     # the attention kernel's scope exists only in a program traced
     # for a TPU: tests/test_seqpolicy.py lowers it there
     assert sorted(TRAIN + PLY + ENCODE + EVAL + MCTS + SEQ + XING + LING
-                  + OLMO + PARTS + [scopes.SEQ_ATTN_KERNEL]) \
+                  + OLMO + LFM2 + PARTS + [scopes.SEQ_ATTN_KERNEL]) \
         == sorted(scopes.ALL)
     assert len(set(scopes.ALL)) == len(scopes.ALL)
 
@@ -481,6 +514,48 @@ def test_the_post_norm_lies_in_no_other_scope(olmo_ops):
             assert scopes.SEQ_POST_NORM not in name
 
 
+@pytest.mark.parametrize("name", LFM2 + [
+    scopes.SEQ_ATTN_FULL, scopes.SEQ_ROUTER, scopes.SEQ_EXPERTS,
+    scopes.SEQ_DENSE_FFN, scopes.SEQ_HEAD, scopes.TRAIN_LOSS,
+    scopes.SEQ_ATTN_PROJ, scopes.SEQ_ATTN_ROPE, scopes.SEQ_ATTN_OUT])
+def test_lfm2_step_scope_survives_the_compile(lfm2_ops, name):
+    assert has(lfm2_ops, name), sorted(set(lfm2_ops))[:40]
+
+
+@pytest.mark.parametrize("name", LFM2)
+def test_lfm2_scopes_name_the_backward_pass_too(lfm2_ops, name):
+    mine = [op for op in lfm2_ops if name in op.split("/")]
+    assert any("transpose(jvp(SeqPolicyNet))" in op for op in mine)
+    assert any("transpose(" not in op for op in mine)
+
+
+def test_the_short_convolution_lies_in_no_other_scope(lfm2_ops):
+    """``seq.shortconv`` lies in no other top-level ``seq.*`` scope
+    and holds none, so the by-scope account still partitions; its
+    three parts lie inside it, one at a time, and in no attention
+    layer; per-head QK-norm reads under ``seq.attn.proj``; the layer
+    with no shared expert and no gate has neither's scope."""
+    tops = [n for n in scopes.ALL if n.startswith("seq.")
+            and n != scopes.SEQ_ATTN_KERNEL
+            and n not in SHORTCONV_PARTS + LING[1:] + PARTS
+            and not n.startswith(scopes.SEQ_EXPERTS + ".")]
+    parts = {}
+    for op in lfm2_ops:
+        path = op.split("/")
+        assert len([n for n in tops if n in path]) <= 1, op
+        inside = [n for n in SHORTCONV_PARTS if n in path]
+        assert len(inside) <= 1, op
+        if inside:
+            parts[inside[0]] = parts.get(inside[0], 0) + 1
+            assert scopes.SEQ_SHORTCONV in path, op
+            assert not [n for n in ATTN_LAYERS + PARTS if n in path], op
+    assert set(parts) == set(SHORTCONV_PARTS)
+    assert any(scopes.SEQ_ATTN_PROJ in op.split("/") and "q_norm" in op
+               for op in lfm2_ops)
+    assert not has(lfm2_ops, scopes.SEQ_SHARED)
+    assert not has(lfm2_ops, scopes.SEQ_ATTN_GATE)
+
+
 @pytest.mark.parametrize("ops, name", [
     (ops, name) for ops, names in (
         ("seq_ops", ATTN_PARTS), ("xing_ops", XING_PARTS),
@@ -507,14 +582,21 @@ def test_a_part_lies_inside_its_parent_and_beside_the_others(
 def test_no_part_is_a_substring_of_another_scope():
     """``chipbench``'s readers match a scope by substring: a new name
     may be contained in, or contain, its parents' alone."""
-    for name in PARTS:
+    for name in PARTS + LFM2:
         for other in scopes.ALL:
             if other == name:
                 continue
-            assert name not in other, (name, other)
+            if name != scopes.SEQ_SHORTCONV:
+                assert name not in other, (name, other)
             if other in name:
-                assert name in KDA_PARTS and other in (
-                    scopes.SEQ_ATTN_KDA, scopes.SEQ_ATTN_KDA_PROJ)
+                if name in SHORTCONV_PARTS:
+                    assert other == scopes.SEQ_SHORTCONV, (name, other)
+                else:
+                    assert name in KDA_PARTS and other in (
+                        scopes.SEQ_ATTN_KDA, scopes.SEQ_ATTN_KDA_PROJ)
+    # and the layer's own scope is held by its parts alone
+    assert sorted(n for n in scopes.ALL if scopes.SEQ_SHORTCONV in n) \
+        == sorted(LFM2)
 
 
 @pytest.mark.parametrize("name", ATTN_PARTS)

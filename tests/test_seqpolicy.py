@@ -791,7 +791,9 @@ def test_the_kernel_runs_under_its_scope_where_its_tiles_fit():
     with unittest.mock.patch.object(seqpolicy, "kernel_platform",
                                     lambda: "tpu"):
         assert seqpolicy.use_kernel(8192, 128)
-        assert not seqpolicy.use_kernel(8192, 64)
+        # half a lane tile (LFM2's heads), and no narrower
+        assert seqpolicy.use_kernel(8192, 64)
+        assert not seqpolicy.use_kernel(8192, 32)
         assert not seqpolicy.use_kernel(32, 128)
 
 

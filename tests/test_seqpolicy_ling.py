@@ -909,8 +909,8 @@ def test_the_other_two_blocks_build_what_they_built(other):
         assert lowered() == text
 
 
-#: sha256 of the toy Laguna, Xing and Ling train steps' lowered text,
-#: as the last commit that meant to change those steps left it
+#: sha256 of the toy Laguna, Xing, Ling and Olmo train steps' lowered
+#: text, as the last commit that meant to change those steps left it
 LOWERINGS = os.path.join(ROOT, "tests", "data",
                          "seq_step_lowerings.json")
 
@@ -938,7 +938,8 @@ def toy_step_digest(other: str) -> str:
 
 @pytest.mark.parametrize("other", ["test_seqpolicy",
                                    "test_seqpolicy_xing",
-                                   "test_seqpolicy_ling"])
+                                   "test_seqpolicy_ling",
+                                   "test_seqpolicy_olmo"])
 def test_the_other_two_steps_lower_to_the_text_on_record(other):
     """A laguna and an xing4_0 spec build no delta layer, so a change
     to the delta layers leaves their train steps' StableHLO what it
@@ -946,8 +947,12 @@ def test_the_other_two_steps_lower_to_the_text_on_record(other):
     before the mixing pass (PR 34's), taken from its checkout by this
     function. The bailing_hybrid step's is PR 35's: a delta layer of
     another form (Gated DeltaNet, PR 36) shares the scan and the
-    mixing pass and leaves Kimi delta attention's step as it was. A
-    commit that MEANS to change those steps records anew: ``python
+    mixing pass and leaves Kimi delta attention's step as it was. The
+    olmo_hybrid step's is the one before the lfm2_moe dialect came: a
+    short-convolution mixer, per-head QK-norm, an expert layer without
+    a shared expert and a tied head leave the four dialects' steps as
+    they were. A commit that
+    MEANS to change those steps records anew: ``python
     tests/test_seqpolicy_ling.py`` rewrites the file."""
     with open(LOWERINGS) as f:
         assert toy_step_digest(other) == json.load(f)[other]
@@ -984,7 +989,7 @@ if __name__ == "__main__":
         patch.setattr(seqpolicy, "KDA_SUB", 4)
         digests = {other: toy_step_digest(other) for other in
                    ("test_seqpolicy", "test_seqpolicy_xing",
-                    "test_seqpolicy_ling")}
+                    "test_seqpolicy_ling", "test_seqpolicy_olmo")}
     os.makedirs(os.path.dirname(LOWERINGS), exist_ok=True)
     with open(LOWERINGS, "w") as f:
         json.dump(digests, f, indent=1)
