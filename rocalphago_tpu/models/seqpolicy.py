@@ -525,12 +525,11 @@ KDA_SUB = 16
 KDA_EXACT = jax.lax.Precision.HIGHEST
 
 
-@jax.custom_vjp
-def unit_lower_inverse(a: jax.Array) -> jax.Array:
-    """``(I + a)^−1`` of strictly lower triangular ``a [..., C, C]``:
-    ``a`` is nilpotent, so the inverse is ``Σ (−a)^i = (I − a)(I +
-    a²)(I + a⁴)…`` — ``log₂ C`` squarings and as many products, all
-    of them matrix products over every chunk and head at once."""
+def _inverse_by_series(a: jax.Array) -> jax.Array:
+    """The definition of :func:`unit_lower_inverse`: ``a`` is
+    nilpotent, so the inverse is ``Σ (−a)^i = (I − a)(I + a²)(I +
+    a⁴)…`` — ``log₂ C`` squarings and as many products, all of them
+    matrix products over every chunk and head at once."""
     c = a.shape[-1]
     x, power, covered = jnp.eye(c, dtype=a.dtype) - a, a, 2
     while covered < c:
@@ -538,6 +537,118 @@ def unit_lower_inverse(a: jax.Array) -> jax.Array:
         x = x + jnp.matmul(x, power, precision=KDA_EXACT)
         covered *= 2
     return x
+
+
+# The series has a second lowering, one Pallas kernel on a TPU
+# (:func:`use_inverse_kernel`): XLA runs each of its ten products as
+# a pass over HBM, where a 64-wide float32 matrix pads to the 128
+# lanes of a tile, so every product reads two and writes one array of
+# twice the matrices' bytes. The kernel reads a block of matrices once
+# and keeps them in VMEM through the ten products. Inside it two
+# matrices lie side by side in a tile's 128 lanes and meet a
+# block-diagonal right operand, so a product fills the MXU's whole
+# width; and the two products of a turn share their right operand
+# (with ``p_k = a^{2^k}`` and ``x_k`` the series to ``p_k``:
+# ``[p_k; x_{k−1}] p_k = [p_{k+1}; x_k − x_{k−1}]``), so a turn is one
+# product of twice the rows. The products are the series' own, in its
+# order, at ``KDA_EXACT``. (Laid side by side in XLA instead, the pair
+# costs a relayout of the array each way around the kernel: 2.9 ms a
+# call against 2.1 at Ling's 4,096 matrices on one v5e, PERF.md §6.)
+
+#: matrices of a block of the inverse's kernel at most, and of one
+#: turn of its loop inside the block (a block in and out, both
+#: buffered: 16 MiB of VMEM at 128 — the 64 lanes it uses of a tile's
+#: 128 — well inside the 64 MiB a call is given)
+KDA_INV_BLOCK = 128
+KDA_INV_SUB = 16
+
+
+def use_inverse_kernel(a: jax.Array) -> bool:
+    """Whether the kernel takes ``a [..., C, C]``: on a TPU, float32,
+    an even count of chunks 64 wide (two to a 128-lane tile)."""
+    return (kernel_platform() == "tpu" and a.dtype == jnp.float32
+            and a.shape[-1] == 64 and (a.size // 64 // 64) % 2 == 0)
+
+
+def _inverse_kernel(a_ref, x_ref, *, sub: int):
+    """``x_ref`` ← :func:`_inverse_by_series` of each matrix of
+    ``a_ref [block, C, C]``, ``sub`` matrices — ``sub // 2`` pairs side
+    by side — a turn of the loop."""
+    from jax.experimental import pallas as pl
+
+    c = a_ref.shape[-1]
+    squarings = (c - 1).bit_length() - 1
+
+    def iota(shape, axis):
+        return jax.lax.broadcasted_iota(jnp.int32, shape, axis)
+
+    eye = (iota((c, 2 * c), 0) == iota((c, 2 * c), 1) % c).astype(
+        jnp.float32)
+    diagonal = (iota((2 * c, 2 * c), 0) < c) == (iota((2 * c, 2 * c), 1)
+                                                 < c)
+
+    def product(x, p):
+        """Each pair of ``x`` times its own pair of ``p`` as the
+        block-diagonal ``[[p₀, 0], [0, p₁]]``."""
+        right = jnp.where(diagonal, jnp.concatenate([p, p], axis=1), 0.0)
+        return jnp.einsum("bij,bjk->bik", x, right, precision=KDA_EXACT,
+                          preferred_element_type=jnp.float32)
+
+    def turn(i, carry):
+        at = pl.ds(pl.multiple_of(i * sub, sub), sub)
+        a = a_ref[at].reshape(sub // 2, 2, c, c)
+        a = jnp.concatenate([a[:, 0], a[:, 1]], axis=-1)
+        x, p = eye - a, product(a, a)
+        for _ in range(squarings - 1):
+            both = product(jnp.concatenate([p, x], axis=1), p)
+            p, x = both[:, :c], x + both[:, c:]
+        x = x + product(x, p)
+        x_ref[at] = jnp.stack([x[..., :c], x[..., c:]], axis=1).reshape(
+            sub, c, c)
+        return carry
+
+    jax.lax.fori_loop(0, a_ref.shape[0] // sub, turn, 0)
+
+
+@functools.partial(jax.jit, static_argnames=("block", "interpret"))
+def inverse_kernel(a: jax.Array, *, block: int | None = None,
+                   interpret: bool = False) -> jax.Array:
+    """:func:`_inverse_by_series` of float32 ``a [..., 64, 64]`` (an
+    even count of matrices) by the Pallas kernel, in blocks of
+    ``block`` matrices — ``KDA_INV_BLOCK`` or all of them, whichever
+    is fewer, unless given — the last block partial where they do not
+    divide the count."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    c = a.shape[-1]
+    flat = a.reshape(-1, c, c)
+    n = flat.shape[0]
+    rows = min(block or KDA_INV_BLOCK, n)
+    spec = pl.BlockSpec((rows, c, c), lambda i: (i, 0, 0))
+    x = pl.pallas_call(
+        functools.partial(_inverse_kernel,
+                          sub=math.gcd(rows, KDA_INV_SUB)),
+        name="unit_lower_inverse", grid=(pl.cdiv(n, rows),),
+        in_specs=[spec], out_specs=spec, out_shape=flat,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=64 * 1024 * 1024),
+        cost_estimate=pl.CostEstimate(
+            flops=10 * 2 * c ** 3 * n, transcendentals=0,
+            bytes_accessed=2 * flat.size * flat.dtype.itemsize),
+        interpret=interpret)(flat)
+    return x.reshape(a.shape)
+
+
+@jax.custom_vjp
+def unit_lower_inverse(a: jax.Array) -> jax.Array:
+    """``(I + a)^−1`` of strictly lower triangular ``a [..., C, C]``:
+    :func:`_inverse_by_series`, by the kernel where
+    :func:`use_inverse_kernel` says."""
+    if use_inverse_kernel(a):
+        return inverse_kernel(a)
+    return _inverse_by_series(a)
 
 
 def _unit_lower_inverse_fwd(a):
@@ -706,7 +817,9 @@ def kda_chunked(q, k, v, g, beta, dtype=jnp.bfloat16):
     row = jnp.arange(c)[:, None]
     col = jnp.arange(c)[None, :]
     a_q = jnp.where(row >= col, a_q, 0.0)
-    t = unit_lower_inverse(jnp.where(row > col, bc * a_k, 0.0))
+    a_k = jnp.where(row > col, bc * a_k, 0.0)
+    with jax.named_scope(scopes.SEQ_ATTN_KDA_SCAN_INVERSE):
+        t = unit_lower_inverse(a_k)
     t = t * jnp.swapaxes(bc, -1, -2)                    # T Diag(β)
     grown = jnp.exp(gc)                                 # e^G ≤ 1
     last = gc[..., -1:, :]

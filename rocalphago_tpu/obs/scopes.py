@@ -69,6 +69,11 @@ SEQ_ATTN_KDA_DECAY = "seq.attn.kda.proj.decay"
 #: the pairwise decays, the inverse, the scan over chunks — forward,
 #: recomputed forward and backward
 SEQ_ATTN_KDA_SCAN = "seq.attn.kda.scan"
+#: inside it: the inverse ``(I + A)^−1`` of every chunk and head
+#: (``seqpolicy.unit_lower_inverse``; the Pallas kernel
+#: ``unit_lower_inverse`` on a TPU) — forward, recomputed forward and
+#: its ``custom_vjp`` backward, which runs under its call's scope
+SEQ_ATTN_KDA_SCAN_INVERSE = "seq.attn.kda.scan.inverse"
 #: the per-head output norm, the channel-wise gate and ``o_proj``
 SEQ_ATTN_KDA_OUT = "seq.attn.kda.out"
 #: a gated short-convolution layer's input norm and residual, and the

@@ -2,7 +2,9 @@
 ``_backward``) compiled for a described TPU v5e at the sequence cells'
 real widths, with the blocks ``seqpolicy.mixing_blocks`` chooses:
 Ling's heads of 128, Olmo's q and k heads of 96 (the whole 2,880 lanes
-a block) and v heads of 192. The chip's compiler refuses here what
+a block) and v heads of 192; and the scan's inverse
+(``seqpolicy.inverse_kernel``) at the cells' chunks, 32 and 30 heads
+of 128 chunks of 64. The chip's compiler refuses here what
 interpret mode passes — a slice it cannot lay out, more VMEM than a
 call may take — at no chip time. Nothing runs; nothing here is a
 device number.
@@ -74,3 +76,11 @@ def test_the_mixing_kernel_compiles_for_a_v5e(one_chip, case, which):
                 y, taps, d, heads, norm)).lower(y, taps, d)
     text = lowered.compile().as_text()
     assert f"kda_mixed_{which}" in text and "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("heads", [32, 30], ids=["ling", "olmo"])
+def test_the_inverse_kernel_compiles_for_a_v5e(one_chip, heads):
+    a = jax.ShapeDtypeStruct((128, 1, heads, 64, 64), jnp.float32,
+                             sharding=one_chip)
+    text = jax.jit(seqpolicy.inverse_kernel).lower(a).compile().as_text()
+    assert "unit_lower_inverse" in text and "tpu_custom_call" in text

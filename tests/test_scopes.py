@@ -364,6 +364,8 @@ XING = [scopes.SEQ_ATTN_MLA, scopes.SEQ_MHC_COEFF,
 #: the three parts inside it
 LING = [scopes.SEQ_ATTN_KDA, scopes.SEQ_ATTN_KDA_PROJ,
         scopes.SEQ_ATTN_KDA_SCAN, scopes.SEQ_ATTN_KDA_OUT]
+#: inside ``seq.attn.kda.scan``: the inverse
+SCAN_PARTS = [scopes.SEQ_ATTN_KDA_SCAN_INVERSE]
 #: what an ``olmo_hybrid`` spec adds: the post-norm blocks' norms
 OLMO = [scopes.SEQ_POST_NORM]
 #: what an ``lfm2_moe`` spec adds: the short convolution's scope and
@@ -377,8 +379,8 @@ def test_every_constant_has_a_case():
     # the attention kernel's scope exists only in a program traced
     # for a TPU: tests/test_seqpolicy.py lowers it there
     assert sorted(TRAIN + PLY + ENCODE + EVAL + MCTS + SEQ + XING + LING
-                  + OLMO + LFM2 + PARTS + [scopes.SEQ_ATTN_KERNEL]) \
-        == sorted(scopes.ALL)
+                  + SCAN_PARTS + OLMO + LFM2 + PARTS
+                  + [scopes.SEQ_ATTN_KERNEL]) == sorted(scopes.ALL)
     assert len(set(scopes.ALL)) == len(scopes.ALL)
 
 
@@ -454,7 +456,7 @@ def test_ling_scopes_lie_side_by_side(ling_ops):
     parts = LING[1:]
     tops = [n for n in scopes.ALL if n.startswith("seq.")
             and n != scopes.SEQ_ATTN_KERNEL
-            and n not in parts + PARTS
+            and n not in parts + PARTS + SCAN_PARTS
             and not n.startswith(scopes.SEQ_EXPERTS + ".")]
     for op in ling_ops:
         path = op.split("/")
@@ -468,6 +470,38 @@ def test_ling_scopes_lie_side_by_side(ling_ops):
     assert any("while" in op for op in scan)     # the loop over chunks
     assert any(scopes.SEQ_ROUTER in op.split("/") and "top_k" in op
                for op in ling_ops)
+
+
+@pytest.mark.parametrize("ops", ["ling_ops", "olmo_ops"])
+def test_the_inverse_lies_inside_the_scan_alone(request, ops):
+    """Both delta-rule forms: ``seq.attn.kda.scan.inverse`` names the
+    forward, the recomputed forward and the ``custom_vjp`` rule's
+    backward (``transpose(jvp(…))``), and every op under it lies
+    inside ``seq.attn.kda.scan`` and ``seq.attn.kda`` and under no
+    other scope, so ``kda_scan_ms_per_step.train`` still reads the
+    whole scan."""
+    inverse = scopes.SEQ_ATTN_KDA_SCAN_INVERSE
+    mine = [op for op in request.getfixturevalue(ops)
+            if inverse in op.split("/")]
+    assert any("transpose(jvp(SeqPolicyNet))" in op for op in mine)
+    assert any("transpose(" not in op for op in mine)
+    parents = (scopes.SEQ_ATTN_KDA, scopes.SEQ_ATTN_KDA_SCAN, inverse)
+    for op in mine:
+        path = op.split("/")
+        assert scopes.SEQ_ATTN_KDA_SCAN in path, op
+        assert scopes.SEQ_ATTN_KDA in path, op
+        assert not [n for n in scopes.ALL
+                    if n not in parents and n in path], op
+
+
+def test_no_other_name_holds_the_inverses():
+    """``chipbench``'s readers match by substring: no other scope
+    contains ``seq.attn.kda.scan.inverse``, and it contains its two
+    parents alone."""
+    inverse = scopes.SEQ_ATTN_KDA_SCAN_INVERSE
+    assert [n for n in scopes.ALL if inverse in n] == [inverse]
+    assert sorted(n for n in scopes.ALL if n in inverse) == sorted(
+        [scopes.SEQ_ATTN_KDA, scopes.SEQ_ATTN_KDA_SCAN, inverse])
 
 
 @pytest.mark.parametrize("name", OLMO + LING + [
@@ -493,7 +527,7 @@ def test_the_post_norm_lies_in_no_other_scope(olmo_ops):
     ``seq.attn.kda`` as in a ``bailing_hybrid`` step."""
     tops = [n for n in scopes.ALL if n.startswith("seq.")
             and n != scopes.SEQ_ATTN_KERNEL
-            and n not in LING[1:] + PARTS
+            and n not in LING[1:] + PARTS + SCAN_PARTS
             and not n.startswith(scopes.SEQ_EXPERTS + ".")]
     post = 0
     for op in olmo_ops:
