@@ -132,6 +132,13 @@ router, softmax and loss):
   (sliding layers, ``block = window``) — :func:`grouped_attention`.
   On the v5e the XLA form spent 3.2 s a step in its softmax's lane
   reductions (PERF.md, PR 26), which is why the kernel is there;
+  where the kernel runs, its q and k (and latent attention's v) come
+  out of one float32 pass each way from the projection's product —
+  the rotary, the scale, the cast, latent attention's concatenations
+  and its key's broadcast, written in the kernel's head-major order —
+  two Pallas kernels on a TPU (:func:`rope_head_major`,
+  :func:`latent_head_major`); the XLA form takes them from
+  :func:`apply_rope` as they are;
 * the expert product is ``jax.lax.ragged_dot`` over token–expert
   pairs sorted by held expert — a grouped matrix product over a
   ragged split. The pairs sent here are sorted to the front of a
@@ -287,6 +294,14 @@ def rope_inv_freq(rope: Rope) -> np.ndarray:
     return inv.astype(np.float32)
 
 
+def rope_angles(rope: Rope, s_len: int) -> jax.Array:
+    """float32 angles ``[S, dims / 2]``: position × inverse frequency.
+    Their ``cos`` and ``sin`` × ``attention_factor`` are the rotary's
+    tables wherever it is taken."""
+    pos = jnp.arange(s_len, dtype=jnp.float32)
+    return pos[:, None] * jnp.asarray(rope_inv_freq(rope))[None, :]
+
+
 def apply_rope(x: jax.Array, rope: Rope, scale: float = 1.0
                ) -> jax.Array:
     """Rotate the leading ``rope.dims`` of each head of ``x``
@@ -294,8 +309,7 @@ def apply_rope(x: jax.Array, rope: Rope, scale: float = 1.0
     dimension ``i`` with ``i + dims/2``), in float32; ``scale``
     (the queries' ``1/√d``) is applied before the cast back."""
     d = rope.dims
-    pos = jnp.arange(x.shape[1], dtype=jnp.float32)
-    angle = pos[:, None] * jnp.asarray(rope_inv_freq(rope))[None, :]
+    angle = rope_angles(rope, x.shape[1])
     cos = (jnp.cos(angle) * rope.attention_factor)[None, :, None, :]
     sin = (jnp.sin(angle) * rope.attention_factor)[None, :, None, :]
     xf = x.astype(jnp.float32)
@@ -337,16 +351,17 @@ def use_kernel(s_len: int, *head_dims: int) -> bool:
 
 
 def kernel_attention(q, k, v, window: int, interpret: bool = False):
-    """Causal grouped-query attention by the splash kernel:
-    ``q [B, S, H, d]``, ``k [B, S, G, d]``, ``v [B, S, G, dv]`` →
-    ``[B, S, H, dv]``. ``window`` 0 is full attention, else
-    ``i − j < window``."""
+    """Causal grouped-query attention by the splash kernel, every
+    operand head-major as the rotary pass writes q and k
+    (:func:`rope_head_major`, :func:`latent_head_major`): ``q [B, H, S,
+    d]``, ``k [B, G, S, d]``, ``v [B, G, S, dv]`` → ``[B, S, H, dv]``.
+    ``window`` 0 is full attention, else ``i − j < window``."""
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as splash,
         splash_attention_mask as masks,
     )
 
-    s_len, h = q.shape[1], q.shape[2]
+    h, s_len = q.shape[1], q.shape[2]
     one = (masks.LocalMask((s_len, s_len), (window - 1, 0), 0)
            if window and window < s_len
            else masks.CausalMask((s_len, s_len)))
@@ -360,8 +375,7 @@ def kernel_attention(q, k, v, window: int, interpret: bool = False):
         residual_checkpoint_name=KERNEL_RESIDUALS,
         interpret=interpret)
     with jax.named_scope(scopes.SEQ_ATTN_KERNEL):
-        out = jax.vmap(kernel)(*(x.transpose(0, 2, 1, 3)
-                                 for x in (q, k, v)))
+        out = jax.vmap(kernel)(q, k, v)
     return out.transpose(0, 2, 1, 3)
 
 
@@ -455,13 +469,6 @@ def grouped_attention(q, k, v, window: int):
     out = jax.lax.map(jax.checkpoint(lambda a: one(*a)), (qg, kg, vg))
     out = out.reshape(b, g, r, s_len, dv).transpose(0, 3, 1, 2, 4)
     return out.reshape(b, s_len, h, dv)
-
-
-def _attention(q, k, v, window: int):
-    """The kernel where its tiles fit, else the XLA form."""
-    if use_kernel(q.shape[1], q.shape[3], v.shape[3]):
-        return kernel_attention(q, k, v, window)
-    return grouped_attention(q, k, v, window)
 
 
 # ------------------------------------------------- Kimi delta attention
@@ -1255,6 +1262,435 @@ def _kda_mixed_bwd(heads, norm, kept, d):
 kda_mixed.defvjp(_kda_mixed_fwd, _kda_mixed_bwd)
 
 
+# --------------- from a q or k projection's product to the kernel's operand
+#
+# Where the attention kernel runs, what lies between the product ``x [B,
+# S, H·d]`` and the kernel's head-major operand ``[B, H, S, d]`` — the
+# rotary, the queries' scale, the cast, and in latent attention the
+# non-rotary lanes' scale, the key's broadcast to every head and the two
+# concatenations — is one pass each way. Latent attention's value comes
+# out of the same pass as its key: read for the key alone, ``kv`` took
+# the layout the value's transposition wanted and was copied for the
+# pass (PERF.md §6). The rotary is the linear map
+#
+#     y = x·C + roll(x, +h)·S₊ + roll(x, −h)·S₋          (h = dims / 2)
+#
+# along a head's lanes, with tables ``[S, d]`` (:func:`rope_tables`):
+# ``C`` the rotary's ``cos`` on its lanes and 1 elsewhere, ``S₋`` its
+# ``−sin`` on the first half, ``S₊`` its ``sin`` on the second, 0
+# elsewhere — :func:`apply_rope`'s own float32 products and sums (a
+# negation is exact and a zero term adds nothing), so its bits, the sign
+# of an exact zero aside. The backward is the map's transpose,
+#
+#     dx = dy·C + roll(dy·S₊, −h) + roll(dy·S₋, +h),
+#
+# and keeps nothing: the tables are functions of the shapes. On a TPU
+# (:func:`rope_blocks`) each way is one Pallas kernel: a block of rows ×
+# whole heads of ``x`` in whole 128-lane tiles, rotated a tile at a time
+# by ``pltpu.roll`` (each head's rotated lanes lie inside one tile), and
+# the transposition done by the block index maps — ``[B, S, H·d]`` read,
+# ``[B, H, S, d]`` written, and back. Anywhere else it is its definition,
+# :func:`apply_rope` and the transposition, differentiated by JAX.
+
+#: rows × lanes of a block of the rotary kernels at most
+ROPE_BLOCK = 1 << 19
+#: lanes of a block at most, where the heads allow
+ROPE_LANES = 512
+
+
+def rope_tables(rope: Rope, s_len: int, head: int, at: int = 0):
+    """``(C, S₊, S₋)``, float32 ``[S, head]``: one head's lanes, the
+    rotated ones ``rope.dims`` from lane ``at``."""
+    angle = rope_angles(rope, s_len)
+    cos = jnp.cos(angle) * rope.attention_factor
+    sin = jnp.sin(angle) * rope.attention_factor
+    zero = jnp.zeros_like(cos)
+
+    def lay(first, second, other: float):
+        fill = [jnp.full((s_len, n), other, jnp.float32)
+                for n in (at, head - at - rope.dims)]
+        return jnp.concatenate([fill[0], first, second, fill[1]], axis=1)
+
+    return lay(cos, cos, 1.0), lay(zero, sin, 0.0), lay(-sin, zero, 0.0)
+
+
+def rope_blocks(s_len: int, width: int, head: int, at: int, dims: int):
+    """The block ``(rows, lanes)`` of the rotary kernels over ``width``
+    lanes of heads ``head`` wide rotated on ``dims`` lanes from ``at``,
+    or None where none is legal: lanes whole heads in whole 128-lane
+    tiles that divide the width, each head's rotated lanes inside one
+    tile — the widest up to ``ROPE_LANES``; rows whole bf16 tiles that
+    divide the row, the most up to ``ROPE_BLOCK`` elements."""
+    tile = math.lcm(head, 128)
+    if width % tile or dims % 2 or any(
+            (i * head + at) % 128 + dims > 128
+            for i in range(tile // head)):
+        return None
+    lanes = max((n for n in range(tile, width + 1, tile)
+                 if width % n == 0 and n <= ROPE_LANES), default=tile)
+    rows = [n for n in range(16, s_len + 1, 16)
+            if s_len % n == 0 and n * lanes <= ROPE_BLOCK]
+    return (rows[-1], lanes) if rows else None
+
+
+def use_rope_kernel(x, heads: int, rope: Rope, at: int) -> bool:
+    """Whether the rotary kernels take ``x [B, S, H·d]``: on a TPU,
+    wherever :func:`rope_blocks` finds a block."""
+    _, s_len, width = x.shape
+    return (kernel_platform() == "tpu" and rope_blocks(
+        s_len, width, width // heads, at, rope.dims) is not None)
+
+
+def _rotated(x, tables, half: int):
+    """``x [R, 128·n]`` float32 by the map above a tile at a time;
+    ``tables`` three ``[R, P]``, ``P`` lanes a period of the heads."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    c, sp, sm = tables
+    tiles = []
+    for t in range(0, x.shape[1], 128):
+        p = t % c.shape[1]
+        xt = x[:, t:t + 128]
+        tiles.append(xt * c[:, p:p + 128]
+                     + pltpu.roll(xt, half, 1) * sp[:, p:p + 128]
+                     + pltpu.roll(xt, 128 - half, 1) * sm[:, p:p + 128])
+    return tiles[0] if len(tiles) == 1 else jnp.concatenate(tiles, 1)
+
+
+def _rotated_back(d, tables, half: int):
+    """The transpose of :func:`_rotated`."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    c, sp, sm = tables
+    tiles = []
+    for t in range(0, d.shape[1], 128):
+        p = t % c.shape[1]
+        dt = d[:, t:t + 128]
+        tiles.append(
+            dt * c[:, p:p + 128]
+            + pltpu.roll(dt * sp[:, p:p + 128], 128 - half, 1)
+            + pltpu.roll(dt * sm[:, p:p + 128], half, 1))
+    return tiles[0] if len(tiles) == 1 else jnp.concatenate(tiles, 1)
+
+
+def _rope_forward_kernel(c_ref, sp_ref, sm_ref, x_ref, o_ref, *, half,
+                         scale):
+    head = o_ref.shape[3]
+    y = _rotated(x_ref[0].astype(jnp.float32),
+                 (c_ref[...], sp_ref[...], sm_ref[...]), half) * scale
+    for i in range(o_ref.shape[1]):
+        o_ref[0, i] = _lanes(y, i * head, head).astype(o_ref.dtype)
+
+
+def _rope_backward_kernel(c_ref, sp_ref, sm_ref, d_ref, dx_ref, *, half,
+                          scale):
+    d = _interleaved([d_ref[0, i].astype(jnp.float32)
+                      for i in range(d_ref.shape[1])], dx_ref.shape[2])
+    dx_ref[0] = _rotated_back(d * scale, (c_ref[...], sp_ref[...],
+                                          sm_ref[...]), half
+                              ).astype(dx_ref.dtype)
+
+
+def _rope_call(kernel, x, name: str, grid, semantics, **kw):
+    """``pallas_call`` as the rotary kernels make it: for XLA's
+    scheduler, ``x`` read once and written once."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    return pl.pallas_call(
+        kernel, name=name, grid=grid,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=semantics,
+            vmem_limit_bytes=64 * 1024 * 1024),
+        cost_estimate=pl.CostEstimate(
+            flops=8 * x.size, transcendentals=0,
+            bytes_accessed=2 * x.size * x.dtype.itemsize), **kw)
+
+
+def _table_specs(rope: Rope, s_len: int, head: int, at: int, rows: int,
+                 lanes: int):
+    """The three tables over one period of the heads in a block
+    (``lcm(head, 128)`` lanes) and their ``BlockSpec``: a block's rows,
+    whatever its batch row and heads."""
+    from jax.experimental import pallas as pl
+
+    period = math.lcm(head, 128)
+    tables = [jnp.tile(t, (1, period // head))
+              for t in rope_tables(rope, s_len, head, at)]
+    spec = pl.BlockSpec((rows, period), lambda b, i, j: (i, 0))
+    return tables, [spec] * 3
+
+
+#: each wrapper is lowered once a shape, however many layers call it
+_rope_jit = functools.partial(
+    jax.jit, static_argnames=("rope", "heads", "at", "scale",
+                              "interpret"))
+
+
+@_rope_jit
+def rope_kernel_forward(x, rope: Rope, heads: int, at: int = 0,
+                        scale: float = 1.0, *, interpret: bool = False):
+    """:func:`rope_head_major`'s value by the Pallas kernel."""
+    from jax.experimental import pallas as pl
+
+    b, s_len, width = x.shape
+    head = width // heads
+    rows, lanes = rope_blocks(s_len, width, head, at, rope.dims)
+    tables, specs = _table_specs(rope, s_len, head, at, rows, lanes)
+    return _rope_call(
+        functools.partial(_rope_forward_kernel, half=rope.dims // 2,
+                          scale=scale),
+        x, "rope_pass_fwd", (b, s_len // rows, width // lanes),
+        ("parallel",) * 3, interpret=interpret,
+        in_specs=specs + [pl.BlockSpec((1, rows, lanes),
+                                       lambda b, i, j: (b, i, j))],
+        out_specs=pl.BlockSpec((1, lanes // head, rows, head),
+                               lambda b, i, j: (b, j, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, heads, s_len, head), x.dtype)
+    )(*tables, x)
+
+
+@_rope_jit
+def rope_kernel_backward(d, rope: Rope, heads: int, at: int = 0,
+                         scale: float = 1.0, *, interpret: bool = False):
+    """:func:`rope_head_major`'s backward by the Pallas kernel: the
+    head-major cotangent ``d [B, H, S, d]`` → ``[B, S, H·d]``."""
+    from jax.experimental import pallas as pl
+
+    b, _, s_len, head = d.shape
+    width = heads * head
+    rows, lanes = rope_blocks(s_len, width, head, at, rope.dims)
+    tables, specs = _table_specs(rope, s_len, head, at, rows, lanes)
+    return _rope_call(
+        functools.partial(_rope_backward_kernel, half=rope.dims // 2,
+                          scale=scale),
+        d, "rope_pass_bwd", (b, s_len // rows, width // lanes),
+        ("parallel",) * 3, interpret=interpret,
+        in_specs=specs + [pl.BlockSpec((1, lanes // head, rows, head),
+                                       lambda b, i, j: (b, j, i, 0))],
+        out_specs=pl.BlockSpec((1, rows, lanes),
+                               lambda b, i, j: (b, i, j)),
+        out_shape=jax.ShapeDtypeStruct((b, s_len, width), d.dtype)
+    )(*tables, d)
+
+
+def _rope_by_definition(x, rope: Rope, heads: int, at: int,
+                        scale: float):
+    b, s_len, width = x.shape
+    x = x.reshape(b, s_len, heads, width // heads)
+    if at:
+        x = jnp.concatenate(
+            [(x[..., :at].astype(jnp.float32) * scale).astype(x.dtype),
+             apply_rope(x[..., at:], rope, scale)], axis=-1)
+    else:
+        x = apply_rope(x, rope, scale)
+    return x.transpose(0, 2, 1, 3)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4))
+def rope_head_major(x, rope: Rope, heads: int, at: int = 0,
+                    scale: float = 1.0):
+    """A q or k projection's product ``x [B, S, H·d]`` → the kernel's
+    operand ``[B, H, S, d]`` in ``x``'s type: each head's ``rope.dims``
+    lanes from ``at`` rotated (:func:`apply_rope`), every lane times
+    ``scale``, in float32 — latent attention's queries with ``at`` its
+    non-rotary width. The backward keeps nothing (comment above)."""
+    if use_rope_kernel(x, heads, rope, at):
+        return rope_kernel_forward(x, rope, heads, at, scale)
+    return _rope_by_definition(x, rope, heads, at, scale)
+
+
+def _rope_head_major_fwd(x, rope, heads, at, scale):
+    return rope_head_major(x, rope, heads, at, scale), None
+
+
+def _rope_head_major_bwd(rope, heads, at, scale, kept, d):
+    b, _, s_len, head = d.shape
+    x = jax.ShapeDtypeStruct((b, s_len, heads * head), d.dtype)
+    if use_rope_kernel(x, heads, rope, at):
+        return rope_kernel_backward(d, rope, heads, at, scale),
+    return jax.vjp(lambda x: _rope_by_definition(
+        x, rope, heads, at, scale), jnp.zeros(x.shape, x.dtype))[1](d)
+
+
+rope_head_major.defvjp(_rope_head_major_fwd, _rope_head_major_bwd)
+
+
+def _latent_forward_kernel(c_ref, sp_ref, sm_ref, kv_ref, pe_ref, k_ref,
+                           v_ref, *, half):
+    width = pe_ref.shape[2]
+    nope = k_ref.shape[3] - width
+    kv = kv_ref[0]
+    pe = _rotated(_tile(pe_ref[0].astype(jnp.float32), 0),
+                  (c_ref[...], sp_ref[...], sm_ref[...]), half)
+    k_ref[0, 0] = jnp.concatenate(
+        [kv[:, :nope], pe[:, :width].astype(k_ref.dtype)], axis=1)
+    v_ref[0, 0] = kv[:, nope:]
+
+
+def _latent_backward_kernel(c_ref, sp_ref, sm_ref, dk_ref, dv_ref,
+                            dkv_ref, dpe_ref, *, half):
+    from jax.experimental import pallas as pl
+
+    width = dpe_ref.shape[2]
+    nope = dk_ref.shape[3] - width
+    head = pl.program_id(2)
+    dk = dk_ref[0, 0]
+    dkv_ref[0] = jnp.concatenate([dk[:, :nope], dv_ref[0, 0]], axis=1)
+    pe = dk[:, nope:].astype(jnp.float32)
+
+    @pl.when(head == 0)
+    def _():
+        dpe_ref[0] = pe
+
+    @pl.when(head > 0)
+    def _():
+        dpe_ref[0] += pe
+
+    @pl.when(head == pl.num_programs(2) - 1)
+    def _():
+        dpe_ref[0] = _rotated_back(
+            _tile(dpe_ref[0], 0), (c_ref[...], sp_ref[...], sm_ref[...]),
+            half)[:, :width]
+
+
+def latent_blocks(s_len: int, nope: int, value: int, dims: int):
+    """The rows of a block of the latent key and value's kernels (one
+    head a block), or None where they do not apply: the non-rotary key
+    and the value whole 128-lane tiles, the rotary key inside one
+    tile."""
+    if nope % 128 or value % 128 or dims > 128 or dims % 2:
+        return None
+    rows = [n for n in range(16, s_len + 1, 16)
+            if s_len % n == 0 and n * (nope + value) <= ROPE_BLOCK]
+    return rows[-1] if rows else None
+
+
+def use_latent_kernel(kv, rope: Rope, nope: int) -> bool:
+    """Whether the kernels take latent attention's key and value from
+    ``kv [B, S, H, nope + value]``: on a TPU, wherever
+    :func:`latent_blocks` finds a block."""
+    _, s_len, _, both = kv.shape
+    return (kernel_platform() == "tpu" and latent_blocks(
+        s_len, nope, both - nope, rope.dims) is not None)
+
+
+def _latent_grid(kv_shape, rope: Rope, nope: int):
+    """Grid, rows, tables and their specs of both latent kernels: one
+    head a block (innermost), the rotary key in one tile's first
+    lanes."""
+    b, s_len, heads, both = kv_shape
+    rows = latent_blocks(s_len, nope, both - nope, rope.dims)
+    tables, specs = _table_specs(rope, s_len, 128, 0, rows, 128)
+    return (b, s_len // rows, heads), rows, tables, specs
+
+
+@functools.partial(jax.jit, static_argnames=("rope", "nope", "interpret"))
+def latent_kernel_forward(kv, pe, rope: Rope, nope: int, *,
+                          interpret: bool = False):
+    """:func:`latent_head_major`'s value by the Pallas kernel."""
+    from jax.experimental import pallas as pl
+
+    b, s_len, heads, both = kv.shape
+    grid, rows, tables, specs = _latent_grid(kv.shape, rope, nope)
+    return _rope_call(
+        functools.partial(_latent_forward_kernel, half=rope.dims // 2),
+        kv, "rope_latent_fwd", grid, ("parallel",) * 3,
+        interpret=interpret,
+        in_specs=specs + [
+            pl.BlockSpec((1, rows, both), lambda b, i, j: (b, i, j)),
+            pl.BlockSpec((1, rows, rope.dims), lambda b, i, j: (b, i, 0))],
+        out_specs=[pl.BlockSpec((1, 1, rows, nope + rope.dims),
+                                lambda b, i, j: (b, j, i, 0)),
+                   pl.BlockSpec((1, 1, rows, both - nope),
+                                lambda b, i, j: (b, j, i, 0))],
+        out_shape=[jax.ShapeDtypeStruct(
+            (b, heads, s_len, nope + rope.dims), kv.dtype),
+                   jax.ShapeDtypeStruct(
+            (b, heads, s_len, both - nope), kv.dtype)]
+    )(*tables, kv.reshape(b, s_len, heads * both), pe)
+
+
+@functools.partial(jax.jit, static_argnames=("rope", "interpret"))
+def latent_kernel_backward(dk, dv, rope: Rope, *,
+                           interpret: bool = False):
+    """:func:`latent_head_major`'s backward by the Pallas kernel: the
+    head-major cotangents ``dk [B, H, S, nope + dims]`` and ``dv [B, H,
+    S, value]`` → ``kv``'s ``[B, S, H·(nope + value)]`` and, summed
+    over the heads and rotated back, the rotary key's float32 ``[B, S,
+    dims]``."""
+    from jax.experimental import pallas as pl
+
+    b, heads, s_len, _ = dk.shape
+    nope, value = dk.shape[3] - rope.dims, dv.shape[3]
+    both = nope + value
+    grid, rows, tables, specs = _latent_grid(
+        (b, s_len, heads, both), rope, nope)
+    return _rope_call(
+        functools.partial(_latent_backward_kernel, half=rope.dims // 2),
+        dk, "rope_latent_bwd", grid, ("parallel", "parallel", "arbitrary"),
+        interpret=interpret,
+        in_specs=specs + [
+            pl.BlockSpec((1, 1, rows, nope + rope.dims),
+                         lambda b, i, j: (b, j, i, 0)),
+            pl.BlockSpec((1, 1, rows, value),
+                         lambda b, i, j: (b, j, i, 0))],
+        out_specs=[pl.BlockSpec((1, rows, both), lambda b, i, j: (b, i, j)),
+                   pl.BlockSpec((1, rows, rope.dims),
+                                lambda b, i, j: (b, i, 0))],
+        out_shape=[jax.ShapeDtypeStruct((b, s_len, heads * both),
+                                        dk.dtype),
+                   jax.ShapeDtypeStruct((b, s_len, rope.dims),
+                                        jnp.float32)]
+    )(*tables, dk, dv)
+
+
+def _latent_by_definition(kv, pe, rope: Rope, nope: int):
+    b, s_len, heads, _ = kv.shape
+    k_pe = apply_rope(pe[:, :, None], rope)
+    k = jnp.concatenate(
+        [kv[..., :nope],
+         jnp.broadcast_to(k_pe, (b, s_len, heads, pe.shape[2]))],
+        axis=-1)
+    return k.transpose(0, 2, 1, 3), kv[..., nope:].transpose(0, 2, 1, 3)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def latent_head_major(kv, pe, rope: Rope, nope: int):
+    """Latent attention's key and value as the kernel takes them,
+    head-major in ``kv``'s type: the key ``[B, H, S, nope + dims]``,
+    each head's non-rotary key ``kv[..., :nope]`` (of ``kv [B, S, H,
+    nope + value]``) beside the one rotary key ``pe [B, S, dims]``
+    rotated (:func:`apply_rope`) — the broadcast and the concatenation
+    in the pass — and the value ``[B, H, S, value]``: ``kv`` is read
+    once, whole, so it keeps the layout its product leaves. Backward,
+    the rotary key's cotangent is the heads' sum, taken inside the
+    pass; nothing is kept."""
+    if use_latent_kernel(kv, rope, nope):
+        return latent_kernel_forward(kv, pe, rope, nope)
+    return _latent_by_definition(kv, pe, rope, nope)
+
+
+def _latent_head_major_fwd(kv, pe, rope, nope):
+    return latent_head_major(kv, pe, rope, nope), None
+
+
+def _latent_head_major_bwd(rope, nope, kept, d):
+    dk, dv = d
+    b, heads, s_len, _ = dk.shape
+    kv = jax.ShapeDtypeStruct((b, s_len, heads, nope + dv.shape[3]),
+                              dk.dtype)
+    if use_latent_kernel(kv, rope, nope):
+        dkv, dpe = latent_kernel_backward(dk, dv, rope)
+        return dkv.reshape(kv.shape), dpe.astype(dk.dtype)
+    return jax.vjp(lambda kv, pe: _latent_by_definition(kv, pe, rope, nope),
+                   jnp.zeros(kv.shape, dk.dtype),
+                   jnp.zeros((b, s_len, rope.dims), dk.dtype))[1](d)
+
+
+latent_head_major.defvjp(_latent_head_major_fwd, _latent_head_major_bwd)
+
+
 # -------------------------------------------------------------- modules
 
 def _weight(module, name: str, shape: tuple, dtype):
@@ -1333,12 +1769,26 @@ class GatedAttention(nn.Module):
                 gate = jax.nn.sigmoid(jnp.dot(
                     x, _weight(self, "gate_proj", (d, h), self.dtype)
                 ).astype(jnp.float32))
+        # the kernel where its tiles fit, its operands head-major; else
+        # the XLA form
+        kernel = use_kernel(s_len, hd, hd)
         with jax.named_scope(scopes.SEQ_ATTN_ROPE):
-            q = apply_rope(q.reshape(b, s_len, h, hd), spec.rope,
-                           1.0 / math.sqrt(hd))
-            k = apply_rope(k.reshape(b, s_len, g, hd), spec.rope)
+            if kernel:
+                q = rope_head_major(q.reshape(b, s_len, h * hd),
+                                    spec.rope, h, 0, 1.0 / math.sqrt(hd))
+                k = rope_head_major(k.reshape(b, s_len, g * hd),
+                                    spec.rope, g)
+            else:
+                q = apply_rope(q.reshape(b, s_len, h, hd), spec.rope,
+                               1.0 / math.sqrt(hd))
+                k = apply_rope(k.reshape(b, s_len, g, hd), spec.rope)
         v = v.reshape(b, s_len, g, hd)
-        a = _attention(q, k, v, spec.window)
+        if kernel:
+            with jax.named_scope(scopes.SEQ_ATTN_KERNEL):
+                v = v.transpose(0, 2, 1, 3)
+            a = kernel_attention(q, k, v, spec.window)
+        else:
+            a = grouped_attention(q, k, v, spec.window)
         if spec.gate:
             with jax.named_scope(scopes.SEQ_ATTN_GATE):
                 a = (a * gate[..., None]).astype(self.dtype)
@@ -1385,18 +1835,29 @@ class LatentAttention(nn.Module):
                         (lat.kv_rank, h * (lat.nope + lat.value)),
                         self.dtype)
             ).reshape(b, s_len, h, lat.nope + lat.value)
+        kernel = use_kernel(s_len, qk, lat.value)
         with jax.named_scope(scopes.SEQ_ATTN_ROPE):
             # the scores' scale goes on the queries, in float32
-            q_nope = (q[..., :lat.nope].astype(jnp.float32)
-                      * lat.scale).astype(self.dtype)
-            q_pe = apply_rope(q[..., lat.nope:], spec.rope, lat.scale)
-            k_pe = apply_rope(kv_a[:, :, None, lat.kv_rank:], spec.rope)
-            q = jnp.concatenate([q_nope, q_pe], axis=-1)
-            k = jnp.concatenate(
-                [kv[..., :lat.nope],
-                 jnp.broadcast_to(k_pe, (b, s_len, h, lat.rope))],
-                axis=-1)
-        a = _attention(q, k, kv[..., lat.nope:], spec.window)
+            if kernel:
+                q = rope_head_major(q.reshape(b, s_len, h * qk),
+                                    spec.rope, h, lat.nope, lat.scale)
+                k, v = latent_head_major(kv, kv_a[..., lat.kv_rank:],
+                                         spec.rope, lat.nope)
+            else:
+                q_nope = (q[..., :lat.nope].astype(jnp.float32)
+                          * lat.scale).astype(self.dtype)
+                q_pe = apply_rope(q[..., lat.nope:], spec.rope, lat.scale)
+                k_pe = apply_rope(kv_a[:, :, None, lat.kv_rank:],
+                                  spec.rope)
+                q = jnp.concatenate([q_nope, q_pe], axis=-1)
+                k = jnp.concatenate(
+                    [kv[..., :lat.nope],
+                     jnp.broadcast_to(k_pe, (b, s_len, h, lat.rope))],
+                    axis=-1)
+        if kernel:
+            a = kernel_attention(q, k, v, spec.window)
+        else:
+            a = grouped_attention(q, k, kv[..., lat.nope:], spec.window)
         if lat.gate:
             with jax.named_scope(scopes.SEQ_ATTN_GATE):
                 gate = jax.nn.sigmoid(jnp.dot(

@@ -87,8 +87,10 @@ SEQ_SHORTCONV_MIX = "seq.shortconv.mix"
 #: ``out_proj``
 SEQ_SHORTCONV_OUT = "seq.shortconv.out"
 #: inside any of the softmax three, where the kernel runs: Pallas
-#: ``splash_attention`` (forward and backward kernels) and the
-#: ``[B,S,H,d]`` → ``[B,H,S,d]`` transposes of q, k, v in front of it
+#: ``splash_attention`` (forward and backward kernels) and, in a
+#: grouped-query layer, v's ``[B,S,H,d]`` → ``[B,H,S,d]`` transpose in
+#: front of it (q and k — and latent attention's v — come head-major
+#: from the rotary pass under ``seq.attn.rope``)
 SEQ_ATTN_KERNEL = "seq.attn.kernel"
 # The other four parts of a softmax layer (``GatedAttention``,
 # ``LatentAttention``), each inside one of the three layer scopes and
@@ -102,7 +104,10 @@ SEQ_ATTN_KERNEL = "seq.attn.kernel"
 SEQ_ATTN_PROJ = "seq.attn.proj"
 #: the float32 rotary passes over ``[B, S, H, d]``; in latent
 #: attention also the scaling of ``q_nope``, the broadcast of ``k_pe``
-#: to the heads and the two concatenations that build q and k
+#: to the heads and the two concatenations that build q and k; where
+#: the attention kernel runs, the one pass that does all of that and
+#: writes q and k (and latent attention's v) in the kernel's
+#: head-major order
 SEQ_ATTN_ROPE = "seq.attn.rope"
 #: the per-head gate: ``gate_proj``'s product from ``x``, the sigmoid
 #: and the product with the kernel's output

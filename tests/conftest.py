@@ -126,7 +126,9 @@ def kernel_gradient(whole_layer_remat):
                 lambda p, i, n: sl.policy_loss_fn(
                     net.module.apply, p, i, n)[0]))(
                         params, ids, ids).jaxpr))
-        return (sum(e.primitive.name == "pallas_call" for e in found),
+        return (sum(e.primitive.name == "pallas_call"
+                    and e.params["name"].startswith("splash")
+                    for e in found),
                 {e.params["name"] for e in found
                  if e.primitive.name == "name"})
 

@@ -290,7 +290,9 @@ def sim_ops(search):
 def kernel_paths():
     """The name stack of every equation of a gated and a latent layer
     traced for a TPU (nothing runs: the kernel's scope exists only in
-    such a trace) — ``{"pallas": [...], "all": [...]}``."""
+    such a trace) — ``{"pallas": [...], "all": [...]}``, ``pallas`` the
+    attention kernel's calls (the rotary pass's kernels run under
+    ``seq.attn.rope``: ``tests/test_rope_pass.py``)."""
     from rocalphago_tpu.models import seqpolicy
 
     rope = seqpolicy.Rope("default", 1e4, 64)
@@ -314,7 +316,8 @@ def kernel_paths():
         for eqn in jaxpr.eqns:
             name = f"{outer}/{eqn.source_info.name_stack}"
             found["all"].append(name)
-            if eqn.primitive.name == "pallas_call":
+            if (eqn.primitive.name == "pallas_call"
+                    and eqn.params["name"].startswith("splash")):
                 found["pallas"].append(name)
             for sub in jax.core.jaxprs_in_params(eqn.params):
                 walk(sub, name)

@@ -679,7 +679,9 @@ def test_the_attention_kernel_is_the_dense_masked_one(window):
     q, k, v = random_qkv(256, heads=4, groups=2, d=128)
     with unittest.mock.patch.object(seqpolicy, "KERNEL_BLOCK", 128):
         got = seqpolicy.kernel_attention(
-            q / np.sqrt(128), k, v, window, interpret=True)
+            (q / np.sqrt(128)).transpose(0, 2, 1, 3),
+            k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3), window,
+            interpret=True)
     assert reference.relative_error(
         got, dense_attention(q, k, v, window)) < 1e-4
 
@@ -756,8 +758,10 @@ def test_the_kept_residuals_give_the_xla_forms_gradients(
     with pytest.MonkeyPatch.context() as patch:
         patch.setattr(seqpolicy, "kernel_platform", lambda: "tpu")
         patch.setattr(seqpolicy, "KERNEL_BLOCK", 128)
-        patch.setattr(seqpolicy, "kernel_attention", functools.partial(
-            seqpolicy.kernel_attention, interpret=True))
+        for name in ("kernel_attention", "rope_kernel_forward",
+                     "rope_kernel_backward"):
+            patch.setattr(seqpolicy, name, functools.partial(
+                getattr(seqpolicy, name), interpret=True))
         kept = grads()
         with whole_layer_remat():
             whole = grads()
@@ -771,7 +775,7 @@ def test_the_kernel_runs_under_its_scope_where_its_tiles_fit():
 
     assert not seqpolicy.use_kernel(8192, 128)      # this is a CPU
     q, k, v = (jax.ShapeDtypeStruct(s, jnp.bfloat16) for s in (
-        (1, 512, 4, 128), (1, 512, 2, 128), (1, 512, 2, 128)))
+        (1, 4, 512, 128), (1, 2, 512, 128), (1, 2, 512, 128)))
     jaxpr = jax.make_jaxpr(
         lambda q, k, v: seqpolicy.kernel_attention(q, k, v, 0))(q, k, v)
 

@@ -339,7 +339,9 @@ def test_the_kernel_at_64_is_the_dense_masked_one():
     k = jax.random.normal(keys[1], (1, 256, 2, 64))
     v = jax.random.normal(keys[2], (1, 256, 2, 64))
     with unittest.mock.patch.object(seqpolicy, "KERNEL_BLOCK", 128):
-        got = seqpolicy.kernel_attention(q / 8.0, k, v, 0, interpret=True)
+        got = seqpolicy.kernel_attention(
+            (q / 8.0).transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+            v.transpose(0, 2, 1, 3), 0, interpret=True)
     assert got.shape == (1, 256, 4, 64)
     want = jnp.stack([_head(q[0, :, h], k[0, :, h // 2], v[0, :, h // 2],
                             1 / 8.0) for h in range(4)], axis=1)

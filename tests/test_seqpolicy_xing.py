@@ -326,8 +326,10 @@ def test_the_kernel_at_192_and_128_is_the_dense_masked_one():
     k = jax.random.normal(keys[1], (1, 256, 2, 192))
     v = jax.random.normal(keys[2], (1, 256, 2, 128))
     with unittest.mock.patch.object(seqpolicy, "KERNEL_BLOCK", 128):
-        got = seqpolicy.kernel_attention(q / np.sqrt(192), k, v, 0,
-                                         interpret=True)
+        got = seqpolicy.kernel_attention(
+            (q / np.sqrt(192)).transpose(0, 2, 1, 3),
+            k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3), 0,
+            interpret=True)
     assert got.shape == (1, 256, 2, 128)
     want = jnp.stack([reference._head(q[0, :, h], k[0, :, h],
                                       v[0, :, h], 1 / np.sqrt(192))
